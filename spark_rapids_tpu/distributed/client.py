@@ -41,6 +41,7 @@ bench rung4_dist A/B at <= 2% overhead).
 """
 from __future__ import annotations
 
+import contextvars
 import threading
 from typing import Dict, Iterator, List, Optional
 
@@ -260,10 +261,14 @@ class DistributedExchange:
             return remote()
         box: Dict[str, object] = {}
         done = threading.Event()
+        # the side thread must carry the query's context (trace id,
+        # current operator span): a bare thread starts with an empty
+        # one and the worker would record the fetch under no trace
+        ctx = contextvars.copy_context()
 
         def run():
             try:
-                box["out"] = remote()
+                box["out"] = ctx.run(remote)
             except BaseException as e:
                 box["err"] = e
             finally:
@@ -301,8 +306,15 @@ class DistributedExchange:
         while True:
             self._drain_redrives()
             try:
+                # ask for the blocks AFTER the last expected one: a
+                # complete partition answers with the count alone (a
+                # fetch always ships at least one block it finds, and a
+                # probe that hauled a data block would also teach the
+                # owner's latency EWMA that bulk replies are normal
+                # before the first hedged fetch could miss)
                 _seqs, _blobs, n_total = self.coord.fetch_blocks(
-                    self.exch_id, pid, after_seq=-1, max_bytes=1)
+                    self.exch_id, pid, after_seq=expected - 1,
+                    max_bytes=1)
             except WorkerLost:
                 self._bump_redrive_budget(pid)
                 self._drain_redrives(include=pid)

@@ -1515,10 +1515,9 @@ class Coordinator:
 
     def shutdown(self) -> None:
         self._stop.set()
-        try:
-            self._listener.close()
-        except OSError:
-            pass
+        # hard_close, not close(): a bare close leaves the accept loop
+        # (and every control-conn reader) blocked on its socket forever
+        P.hard_close(self._listener)
         with self._lock:
             socks = list(self._conns.values()) + [
                 w.control for w in self._workers.values()
@@ -1532,7 +1531,8 @@ class Coordinator:
                 if w.state in (ALIVE, QUARANTINED, DEGRADED):
                     w.state = LEFT
         for s in socks:
-            try:
-                s.close()
-            except OSError:
-                pass
+            P.hard_close(s)
+        me = threading.current_thread()
+        for t in self._threads:
+            if t is not me:
+                t.join(self.heartbeat_s * 2 + 1.0)

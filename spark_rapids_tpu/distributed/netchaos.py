@@ -46,7 +46,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
-from spark_rapids_tpu.distributed.protocol import MAGIC
+from spark_rapids_tpu.distributed.protocol import MAGIC, hard_close
 
 _HDR = struct.Struct("<4sII")
 
@@ -315,28 +315,18 @@ class NetChaosProxy:
         except OSError:
             pass
         for s in (src, dst):
-            try:
-                s.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-            try:
-                s.close()
-            except OSError:
-                pass
+            hard_close(s)
 
     def close(self) -> None:
         self._stop.set()
-        try:
-            self._listener.close()
-        except OSError:
-            pass
+        # hard_close: a bare close() wakes neither the accept loop nor a
+        # pump blocked in recv()
+        hard_close(self._listener)
         with self._socks_lock:
             socks, self._socks = self._socks, []
         for s in socks:
-            try:
-                s.close()
-            except OSError:
-                pass
+            hard_close(s)
+        self._accept_thread.join(2.0)
 
 
 def interpose(coord, worker_id: str,

@@ -225,6 +225,25 @@ def connect(host: str, port: int, timeout_s: float) -> socket.socket:
     return s
 
 
+def hard_close(sock: Optional[socket.socket]) -> None:
+    """Close a socket another thread may be blocked on.  On Linux a bare
+    ``close()`` does NOT wake a thread inside ``accept()``/``recv()`` on
+    that socket — the call keeps its reference to the open file and
+    blocks on (a listener's accept loop outlives its owner forever).
+    ``shutdown(SHUT_RDWR)`` wakes it: accept fails with EINVAL, recv
+    returns EOF."""
+    if sock is None:
+        return
+    try:
+        sock.shutdown(socket.SHUT_RDWR)
+    except OSError:
+        pass                      # never connected / already shut down
+    try:
+        sock.close()
+    except OSError:
+        pass
+
+
 def parse_endpoint(ep: str) -> Tuple[str, int]:
     host, _, port = ep.rpartition(":")
     return (host or "127.0.0.1"), int(port)

@@ -320,18 +320,14 @@ def _warm_caches(compile_dir: Optional[str]) -> int:
     if not compile_dir:
         return 0
     try:
-        import jax
+        from spark_rapids_tpu.compilecache import apply_persistent_cache_dir
 
-        from spark_rapids_tpu.compilecache import ensure_atomic_cache_put
-
-        # N workers + the driver write this SHARED directory; stock
-        # jax publishes entries non-atomically (see the helper)
-        ensure_atomic_cache_put()
-        jax.config.update("jax_compilation_cache_dir", compile_dir)
+        # N workers + the driver write this SHARED directory; the helper
+        # makes entry publication atomic before pointing jax at it
+        apply_persistent_cache_dir(compile_dir)
         return len([f for f in os.listdir(compile_dir)
-                    if not f.startswith(".")]) if os.path.isdir(
-                        compile_dir) else 0
-    except Exception:
+                    if not f.startswith(".")])
+    except OSError:
         return 0
 
 
@@ -372,6 +368,9 @@ class WorkerServer:
         self._listener: Optional[socket.socket] = None
         self._control: Optional[socket.socket] = None
         self._threads: List[threading.Thread] = []
+        # accepted data-plane conns, so stop() can wake their readers
+        self._data_conns: set = set()
+        self._data_conns_lock = threading.Lock()
         self.data_port: Optional[int] = None
 
     # -- lifecycle -------------------------------------------------------
@@ -454,13 +453,17 @@ class WorkerServer:
                                            "worker_id": self.worker_id})
             except OSError:
                 pass
-        for s in (self._control, self._listener):
-            if s is not None:
-                try:
-                    s.close()
-                except OSError:
-                    pass
+        # hard_close, not close(): a bare close leaves the serve loop
+        # blocked in accept() and every data conn blocked in recv()
+        with self._data_conns_lock:
+            conns, self._data_conns = list(self._data_conns), set()
+        for s in (self._control, self._listener, *conns):
+            P.hard_close(s)
         self._control = self._listener = None
+        me = threading.current_thread()
+        for t in self._threads:
+            if t is not me:
+                t.join(self.heartbeat_s * 2 + 1.0)
         self.store.close()
 
     def run_forever(self) -> None:
@@ -546,6 +549,8 @@ class WorkerServer:
             except OSError:
                 return
             conn.settimeout(self.op_timeout_s * 4)
+            with self._data_conns_lock:
+                self._data_conns.add(conn)
             t = threading.Thread(target=self._serve_conn, args=(conn,),
                                  daemon=True,
                                  name=f"srt-dist-data-{self.worker_id}")
@@ -575,6 +580,8 @@ class WorkerServer:
                 except OSError:
                     return
         finally:
+            with self._data_conns_lock:
+                self._data_conns.discard(conn)
             try:
                 conn.close()
             except OSError:

@@ -667,8 +667,8 @@ class _BaseTpuJoinExec(TpuExec):
                 return self._semi_anti(probe, counts, anti=True)
             with_um = jt in (JoinType.LEFT_OUTER, JoinType.FULL_OUTER)
             # ONE host round trip for both sizing scalars (the seed synced
-            # total and n_um separately — BENCH_r05 counted the extra
-            # round trip on every probe batch of qb_left_join); semi/anti
+            # total and n_um separately, an extra round trip on every
+            # probe batch of qb_left_join); semi/anti
             # return above without paying the total sync at all
             from spark_rapids_tpu.perfcounters import sync_get
 

@@ -523,7 +523,7 @@ def _first_match_pos(s: DeviceColumn, needle: DeviceColumn,
     Start positions are scanned in CHUNKS inside a lax.fori_loop — compile
     size is O(1) in the string width (a Python loop over `range(width)`
     unrolled a 2048-step program at the widest bucket: minutes of XLA
-    compile — VERDICT r3 weak #4), while each iteration stays a wide
+    compile), while each iteration stays a wide
     vectorized gather+compare so the MXU-adjacent VPU lanes stay busy.
     Peak scratch is capped at ~256MB via the chunk size."""
     w = max(s.width, 1)

@@ -229,7 +229,7 @@ def read_iceberg(session, table_path: str,
 
 
 # ---------------------------------------------------------------------------
-# Write path (VERDICT r3 Next #7).  Reference analog: the reference's
+# Write path.  Reference analog: the reference's
 # Iceberg module is read-only too in most branches; Spark's Iceberg writes
 # go through the iceberg-spark runtime (SURVEY.md §2.8 Iceberg).  This
 # implements format-version-2 append/overwrite commits from scratch:

@@ -193,7 +193,7 @@ def _decode_string_page_compressed(page, cp, ndict):
 
 def _decode_plain_string_page(page):
     """PLAIN BYTE_ARRAY page -> (chars matrix, lens, identity indices,
-    validity) — VERDICT r3 Next #4.  The interleaved (len, bytes) layout
+    validity).  The interleaved (len, bytes) layout
     forces a sequential length walk (C kernel, host_kernels.cpp); the
     char gather into the padded matrix is one vectorized numpy pass and
     the matrix uploads once like a page-local dictionary."""

@@ -215,6 +215,7 @@ class TpuFileSourceScanExec(TpuExec):
         set counts as a decoder failure (``file_decoder_fallbacks``) and
         feeds the per-format decode breaker; it never escalates to the
         stage fault domain — the host decoder owns the file from here.
+        Programming errors (Import/Attribute/Name/TypeError) re-raise.
         ``blocked`` is the per-SCAN breaker decision (consulted once in
         execute_columnar, not per file)."""
         import os
@@ -252,6 +253,12 @@ class TpuFileSourceScanExec(TpuExec):
             # silent, not a decoder failure
             self._log_decode_fallback(path, f"{type(ex).__name__}: {ex}")
             return None
+        except (ImportError, AttributeError, NameError, TypeError):
+            # a programming error in the decoder (a jax API that moved, a
+            # wrong call) is not "a file outside the supported subset":
+            # falling to the host decoder would answer from pyarrow with
+            # rc 0 and hide that the device path is broken
+            raise
         except Exception as ex:
             kind = CL.classify_failure(ex)
             if kind == CL.PROPAGATE:

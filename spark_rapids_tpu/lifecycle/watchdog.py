@@ -1,4 +1,5 @@
-"""The deadline watchdog — ONE daemon thread trips expired queries.
+"""The deadline watchdog — ONE daemon thread trips expired queries
+(alive only while a query is registered).
 
 Every lifecycle-managed query registers here for the duration of its
 collect(); the watchdog scans the registry every
@@ -26,7 +27,6 @@ from spark_rapids_tpu.lifecycle.context import (
 _COND = threading.Condition()
 _ACTIVE: "set[QueryContext]" = set()
 _THREAD: Optional[threading.Thread] = None
-_IDLE_PERIOD_S = 0.5
 
 
 def register(ctx: QueryContext) -> None:
@@ -53,13 +53,19 @@ def active_queries() -> List[QueryContext]:
 
 
 def _run() -> None:
+    global _THREAD
     from spark_rapids_tpu import perfcounters as PC
 
     while True:
         with _COND:
             targets = list(_ACTIVE)
-            period = min(
-                [c.watchdog_period_s for c in targets] or [_IDLE_PERIOD_S])
+            if not targets:
+                # nothing to watch: the thread ends, and the next
+                # register() (same lock) starts a fresh one — an idle
+                # process keeps no watchdog thread alive
+                _THREAD = None
+                return
+            period = min(c.watchdog_period_s for c in targets)
         now = time.monotonic_ns()
         for ctx in targets:
             if ctx.deadline_expired(now) and not ctx.token.cancelled:

@@ -26,16 +26,25 @@ TEST_DEVICE_MEMORY = conf("spark.rapids.tpu.test.deviceMemoryBytes").doc(
 ).bytes_conf(0)
 
 
-def _physical_hbm_bytes() -> Optional[int]:
+_CPU_BACKEND_MEMORY = 16 << 30   # the XLA CPU backend reports no stats
+
+
+def _physical_hbm_bytes() -> int:
+    """Device 0's memory size as the runtime reports it.  Only the CPU
+    backend (tests) may go without: on an accelerator a missing
+    ``memory_stats()`` is an error, never an assumed 16 GiB."""
     import jax
 
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return None
+    dev = jax.local_devices()[0]
+    stats = dev.memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    if dev.platform == "cpu":
+        return _CPU_BACKEND_MEMORY
+    raise RuntimeError(
+        f"{dev.platform} device {dev.device_kind!r} reports no "
+        f"memory_stats()['bytes_limit']: the HBM pool cannot be sized "
+        f"(set spark.rapids.tpu.test.deviceMemoryBytes to override)")
 
 
 class TpuDeviceManager:
@@ -44,7 +53,7 @@ class TpuDeviceManager:
     def __init__(self, tpu_conf: Optional[TpuConf] = None):
         c = tpu_conf or TpuConf()
         override = c.get(TEST_DEVICE_MEMORY)
-        physical = override or _physical_hbm_bytes() or (16 << 30)
+        physical = override or _physical_hbm_bytes()
         reserve = c.get(HBM_RESERVE)
         frac = c.get(HBM_POOL_FRACTION)
         self.physical_bytes = physical

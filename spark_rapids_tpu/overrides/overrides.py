@@ -1783,7 +1783,7 @@ class TpuOverrides:
             )
 
             root = TpuTransitionOverrides.apply(root, conf)
-            # transition-stage explain parity (VERDICT r4 Next #8): the
+            # transition-stage explain parity: the
             # collective/fused stages report install/fallback like execs
             meta.stage_decisions = stage_decisions()
             if explain in ("NOT_ON_GPU", "ALL"):

@@ -83,7 +83,7 @@ def _mesh_stage_reason(conf: TpuConf, switch):
 
 # ---------------------------------------------------------------------------
 # Stage rules: the taggable registry of transition-installed execs
-# (VERDICT r4 Next #8).  The reference registers every exec in
+#.  The reference registers every exec in
 # GpuOverrides.execs with per-exec explain/fallback; the collective (ICI)
 # and fused stages here are installed by plan REWRITE rather than node
 # conversion, so they get their own registry + per-apply decision ledger
@@ -557,7 +557,7 @@ class TpuTransitionOverrides:
         """AQE-style shuffle partition coalescing for one device: hash/
         round-robin exchanges repartition for parallelism that a single
         chip does not have, and every extra partition costs a program
-        launch (and, on a compile-tunnel platform, potentially a compile).
+        launch (and potentially a compile).
         Collapse them to a single partition; results are unchanged
         (aggs/joins are partition-count independent)."""
         import jax

@@ -5,9 +5,9 @@ Reference analog: "GPU Acceleration of SQL Analytics on Compressed Data"
 (arXiv:2506.10092) and cuDF's gpuinflate/snappy device decompressors: the
 winning trade on a bandwidth-starved host->device link is to transfer the
 SMALLEST representation (the compressed page) and let the accelerator do
-the byte movement.  On this platform the link tops out near 5-40 MB/s
-(BENCH_r05), so every decoded byte shipped is ~25x more expensive than a
-compressed one.
+the byte movement: the host->device link is an order of magnitude
+slower than HBM, so a decoded byte shipped costs several times what a
+compressed one does.
 
 TPU adaptation (the same host-parses-structure / device-moves-bytes split
 as pallas/decode.py): a snappy stream is a sequence of ops — literal runs

@@ -38,9 +38,8 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from spark_rapids_tpu.parallel.compat import shard_map
 
 
 def make_mesh2(n_host: int, n_ici: int,

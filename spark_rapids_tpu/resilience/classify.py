@@ -15,7 +15,7 @@ Classes:
                       Handled by the memory/retry.py path: spill + retry.
   * TRANSIENT       — infrastructure errors that may heal on their own
                       (UNAVAILABLE, DEADLINE_EXCEEDED, ABORTED, CANCELLED,
-                      UNKNOWN, INTERNAL; plugin/tunnel disconnects).
+                      UNKNOWN, INTERNAL; runtime/plugin disconnects).
                       Bounded retry with exponential backoff + jitter.
   * DETERMINISTIC   — compile / lowering / unsupported-dtype / shape
                       errors: retrying re-derives the same failure, so the

@@ -1,6 +1,6 @@
 """Bounded-cardinality (groups-cap ladder) aggregation path.
 
-VERDICT r5 perf work: with spark.rapids.tpu.agg.smallGroupsCap set below
+With spark.rapids.tpu.agg.smallGroupsCap set below
 the batch capacity, the sort-based group-by runs a B-wide boundary-form
 program (cumsum-diff sums, boundary-gather min/max/first — no full-width
 scatters) and grows B on overflow using the synced output row count.

@@ -125,7 +125,7 @@ def test_adaptive_shuffle_reader_coalesces_on_measured_stats():
     """The AQE shuffle reader records per-partition rows/bytes at
     execution and coalesces partitions on those MEASURED stats
     (GpuCustomShuffleReaderExec analog) — a runtime plan change beyond
-    the broadcast-join case (VERDICT r3 Next #8)."""
+    the broadcast-join case."""
     from spark_rapids_tpu.exec.exchange import TpuAdaptiveShuffleReaderExec
     from spark_rapids_tpu.session import TpuSession, sum_
 

@@ -259,7 +259,6 @@ _CHILD = textwrap.dedent("""
     import glob, json, os, sys
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     events = {"persistentHits": 0, "persistentMisses": 0}
     try:
         from jax._src import monitoring
@@ -312,6 +311,8 @@ def test_persistent_cache_fresh_process_hits(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.dirname(os.path.dirname(
                    os.path.abspath(__file__))))
+    # the conf places the cache only when the environment does not
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
 
     def run():
         out = subprocess.run(

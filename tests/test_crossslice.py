@@ -1,5 +1,5 @@
-"""Cross-slice (DCN analog) two-level mesh repartition (VERDICT r4
-Next #10): hierarchical ICI-then-host routing over a (host x ici)
+"""Cross-slice (DCN analog) two-level mesh repartition: hierarchical
+ICI-then-host routing over a (host x ici)
 virtual mesh, verified against host-side partition ids.  See
 parallel/crossslice.py for the documented protocol."""
 import jax

@@ -359,6 +359,10 @@ def test_heartbeat_silence_declares_lost(coordinator):
 # acceptance pins
 # ---------------------------------------------------------------------------
 
+# ISSUE 23: 81 s of the 1470 s tier-1 budget (XLA:CPU compiles of the
+# join's programs at three shapes); the SIGKILL-recovery contract stays in
+# tier-1 through test_stress_harness::test_worker_kill_chaos_twin
+@pytest.mark.slow
 def test_distributed_join_survives_sigkill_mid_shuffle(coordinator):
     """THE acceptance pin: a 2-process distributed join at ~100x a
     shrunken per-worker pool, one worker SIGKILLed mid-shuffle,
@@ -456,6 +460,10 @@ def test_flapping_worker_quarantined_until_ttl_probe(coordinator):
         w2.stop(goodbye=True)
 
 
+# ISSUE 23: 81 s of the 1470 s tier-1 budget (three cold collects, two
+# worker spawns); join/leave bookkeeping stays in tier-1 through the
+# membership unit tests above
+@pytest.mark.slow
 def test_elastic_membership_between_queries(coordinator):
     """Workers join/leave between queries: with workers the exchange
     routes remotely; with none it falls through to the in-process

@@ -211,7 +211,7 @@ def test_iceberg_mixed_deletes(tmp_path):
     assert ss.isdisjoint({"a7", "a9", "b0"})
 
 
-# -- round 4: write/commit path (VERDICT r3 Next #7) ------------------------
+# -- round 4: write/commit path ------------------------
 
 
 def _rows(df):

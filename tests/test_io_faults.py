@@ -322,6 +322,28 @@ def test_decoder_fallback_single_file(tmp_path):
     assert snap["transient_retries"] == 0
 
 
+@pytest.mark.parametrize("error", [ImportError, AttributeError, NameError,
+                                   TypeError])
+def test_decoder_programming_error_reraises(tmp_path, monkeypatch, error):
+    """A programming error out of the device decoder (a jax API that
+    moved, a wrong call) must fail the scan — not count one
+    file_decoder_fallbacks and answer from the host decoder with rc 0."""
+    from spark_rapids_tpu.io import parquet_device
+
+    def broken(path, schema):
+        raise error("decoder bug")
+
+    monkeypatch.setattr(parquet_device, "read_parquet_device", broken)
+    paths = write_multifile_dataset(tmp_path, "parquet", n_files=1,
+                                    rows_per_file=10)
+    PC.reset()
+    conf = {**DEV_CONF,
+            "spark.rapids.tpu.resilience.runtimeFallbackEnabled": "false"}
+    with pytest.raises(error, match="decoder bug"):
+        _read(_session("PERFILE", conf), "parquet", paths).collect()
+    assert PC.snapshot()["file_decoder_fallbacks"] == 0
+
+
 def test_decode_breaker_trips_to_native_at_plan_time(tmp_path):
     from spark_rapids_tpu.resilience import active_faults, inject_fault
     from spark_rapids_tpu.resilience.breaker import get_breaker

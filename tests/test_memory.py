@@ -385,3 +385,33 @@ def test_metrics_report_surface():
     rep = q.metrics_report()
     assert "numOutputRows" in rep and "opTime" in rep
     assert "TpuHashAggregate" in rep
+
+
+def test_device_manager_raises_on_accelerator_without_memory_stats(
+        monkeypatch):
+    """ISSUE 23: only the CPU backend may go without memory_stats(); on
+    a TPU a missing report is an error, never an assumed 16 GiB."""
+    import jax
+
+    from spark_rapids_tpu.config import TpuConf
+    from spark_rapids_tpu.memory import device_manager as DM
+
+    class _Dev:
+        def __init__(self, platform, stats):
+            self.platform, self.device_kind = platform, "TPU v5 lite"
+            self._stats = stats
+
+        def memory_stats(self):
+            return self._stats
+
+    monkeypatch.setattr(jax, "local_devices",
+                        lambda: [_Dev("tpu", None)])
+    with pytest.raises(RuntimeError, match="memory_stats"):
+        DM.TpuDeviceManager(TpuConf({}))
+    monkeypatch.setattr(jax, "local_devices",
+                        lambda: [_Dev("tpu", {"bytes_limit": 15 << 30})])
+    assert DM.TpuDeviceManager(TpuConf({})).physical_bytes == 15 << 30
+    monkeypatch.setattr(jax, "local_devices",
+                        lambda: [_Dev("cpu", None)])
+    assert DM.TpuDeviceManager(
+        TpuConf({})).physical_bytes == DM._CPU_BACKEND_MEMORY

@@ -11,9 +11,8 @@ needs_mesh = pytest.mark.skipif(
 
 # Mesh-COLLECTIVE tests compile multi-device SPMD programs — minutes of
 # XLA CPU compile apiece, ~27min for the suite — which the tier-1
-# 'not slow' budget cannot absorb now that they PASS (at seed the whole
-# suite failed fast on the jax shard_map kwarg drift parallel/compat.py
-# shims away).  Plan/install-level tests stay in tier-1; the collectives
+# 'not slow' budget cannot absorb.  Plan/install-level tests stay in
+# tier-1; the collectives
 # run green via `pytest tests/test_multichip.py` (ISSUE 10 run) and the
 # driver's MULTICHIP_* artifact (__graft_entry__.dryrun_multichip).
 mesh_collective = pytest.mark.slow
@@ -378,7 +377,7 @@ def test_ici_sort_installed():
 @mesh_collective
 def test_ici_device_count_sweep(n_dev):
     """Non-power-of-2 meshes: quota/padding math must hold for every
-    device count (VERDICT r2 weak #9)."""
+    device count."""
     import sys
     sys.path.insert(0, "tests")
     from asserts import assert_tpu_and_cpu_are_equal_collect
@@ -424,7 +423,7 @@ def test_ici_sort_device_count_sweep(n_dev):
 @mesh_collective
 def test_ici_right_full_joins_on_mesh(how):
     """RIGHT (mirror-swapped) and FULL (matched-build tail) mesh joins run
-    through the ICI exec and match the oracle (VERDICT r3 Next #3)."""
+    through the ICI exec and match the oracle."""
     import sys
     sys.path.insert(0, "tests")
     from asserts import assert_tpu_and_cpu_are_equal_collect
@@ -789,7 +788,7 @@ def test_ici_all_to_all_columns_null_validity_round_trip():
     device its hash names."""
     from spark_rapids_tpu import types as T
     from spark_rapids_tpu.columnar.column import DeviceColumn
-    from spark_rapids_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from spark_rapids_tpu.parallel.mesh import (
         _local_hash_partition_ids,
         ici_all_to_all_columns,
@@ -860,7 +859,7 @@ def test_ici_all_to_all_zero_host_bytes():
     from spark_rapids_tpu import perfcounters as PC
     from spark_rapids_tpu import types as T
     from spark_rapids_tpu.columnar.column import DeviceColumn
-    from spark_rapids_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from spark_rapids_tpu.parallel.mesh import (
         _local_hash_partition_ids,
         ici_all_to_all_columns,

@@ -337,6 +337,9 @@ def test_bench_skip_bookkeeping_only_unfinished():
 # the acceptance pin: hash-join + aggregation at >= 10x the pool
 # ---------------------------------------------------------------------------
 
+# ISSUE 23: 80 s of the 1470 s tier-1 budget; the sized-exchange, spill
+# and sub-partition paths it composes keep their own tier-1 tests here
+@pytest.mark.slow
 def test_ooc_hash_join_agg_10x_pool(tmp_path):
     """ISSUE 10 acceptance: a hash-join + aggregation whose input
     exceeds the (conf-capped) HBM pool by >= 10x completes correctly vs

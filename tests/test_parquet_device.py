@@ -173,7 +173,7 @@ def test_device_decode_v2_pages_numerics(tmp_path, page_version):
     assert_tpu_and_cpu_are_equal_collect(build, conf=_CONF)
 
 
-# -- round 4: snappy + PLAIN byte_array pages (VERDICT r3 Next #4) ----------
+# -- round 4: snappy + PLAIN byte_array pages ----------
 
 
 def test_snappy_plain_string_pages(tmp_path):

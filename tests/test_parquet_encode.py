@@ -1,4 +1,4 @@
-"""Device-side Parquet ENCODE (VERDICT r4 Next #4) — write-read
+"""Device-side Parquet ENCODE — write-read
 roundtrips where the pages were encoded by device kernels (dictionary
 build, k-bit index packing, def-level packing; counters prove programs
 launched), snappy-compressed by the from-scratch C compressor twin, and

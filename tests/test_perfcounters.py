@@ -1,10 +1,10 @@
 """Durability guards for the perf-counter patches and the compile cache.
 
-VERDICT r4 (weak #5 / next #6): the counters live on monkey-patched JAX
-internals (``ArrayImpl.__array__``, scalar dunders, ``_cache_size``); a JAX
-upgrade could silently zero them via the guarded ``SYNC_COUNTING=False``
-path.  These tests fail LOUDLY instead, and pin the one-cache-authority
-behavior of ``TpuSession``.
+The counters live on monkey-patched JAX internals
+(``ArrayImpl.__array__``, scalar dunders, ``_cache_size``); a JAX upgrade
+that drops one must fail LOUDLY (the install raises at import), never
+zero the counts.  Also pins the one-cache-authority behavior of
+``TpuSession``.
 """
 import os
 
@@ -137,30 +137,62 @@ def test_counter_keys_are_snake_case_only():
     PC.reset()
 
 
-def test_session_applies_compile_cache_conf():
+def test_session_applies_compile_cache_conf(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR unset: the cache goes to the fixed
+    in-checkout ``.jax_compile_cache/<backend>`` (the path is part of the
+    cache key, so it never moves), or to ``<conf dir>/<backend>``."""
     import jax
 
     from spark_rapids_tpu import session as S
-    from spark_rapids_tpu.config import COMPILE_CACHE_DIR, TpuConf
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     # force a fresh application regardless of earlier sessions in-process
     S._COMPILE_CACHE_APPLIED = None
     S.TpuSession({})
-    want = TpuConf({}).get(COMPILE_CACHE_DIR)
-    # the applied dir is partitioned by backend (CPU AOT artifacts are
-    # machine-specific; mixing relay-compiled ones risks SIGILL)
-    assert jax.config.jax_compilation_cache_dir.startswith(want)
-    assert S._COMPILE_CACHE_APPLIED.startswith(want)
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(S.__file__)))
+    want = os.path.join(checkout, ".jax_compile_cache",
+                        jax.default_backend())
+    assert jax.config.jax_compilation_cache_dir == want
+    assert S._COMPILE_CACHE_APPLIED == want
     # a later session with an explicitly different dir is honored, not
-    # silently ignored (code-review finding)
-    import tempfile
-
-    with tempfile.TemporaryDirectory() as td:
-        other = os.path.join(td, "xc")
-        S.TpuSession({"spark.rapids.tpu.compileCache.dir": other})
-        assert jax.config.jax_compilation_cache_dir.startswith(other)
+    # silently ignored
+    other = str(tmp_path / "xc")
+    S.TpuSession({"spark.rapids.tpu.compileCache.dir": other})
+    assert jax.config.jax_compilation_cache_dir == os.path.join(
+        other, jax.default_backend())
     S._COMPILE_CACHE_APPLIED = None
     S.TpuSession({})      # restore the default for the rest of the suite
+
+
+def test_compile_cache_placed_from_outside_sets_nothing(monkeypatch,
+                                                        tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: whoever started the process placed
+    the cache — no code path may call
+    jax.config.update("jax_compilation_cache_dir", ...)."""
+    import jax
+
+    from spark_rapids_tpu import session as S
+    from spark_rapids_tpu.distributed.worker import _warm_caches
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "out"))
+    updated = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updated.append(k), real_update(k, v))[1])
+    before = jax.config.jax_compilation_cache_dir
+    S._COMPILE_CACHE_APPLIED = None
+    try:
+        S.TpuSession({})
+        S.TpuSession({"spark.rapids.tpu.compile.cacheDir":
+                      str(tmp_path / "conf")})
+        _warm_caches(str(tmp_path / "warm"))
+        assert updated == []
+        assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        monkeypatch.undo()
+        S._COMPILE_CACHE_APPLIED = None
+        S.TpuSession({})  # restore the default for the rest of the suite
 
 
 def test_concurrent_increments_lose_nothing():

@@ -1,4 +1,4 @@
-"""AQE skew-split for the mesh join (VERDICT r4 Next #9).
+"""AQE skew-split for the mesh join.
 
 A 100:1 hot key routes most probe rows (and their join output) to one
 device; the exec detects it from the per-epoch matched totals it syncs
@@ -22,9 +22,7 @@ needs_mesh = pytest.mark.skipif(
 
 # every test here EXECUTES the mesh join (multi-capacity SPMD compiles,
 # minutes on CPU XLA) — outside the tier-1 'not slow' budget for the
-# same reason as test_multichip's collective tests (ISSUE 10): at seed
-# they failed fast on the jax shard_map kwarg drift, with the
-# parallel/compat.py shim they pass but pay full compile cost
+# same reason as test_multichip's collective tests (ISSUE 10)
 pytestmark = pytest.mark.slow
 
 _CONF = {

@@ -1,8 +1,8 @@
 """Literal-expectation tests pinning Spark-documented semantics.
 
 The differential harness proves TPU == oracle; since BOTH are written here,
-a shared misunderstanding of Spark would be invisible to it (VERDICT r1
-weak #7).  This file pins ~50 hand-derived expectations from Spark's
+a shared misunderstanding of Spark would be invisible to it.  This file
+pins ~50 hand-derived expectations from Spark's
 documented behavior (ANSI errors, HALF_UP decimal rounding, NaN/-0.0
 ordering, Java integer wrap, date/time edges) and checks BOTH backends
 against the literal values — oracle bugs cannot silently define truth.
@@ -554,7 +554,7 @@ def test_to_json_omits_null_fields():
 def test_float_sum_inf_cancellation_pinned():
     """Spark sum over [+inf, -inf] is NaN (IEEE): the oracle's scalar adds
     hit this path with a RuntimeWarning — pin the semantics so the NaN
-    behavior is deliberate, not incidental (VERDICT r2 weak #8)."""
+    behavior is deliberate, not incidental."""
     import warnings
 
     from spark_rapids_tpu.session import TpuSession, sum_, avg_

@@ -1,5 +1,5 @@
 """Transition-stage rule registry: explain/fallback parity for the
-collective (ICI) and fused execs (VERDICT r4 Next #8).
+collective (ICI) and fused execs.
 
 Reference analog: GpuOverrides.execs entries get per-exec tagging with
 ``spark.rapids.sql.explain`` fallback reasons; the stages installed by

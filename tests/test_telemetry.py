@@ -316,7 +316,17 @@ def test_deadline_trip_dumps_postmortem_naming_tripped_query(fresh_hub):
         "spark.rapids.tpu.query.watchdogPeriodMs": "20",
     })
     df = _agg_query(s)
-    df.collect()                 # warm compiles outside the deadline
+    # warm the compiles: programs are keyed on the whole conf, so the
+    # warm-up runs under the same 300 ms deadline — a cold compile that
+    # overruns it is cached by the retry (earlier tests of this module
+    # used to do the warming by accident)
+    for _ in range(5):
+        try:
+            df.collect()
+            break
+        except QueryDeadlineExceeded:
+            continue
+    hub.reset_dump_limits()
     sem = get_semaphore(1)
     held, release = threading.Event(), threading.Event()
 

@@ -239,7 +239,7 @@ def test_iceberg_equality_delete_nulls_rejected(tmp_path):
         s.read.iceberg(p)
 
 
-# -- round 4: vectorized fast path (VERDICT r3 Next #5) ---------------------
+# -- round 4: vectorized fast path ---------------------
 
 
 def _both_paths(path, schema, options):
@@ -307,7 +307,7 @@ def test_csv_fast_path_quoted_and_ragged(tmp_path):
 
 def test_csv_fast_path_throughput(tmp_path):
     """2M-row clean numeric CSV parses within 5x of pyarrow's own typed
-    parse (VERDICT r3 Next #5 'done' bar)."""
+    parse."""
     import time
 
     import numpy as np
