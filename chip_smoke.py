@@ -2,8 +2,7 @@
 """chip_smoke.py — the quickest proof that the query path runs on the chip.
 
 One process drives ``TpuSession`` -> ``overrides/`` -> ``exec/`` -> XLA
-programs on the attached TPU, at the sizes BASELINE.md calls real, and
-checks every answer:
+programs on the attached TPU and checks every answer:
 
   q6_parquet    TPC-H Q6 over a parquet file written here (cold scan path)
   q6_hot        TPC-H Q6 over device-resident batches (scan cache on)
@@ -14,14 +13,15 @@ checks every answer:
                 spark.rapids.sql.format.parquet.decode.device=true, so the
                 Pallas bit-unpack kernel runs COMPILED (tpu_custom_call)
 
-Q6 runs at 50 M rows; the rung-2 queries at 65,536 (cut from 20 M: a cold
-run is compile-bound — see RUNG2_ROWS — so their programs are compiled
-first, all queries concurrently).  Every query is compared with bench.py's
-hand-vectorised numpy reference at full size and with the row oracle
-(spark.rapids.sql.enabled=false) at 65,536 rows, is collected twice
-(adaptive execs change strategy on the second run), and must leave every
-fallback counter at 0 with the CPU stage fallback switched off.  One JSON
-line per phase, then the verdict:
+Q6 runs at 50 M rows; the rung-2 queries at 8,192 (cut from 20 M: a cold
+run is compile-bound — see SMALL_ROWS).  Every query is compared with
+bench.py's hand-vectorised numpy reference at full size and with the row
+oracle (spark.rapids.sql.enabled=false) at 8,192 rows, is collected twice
+(adaptive execs change strategy on the second run), must run the plan
+shape its phase names (the execs are asserted and explain() is printed)
+and must leave every fallback counter at 0 with the CPU stage fallback
+switched off.  Every phase starts cold: its line carries its own compile
+wall.  One JSON line per phase, then the verdict:
 
   {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 
@@ -42,15 +42,21 @@ import time
 import numpy as np
 
 Q6_ROWS = 50_000_000       # BASELINE.md rung 1
-ORACLE_ROWS = 65_536       # the row oracle is row-at-a-time python
-# BASELINE.md's rung 2 is 20 M rows.  Cut to the oracle's size (PR 23):
-# every sort-bearing program compiles in minutes on the chip's host, the
-# time growing only with log^2 of the row count (PERF.md).  At 200 k +
-# 20 M rows one rung-2 query had not finished compiling in 24 minutes; at
-# 200 k alone a cold run took 1080 s of the 1200 s this script may take.
-# One 2^16 bucket per query, shared by the oracle comparison, leaves room.
-RUNG2_ROWS = ORACLE_ROWS
-MESH_ROWS = 400_000        # ISSUE 23 asked 20 M; cut for the same reason
+# BASELINE.md's rung 2 is 20 M rows.  Cut (PR 23) to the 8,192-row bucket
+# of columnar.column.DEFAULT_ROW_BUCKETS: the TPU compiler's time for a
+# sort-bearing program has a cliff between that bucket and the next
+# (bounded group-by, compiled for a described v5e: 10.81 s at 2^13 rows,
+# 187 s at 2^16, 352 s at 2^22; PERF.md).  At 20 M rows one rung-2 query
+# had not finished compiling in 24 minutes; at 65,536 the three queries
+# hold ~26 minutes of compiles, which fit the 1200 s this script may take
+# only when compiled concurrently.  Below the cliff a cold run compiles
+# every phase in turn and still ends well inside the limit.
+SMALL_ROWS = 8_192         # rung 2, decode, and Q6's row-oracle pass
+# ISSUE 23 asked 20 M for the mesh path.  Its epoch program for 2^25 rows
+# did not compile in 12 minutes for a described v5e:2x2 (PERF.md, PR 23)
+# and a four-chip minute costs four: 1 M rows fill the 2^20-row bucket,
+# whose eight programs compile in ~5 minutes of the chip host's time.
+MESH_ROWS = 1_000_000
 
 # a chip run in which any of these moved did not run (only) on the chip
 FALLBACK_COUNTERS = (
@@ -63,10 +69,6 @@ BASE_CONF = {
     # a compile or lowering error must fail the smoke, not become a
     # CPU-oracle answer with rc 0
     "spark.rapids.tpu.resilience.runtimeFallbackEnabled": False,
-    # room for compile_concurrently's threads (the default 2 permits
-    # would serialize them); programs are keyed on the whole conf, so
-    # every device session of the smoke carries the same value
-    "spark.rapids.sql.concurrentGpuTasks": 4,
 }
 ORACLE_CONF = {"spark.rapids.sql.enabled": False}
 
@@ -149,53 +151,6 @@ def _assert_device_only(df, counters: dict, what: str) -> None:
 
 
 # ---------------------------------------------------------------------------
-# compile first, all queries at once
-# ---------------------------------------------------------------------------
-
-def compile_concurrently(stage: str, builds: dict) -> None:
-    """``builds``: name -> (session conf, build(session) -> DataFrame).
-    Collect every query twice, each on its own thread, before any phase
-    is measured.  A cold run is compile-bound (minutes per sort-bearing
-    program, seconds of execution), XLA compiles release the GIL and the
-    host has a dozen cores: the stage costs the SLOWEST query's compiles
-    instead of their sum.  The phases that follow find every program in
-    the registry and are checked and measured one at a time."""
-    import threading
-
-    from spark_rapids_tpu import perfcounters as PC
-    from spark_rapids_tpu.session import TpuSession
-
-    errors = {}
-
-    def work(name, conf_build):
-        conf, build = conf_build
-        try:
-            df = build(TpuSession(dict(conf)))
-            df.collect()
-            df.collect()     # the adaptive second-run programs too
-        except Exception as e:
-            errors[name] = e
-
-    snap = PC.snapshot()
-    t0 = time.perf_counter()
-    threads = [threading.Thread(target=work, args=item,
-                                name=f"compile-{item[0]}")
-               for item in builds.items()]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    d = PC.since(snap)
-    _emit({"phase": stage, "queries": sorted(builds),
-           "wall_s": time.perf_counter() - t0,
-           "compileWall_s": (d["compile_wall_ns"]
-                             + d["aot_compile_wall_ns"]) / 1e9,
-           "nCompiles": d["compiles"] + d["aot_compiles"]})
-    for name, e in errors.items():
-        raise RuntimeError(f"{stage}: {name} failed") from e
-
-
-# ---------------------------------------------------------------------------
 # one measured phase
 # ---------------------------------------------------------------------------
 
@@ -204,6 +159,7 @@ def measure(name: str, rows: int, df, check) -> tuple:
     run to zero fallbacks.  Returns (record, first rows)."""
     from spark_rapids_tpu import perfcounters as PC
 
+    print(f"--- {name} explain ---\n{df.explain()}", flush=True)
     snap = PC.snapshot()
     t0 = time.perf_counter()
     first = df.collect()
@@ -229,27 +185,45 @@ def measure(name: str, rows: int, df, check) -> tuple:
     }, first
 
 
-def run_phase(name: str, rows: int, build, check, oracle_pair=None,
-              after=None) -> None:
-    """One measured one-chip phase.  ``oracle_pair`` is ``(build_small,
-    n_small)``: the same query at <= ORACLE_ROWS, device vs row oracle,
-    run first (its compiles are not in the phase's ``compileWall_s``)."""
+def _assert_plan_shape(name: str, root, expect, forbid) -> None:
+    """The executed plan holds every exec class of ``expect`` and none of
+    ``forbid``: a phase must run the shape it is named for."""
+    for cls in expect:
+        assert _find_exec(root, cls) is not None, \
+            f"{name}: no {cls.__name__} in the executed plan\n{root.pretty()}"
+    for cls in forbid:
+        assert _find_exec(root, cls) is None, \
+            f"{name}: {cls.__name__} in the executed plan\n{root.pretty()}"
+
+
+def run_phase(name: str, rows: int, build, check, *, conf=None, small=None,
+              expect=(), forbid=(), after=None) -> None:
+    """One measured one-chip phase, cold: two collects on the device,
+    then the row oracle.  ``small`` is ``(build_small, n_small)`` when the
+    phase's own data is more than the row oracle can take; without it the
+    oracle runs ``build`` itself and is compared with the first collect.
+    ``conf`` adds to BASE_CONF for the device sessions."""
     from spark_rapids_tpu import perfcounters as PC
     from spark_rapids_tpu.session import TpuSession
 
-    if oracle_pair is not None:
-        build_small, n_small = oracle_pair
-        dev_df = build_small(TpuSession(dict(BASE_CONF)))
+    dev_conf = {**BASE_CONF, **(conf or {})}
+    df = build(TpuSession(dict(dev_conf)))
+    rec, dev_rows = measure(name, rows, df, check)
+    root = df._planned()[0]
+    # after the run: adaptive execs print what they decided
+    print(f"--- {name} executed plan ---\n{root.pretty()}", flush=True)
+    _assert_plan_shape(name, root, expect, forbid)
+
+    oracle_build, n_oracle = small if small is not None else (build, rows)
+    if small is not None:
+        dev_df = oracle_build(TpuSession(dict(dev_conf)))
         snap = PC.snapshot()
         dev_rows = dev_df.collect()
-        _assert_device_only(dev_df, PC.since(snap), f"{name}@{n_small}")
-        assert_rows_equal(
-            dev_rows, build_small(TpuSession(dict(ORACLE_CONF))).collect(),
-            f"{name}@{n_small} vs row oracle")
-
-    df = build(TpuSession(dict(BASE_CONF)))
-    rec, _ = measure(name, rows, df, check)
-    rec["oracleRows"] = oracle_pair[1] if oracle_pair else None
+        _assert_device_only(dev_df, PC.since(snap), f"{name}@{n_oracle}")
+    assert_rows_equal(
+        dev_rows, oracle_build(TpuSession(dict(ORACLE_CONF))).collect(),
+        f"{name}@{n_oracle} vs row oracle")
+    rec["oracleRows"] = n_oracle
     if after is not None:
         rec.update(after(df))
     _emit(rec)
@@ -286,6 +260,7 @@ def _write_parquet(path: str, cols: dict, **kw) -> None:
 
 def _phases_q6(n: int, n_small: int, tmp: str) -> None:
     import bench
+    from spark_rapids_tpu.io.scan import TpuFileSourceScanExec
 
     li = bench.make_lineitem(n)
     want = bench.cpu_q6_vectorized(li)
@@ -300,7 +275,8 @@ def _phases_q6(n: int, n_small: int, tmp: str) -> None:
         assert int(rows[0][0]) == want, f"Q6 {rows[0][0]} vs numpy {want}"
 
     run_phase("q6_parquet", n, _q6_parquet_build(big), check_ints,
-              oracle_pair=(_q6_parquet_build(small), n_small))
+              small=(_q6_parquet_build(small), n_small),
+              expect=(TpuFileSourceScanExec,))
 
     def check_dec(rows):
         assert int(rows[0][0].scaleb(4)) == want, \
@@ -314,25 +290,26 @@ def _phases_q6(n: int, n_small: int, tmp: str) -> None:
         return build
 
     run_phase("q6_hot", n, hot(li), check_dec,
-              oracle_pair=(hot(li_small), n_small))
+              small=(hot(li_small), n_small))
 
 
 def _phases_rung2(n: int) -> None:
-    """n <= ORACLE_ROWS: each query's oracle comparison runs on the very
-    data (and programs) of its measured phase."""
+    """n is within the row oracle's reach: each query's oracle comparison
+    runs on the very data of its measured phase."""
     import bench
+    from spark_rapids_tpu.exec.exchange import (
+        TpuBroadcastExchangeExec,
+        TpuShuffleExchangeExec,
+    )
+    from spark_rapids_tpu.exec.fused import TpuWindowChainFusedExec
+    from spark_rapids_tpu.exec.join import (
+        TpuAdaptiveJoinExec,
+        TpuBroadcastHashJoinExec,
+    )
 
     ss = bench.make_store_sales(n)
     dd = bench.make_date_dim()
     sr = bench.make_store_returns(ss, n // 10)
-    builds = {
-        "qa_join_agg": lambda s: bench.build_qa(s, ss, dd),
-        "qb_left_join": lambda s: bench.build_qb(s, ss, sr),
-        "qc_window": lambda s: bench.build_qc(s, ss),
-    }
-    compile_concurrently("compile_rung2", {
-        name: (BASE_CONF, build) for name, build in builds.items()})
-
     want_a = bench.cpu_qa_vectorized(ss, dd)
     want_b = bench.cpu_qb_vectorized(ss, sr)
     want_c = bench.cpu_qc_vectorized(ss)
@@ -350,11 +327,33 @@ def _phases_rung2(n: int) -> None:
                for r in rows}
         assert got == want_c, "qc mismatch vs numpy reference"
 
-    for name, check in (("qa_join_agg", check_qa),
-                        ("qb_left_join", check_qb),
-                        ("qc_window", check_qc)):
-        run_phase(name, n, builds[name], check,
-                  oracle_pair=(builds[name], n))
+    run_phase("qa_join_agg", n, lambda s: bench.build_qa(s, ss, dd),
+              check_qa, expect=(TpuBroadcastExchangeExec,),
+              forbid=(TpuShuffleExchangeExec,))
+    # at the ladder's 20 M rows store_returns (~40 MB) is past
+    # spark.sql.autoBroadcastJoinThreshold and the planner shuffles both
+    # sides; cut to n rows it would be broadcast.  Pin the shape the
+    # phase is named for, as bench.py's out-of-core rung does.
+    run_phase("qb_left_join", n, lambda s: bench.build_qb(s, ss, sr),
+              check_qb,
+              conf={"spark.sql.autoBroadcastJoinThreshold": "-1"},
+              # the adaptive exec wraps the shuffled join;
+              # _joined_shuffled checks what it decided at run time
+              expect=(TpuShuffleExchangeExec, TpuAdaptiveJoinExec),
+              forbid=(TpuBroadcastExchangeExec, TpuBroadcastHashJoinExec),
+              after=_joined_shuffled)
+    run_phase("qc_window", n, lambda s: bench.build_qc(s, ss), check_qc,
+              expect=(TpuWindowChainFusedExec,))
+
+
+def _joined_shuffled(df) -> dict:
+    """qb's adaptive join must have kept the shuffled plan at run time."""
+    from spark_rapids_tpu.exec.join import TpuAdaptiveJoinExec
+
+    aj = _find_exec(df._planned()[0], TpuAdaptiveJoinExec)
+    assert (aj.decision or "").startswith("shuffled"), \
+        f"qb_left_join: the adaptive join decided {aj.decision!r}"
+    return {"joinDecision": aj.decision}
 
 
 def _find_exec(root, cls):
@@ -414,15 +413,14 @@ def _phase_decode(n: int, tmp: str) -> None:
         return {"deviceDecode_s": decode_ns / 1e9,
                 "unpackPrograms": sorted(PD._UNPACK_JITS)}
 
-    # the file is <= ORACLE_ROWS: the oracle comparison IS at full size
-    run_phase("decode", n, build, check, oracle_pair=(build, n),
-              after=after)
+    run_phase("decode", n, build, check,
+              expect=(TpuFileSourceScanExec,), after=after)
 
 
 def run_single_chip(q6_rows: int, small_rows: int) -> None:
     """Every one-chip phase; raises on the first failure.  ``small_rows``
-    (<= ORACLE_ROWS) sizes the rung-2 and decode phases and Q6's oracle
-    pass."""
+    (what the row oracle can take) sizes the rung-2 and decode phases and
+    Q6's oracle pass."""
     from spark_rapids_tpu import native
 
     _emit({"phase": "setup", "device": device_info(),
@@ -509,10 +507,6 @@ def run_mesh(n: int, n_devices: int) -> None:
 
     queries = (("q6", lambda s: bench.build_q6(s, li), check_q6),
                ("grouped_sum_count", grouped, check_g))
-    compile_concurrently("compile_mesh", {
-        f"{name}_{side}": (conf, build)
-        for name, build, _ in queries
-        for side, conf in (("mesh", MESH_CONF), ("single", BASE_CONF))})
     for name, build, check in queries:
         results = {}
         for side, conf in (("mesh", MESH_CONF), ("single", BASE_CONF)):
@@ -526,8 +520,6 @@ def run_mesh(n: int, n_devices: int) -> None:
                 _record_shards(ici, shards)
             else:
                 assert ici is None, f"{name}: mesh exec in the mesh-off plan"
-            print(f"--- {name} [{side}] explain ---\n{df.explain()}",
-                  flush=True)
             snap = PC.snapshot()
             rec, results[side] = measure(f"{name}_{side}", n, df, check)
             d = PC.since(snap)
@@ -562,7 +554,7 @@ def main(argv=None) -> int:
         if args.chips == 4:
             run_mesh(MESH_ROWS, 4)
         else:
-            run_single_chip(Q6_ROWS, RUNG2_ROWS)
+            run_single_chip(Q6_ROWS, SMALL_ROWS)
     except Exception as e:            # the verdict line must still print
         import traceback
 

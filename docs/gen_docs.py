@@ -118,9 +118,13 @@ def gen_configs() -> str:
            "",
            "| Key | Default | Description |",
            "|---|---|---|"]
+    # a default under the checkout reads the same wherever the checkout is
+    checkout = os.path.dirname(HERE)
     for key in sorted(_REGISTRY):
         e = _REGISTRY[key]
         default = getattr(e, "default", None)
+        if isinstance(default, str) and default.startswith(checkout):
+            default = "<checkout>" + default[len(checkout):]
         doc = (e.doc or "").replace("\n", " ")
         out.append(f"| `{key}` | `{default}` | {doc} |")
     out.append("")

@@ -128,11 +128,16 @@ def _leaked_threads(before, grace_s=3.0):
 @pytest.fixture(autouse=True, scope="module")
 def _module_compile_state():
     """ISSUE 23 (tier-1 must reach its end in ONE process): bound what a
-    module leaves behind.  At module teardown the AOT pool is drained
-    and its threads ended (no background XLA compile overlaps a later
-    foreground one), the program registry is emptied and jax's
-    executable caches are dropped, so compiled programs do not pile up
-    across 77 modules."""
+    module leaves behind.  Every live XLA:CPU executable holds ~15-17
+    memory mappings; at ~3,800 of them the process reaches
+    vm.max_map_count (65,530), LLVM's JIT gets "Cannot allocate memory"
+    and the next compile segfaults in backend_compile_and_load — the
+    seed's rc 139 (PR 23: a probe compiling distinct programs died at
+    65,384 mappings, and a full run without the two lines below died at
+    84 %; one without ``shutdown_aot`` reached its end).  So at module
+    teardown the program registry is emptied and jax's executable caches
+    are dropped; the AOT pool is drained and its threads ended too, so
+    no background compile outlives its module."""
     yield
     import jax
 

@@ -16,20 +16,50 @@ def test_chip_smoke_phases_rehearsal_on_cpu(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "require_compiled_kernel",
                         lambda text: None)
     chip_smoke.run_single_chip(20_000, 20_000)
-    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+    out = capsys.readouterr().out
+    lines = [json.loads(ln) for ln in out.splitlines()
              if ln.startswith("{")]
     phases = {ln["phase"]: ln for ln in lines}
-    assert list(phases) == ["setup", "q6_parquet", "q6_hot",
-                            "compile_rung2", "qa_join_agg", "qb_left_join",
-                            "qc_window", "decode"]
-    assert phases["compile_rung2"]["nCompiles"] > 0
+    assert list(phases) == ["setup", "q6_parquet", "q6_hot", "qa_join_agg",
+                            "qb_left_join", "qc_window", "decode"]
     for name, rec in phases.items():
-        if name in ("setup", "compile_rung2"):
+        if name == "setup":
             continue
         assert rec["rows"] == 20_000 and rec["nProgramsLaunched"] > 0
         assert all(rec[k] == 0 for k in chip_smoke.FALLBACK_COUNTERS)
+    # the phase named "shuffled left join" ran one: both sides through an
+    # exchange, and the adaptive join kept the shuffled plan at run time
+    assert phases["qb_left_join"]["joinDecision"].startswith("shuffled")
+    assert "--- qb_left_join executed plan ---" in out
+    assert "TpuShuffleExchange" in out.split(
+        "--- qb_left_join executed plan ---")[1].split("\n{")[0]
     assert phases["decode"]["deviceDecode_s"] > 0
     assert phases["decode"]["unpackPrograms"]
+
+
+def test_chip_smoke_refuses_a_plan_of_another_shape():
+    """A phase whose executed plan is not the shape it names fails: qb
+    planned with the default broadcast threshold is a broadcast join."""
+    import bench
+    import chip_smoke
+    from spark_rapids_tpu.exec.exchange import (
+        TpuBroadcastExchangeExec,
+        TpuShuffleExchangeExec,
+    )
+    from spark_rapids_tpu.session import TpuSession
+
+    ss = bench.make_store_sales(2_000)
+    sr = bench.make_store_returns(ss, 200)
+    root = bench.build_qb(TpuSession(dict(chip_smoke.BASE_CONF)),
+                          ss, sr)._planned()[0]
+    chip_smoke._assert_plan_shape("qb", root, (TpuBroadcastExchangeExec,),
+                                  (TpuShuffleExchangeExec,))
+    with pytest.raises(AssertionError, match="no TpuShuffleExchangeExec"):
+        chip_smoke._assert_plan_shape("qb", root,
+                                      (TpuShuffleExchangeExec,), ())
+    with pytest.raises(AssertionError, match="TpuBroadcastExchangeExec in"):
+        chip_smoke._assert_plan_shape("qb", root, (),
+                                      (TpuBroadcastExchangeExec,))
 
 
 def test_chip_smoke_refuses_to_run_without_a_tpu(capsys):

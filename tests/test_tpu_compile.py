@@ -61,22 +61,30 @@ def _compile(fn, *args):
     return compiled
 
 
-# The TPU compiler unrolls lax.sort (depth ~ log^2 n): every program that
-# holds a multi-operand int64 sort compiles in MINUTES here (PR 23, 8-core
-# sandbox: bounded group-by 477 s and join probe 121 s at 2^25 rows, the
-# ICI epoch program longer still), against a 1470 s budget for the whole
-# suite.  Tier-1 therefore LOWERS those programs for the described chip
-# (tracing + StableHLO for the placed operands: x64, sharding and shape
-# errors) and the full compile is the `slow` case of the same test.
+# Every program that holds a multi-operand int64 sort compiles in
+# MINUTES past the 8,192-row bucket (PR 23, 8-core sandbox, bounded
+# group-by: 10.81 s at 2^13 rows, 187 s at 2^16, 352 s at 2^22, more than
+# 400 s at 2^25; join probe 1.3 s / 72.6 s / 121 s at 2^13 / 2^16 / 2^25;
+# ICI epoch program 11.6 s / 102.9 s at 2^13 / 2^16 and longer than 12 min
+# at 2^25), against a 1470 s budget for the whole suite.  Tier-1 therefore
+# COMPILES all three at 2^16 rows — the first bucket past that cliff, so
+# the compiler takes the sort it takes at real sizes — concurrently in
+# one test, LOWERS them at 2^25 (tracing + StableHLO for the placed
+# operands: x64, sharding and shape errors), and the full compile at
+# 2^25 (does it fit HBM?) is the `slow` case.
+TIER1_ROWS = 1 << 16
+REAL_ROWS = 1 << 25
 SORT_PROGRAM = pytest.mark.parametrize(
     "full_compile", [False, pytest.param(True, marks=pytest.mark.slow)],
     ids=["lower", "compile"])
 
 
-def _lower_or_compile(full_compile, fn, *args):
+def _lower_or_compile(full_compile, lowered):
     if full_compile:
-        return _compile(fn, *args).as_text()
-    return fn.lower(*args).as_text()
+        compiled = lowered.compile()
+        print(compiled.memory_analysis())
+        return compiled.as_text()
+    return lowered.as_text()
 
 
 def _long_df(session, n=64, **cols):
@@ -86,6 +94,69 @@ def _long_df(session, n=64, **cols):
     rng = np.random.default_rng(23)
     data = {name: rng.integers(0, hi, n) for name, hi in cols.items()}
     return bench._df(session, data, [T.LONG] * len(data))
+
+
+def _lower_group_by(cap, one_chip, bounded):
+    """The grouped-aggregate program the engine runs on a ``cap``-row
+    batch: the bounded-cardinality one past the groups-cap conf, the
+    full-width one at or below it."""
+    from spark_rapids_tpu.compilecache.aot import dummy_batch_args
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.session import TpuSession, count_, sum_
+
+    s = TpuSession({"spark.rapids.sql.enabled": True})
+    df = _long_df(s, k=50, v=100).group_by("k").agg(
+        sum_("v", "s"), count_(None, "c"))
+    agg = _find_exec(df._planned()[0], TpuHashAggregateExec)
+    bound = agg._bounded_groups_cap(cap)
+    assert bool(bound) == bounded, f"groups cap {bound} at {cap} rows"
+    jitted, _ = agg._agg_program(bound)[1]()
+    return jitted.lower(*_placed(
+        dummy_batch_args(agg.input_schema, cap), one_chip))
+
+
+def _lower_sort_merge_join_probe(cap, one_chip):
+    from spark_rapids_tpu.compilecache.aot import (
+        abstract_array,
+        abstract_scalar,
+        dummy_columns,
+    )
+    from spark_rapids_tpu.exec.join import TpuAdaptiveJoinExec
+    from spark_rapids_tpu.session import TpuSession
+
+    s = TpuSession({"spark.rapids.sql.enabled": True,
+                    "spark.sql.autoBroadcastJoinThreshold": "-1"})
+    df = _long_df(s, k=50, v=100).join(_long_df(s, k=50, w=100), on=["k"])
+    join = _find_exec(df._planned()[0], TpuAdaptiveJoinExec).shuffled
+    pschema = join._probe_child().output
+    args = ((abstract_array((cap,), jnp.int64),),
+            abstract_scalar(jnp.int32),
+            dummy_columns(pschema, cap),
+            abstract_scalar(jnp.int32))
+    return jax.jit(join._probe_fn(pschema)).lower(*_placed(args, one_chip))
+
+
+def _lower_ici_epoch(cap, topo):
+    """The epoch program of TpuIciShuffleAggExec — local partial
+    aggregate, murmur3 all-to-all over ICI, merge — on a 4-device mesh of
+    the described chips, ``cap`` rows over the mesh."""
+    from spark_rapids_tpu.compilecache.aot import dummy_batch_args
+    from spark_rapids_tpu.exec.ici import TpuIciShuffleAggExec
+    from spark_rapids_tpu.session import TpuSession, count_, sum_
+
+    s = TpuSession({"spark.rapids.sql.enabled": True,
+                    "spark.rapids.shuffle.mode": "ICI",
+                    "spark.rapids.tpu.mesh.enabled": True})
+    df = _long_df(s, k=37, v=1000).group_by("k").agg(
+        sum_("v", "s"), count_(None, "c"))
+    ici = _find_exec(df._planned()[0], TpuIciShuffleAggExec)
+    assert ici is not None, df.explain()
+    ici.mesh = Mesh(np.array(topo.devices[:4]), (ici.axis,))
+    rows = NamedSharding(ici.mesh, P(ici.axis))
+    cols, num_rows = dummy_batch_args(ici.children[0].output, cap)
+    return ici._build_epoch_program(first=True).lower(
+        _placed(cols, rows),
+        _placed(num_rows, NamedSharding(ici.mesh, P())))
 
 
 # expect trouble from the (_TILE, 128) uint32 blocks and from x64 grid
@@ -115,70 +186,50 @@ def test_fused_q6_stage_compiles_at_2_26_rows(one_chip):
     _compile(jax.jit(q6_step), *args)
 
 
+def test_sort_programs_compile_for_v5e_at_2_16_rows(topo, one_chip):
+    """XLA:TPU itself (not only the lowering) accepts the three
+    sort-bearing programs; traced here, compiled on three threads (the
+    compiler releases the GIL, the wall is the slowest of them)."""
+    import time
+    from concurrent.futures import ThreadPoolExecutor
+
+    lowered = {
+        "group_by": _lower_group_by(TIER1_ROWS, one_chip, bounded=False),
+        "sort_merge_join_probe":
+            _lower_sort_merge_join_probe(TIER1_ROWS, one_chip),
+        "ici_epoch_4_chips": _lower_ici_epoch(TIER1_ROWS, topo),
+    }
+
+    def compile_timed(low):
+        t0 = time.perf_counter()
+        return low.compile(), time.perf_counter() - t0
+
+    with ThreadPoolExecutor(len(lowered)) as pool:
+        jobs = {name: pool.submit(compile_timed, low)
+                for name, low in lowered.items()}
+        done = {name: job.result() for name, job in jobs.items()}
+    for name, (compiled, seconds) in done.items():
+        print(f"{name}: compiled in {seconds:.1f} s\n"
+              f"{compiled.memory_analysis()}")
+    assert "sort" in done["group_by"][0].as_text()
+    ici_text = done["ici_epoch_4_chips"][0].as_text()
+    assert "all-to-all" in ici_text or "all_to_all" in ici_text
+
+
 @SORT_PROGRAM
 def test_bounded_group_by_for_v5e_at_2_25_rows(one_chip, full_compile):
-    from spark_rapids_tpu.compilecache.aot import dummy_batch_args
-    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
-    from spark_rapids_tpu.session import TpuSession, count_, sum_
-
-    s = TpuSession({"spark.rapids.sql.enabled": True})
-    df = _long_df(s, k=50, v=100).group_by("k").agg(
-        sum_("v", "s"), count_(None, "c"))
-    agg = _find_exec(df._planned()[0], TpuHashAggregateExec)
-    cap = 1 << 25
-    bound = agg._bounded_groups_cap(cap)
-    assert bound, "the bounded-cardinality ladder does not apply"
-    jitted, _ = agg._agg_program(bound)[1]()
-    text = _lower_or_compile(full_compile, jitted, *_placed(
-        dummy_batch_args(agg.input_schema, cap), one_chip))
+    text = _lower_or_compile(
+        full_compile, _lower_group_by(REAL_ROWS, one_chip, bounded=True))
     assert "sort" in text
 
 
 @SORT_PROGRAM
 def test_sort_merge_join_probe_for_v5e_at_2_25_rows(one_chip, full_compile):
-    from spark_rapids_tpu.compilecache.aot import (
-        abstract_array,
-        abstract_scalar,
-        dummy_columns,
-    )
-    from spark_rapids_tpu.exec.join import TpuAdaptiveJoinExec
-    from spark_rapids_tpu.session import TpuSession
-
-    s = TpuSession({"spark.rapids.sql.enabled": True,
-                    "spark.sql.autoBroadcastJoinThreshold": "-1"})
-    df = _long_df(s, k=50, v=100).join(_long_df(s, k=50, w=100), on=["k"])
-    join = _find_exec(df._planned()[0], TpuAdaptiveJoinExec).shuffled
-    cap = 1 << 25
-    pschema = join._probe_child().output
-    args = ((abstract_array((cap,), jnp.int64),),
-            abstract_scalar(jnp.int32),
-            dummy_columns(pschema, cap),
-            abstract_scalar(jnp.int32))
-    _lower_or_compile(full_compile, jax.jit(join._probe_fn(pschema)),
-                      *_placed(args, one_chip))
+    _lower_or_compile(
+        full_compile, _lower_sort_merge_join_probe(REAL_ROWS, one_chip))
 
 
 @SORT_PROGRAM
 def test_ici_hash_repartition_for_four_v5e_chips(topo, full_compile):
-    """The epoch program of TpuIciShuffleAggExec — local partial
-    aggregate, murmur3 all-to-all over ICI, merge — on a 4-device mesh of
-    the described chips, 2^25 rows over the mesh."""
-    from spark_rapids_tpu.compilecache.aot import dummy_batch_args
-    from spark_rapids_tpu.exec.ici import TpuIciShuffleAggExec
-    from spark_rapids_tpu.session import TpuSession, count_, sum_
-
-    s = TpuSession({"spark.rapids.sql.enabled": True,
-                    "spark.rapids.shuffle.mode": "ICI",
-                    "spark.rapids.tpu.mesh.enabled": True})
-    df = _long_df(s, k=37, v=1000).group_by("k").agg(
-        sum_("v", "s"), count_(None, "c"))
-    ici = _find_exec(df._planned()[0], TpuIciShuffleAggExec)
-    assert ici is not None, df.explain()
-    ici.mesh = Mesh(np.array(topo.devices[:4]), (ici.axis,))
-    rows = NamedSharding(ici.mesh, P(ici.axis))
-    cols, num_rows = dummy_batch_args(ici.children[0].output, 1 << 25)
-    program = ici._build_epoch_program(first=True)
-    text = _lower_or_compile(
-        full_compile, program, _placed(cols, rows),
-        _placed(num_rows, NamedSharding(ici.mesh, P())))
+    text = _lower_or_compile(full_compile, _lower_ici_epoch(REAL_ROWS, topo))
     assert "all-to-all" in text or "all_to_all" in text
