@@ -228,15 +228,17 @@ def cached_program(key_parts, factory: Callable[[], Tuple[Any, Any]],
                             created_out=created_out)
 
 
-def cached_jit_program(key_parts, builder, label: str = "", **jit_kwargs):
+def cached_jit_program(key_parts, builder, label: str = "",
+                       name: Optional[str] = None, **jit_kwargs):
     """The shared exec-layer wrapper most call sites want: a ``tpu_jit``
     of ``builder`` shared through the registry when ``key_parts`` is
-    fingerprintable, instance-private otherwise.  Returns the jitted
-    callable."""
+    fingerprintable, instance-private otherwise.  ``name`` is the
+    program's name in a trace (``tpu_jit``), no part of the key.
+    Returns the jitted callable."""
     from spark_rapids_tpu.perfcounters import tpu_jit
 
     if key_parts is None:
-        return tpu_jit(builder, **jit_kwargs)
+        return tpu_jit(builder, name, **jit_kwargs)
     return cached_program(
-        key_parts, lambda: (tpu_jit(builder, **jit_kwargs), None),
+        key_parts, lambda: (tpu_jit(builder, name, **jit_kwargs), None),
         label=label).jitted

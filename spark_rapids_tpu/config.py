@@ -874,12 +874,6 @@ UDF_COMPILER_ENABLED = conf("spark.rapids.sql.udfCompiler.enabled").doc(
     "reference's bytecode decompiler); untranslatable functions keep the "
     "arrow-eval path.").boolean_conf(True)
 
-PROFILE_ENABLED = conf("spark.rapids.profile.enabled").doc(
-    "Wrap every operator's per-batch work in jax.profiler TraceAnnotations "
-    "so XProf/Perfetto timelines attribute device time to plan operators "
-    "(the NVTX-ranges analog; reference: nvtx_profiling.md + the CUPTI "
-    "profiler module).").boolean_conf(False)
-
 SHUFFLE_MODE = conf("spark.rapids.shuffle.mode").doc(
     "MULTITHREADED (serialize batches host-side, concat-friendly Kudo-style "
     "format), ICI (device-resident all-to-all over the TPU interconnect via "

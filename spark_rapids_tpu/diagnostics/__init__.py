@@ -52,6 +52,9 @@ def _stamp_unrecorded(root, keep_qid=None) -> None:
                 and getattr(node, "_diag_qid", None) == keep_qid):
             node._diag_qid = "(unrecorded)"
             node._diag_path = None
+            for inner in node.inner_execs():
+                inner._diag_qid = "(unrecorded)"
+                inner._diag_path = None
         for c in node.children:
             if isinstance(c, TpuExec):
                 walk(c)

@@ -182,7 +182,7 @@ class QueryDiagnostics:
         overwrites stale paths a previous query's recorder left behind."""
         from spark_rapids_tpu.exec.base import TpuExec
 
-        def walk(node, path):
+        def walk(node, path, descend=True):
             node._diag_path = path
             node._diag_qid = self.query_id
             cal = _cal_key_of(node)
@@ -195,9 +195,16 @@ class QueryDiagnostics:
                     self.ops[path].cal_op, self.ops[path].cal_fp = cal
                 self._metric_base[path] = {
                     m.name: m.value for m in node.metrics.values()}
+            if not descend:
+                return
             for i, c in enumerate(node.children):
                 if isinstance(c, TpuExec):
                     walk(c, f"{path}.{i}")
+            # an exec the node runs without holding it as a child gets a
+            # stable path too, not a +N of the run; its children are the
+            # node's own and are walked above
+            for k, inner in enumerate(node.inner_execs()):
+                walk(inner, f"{path}.i{k}", descend=False)
 
         walk(root, "0")
 
@@ -548,7 +555,7 @@ class QueryDiagnostics:
                 self.closed = True
         self.total = {k: cur[k] - self.snap0.get(k, 0) for k in cur}
         if root is not None:
-            def walk(node):
+            def walk(node, descend=True):
                 path = getattr(node, "_diag_path", None)
                 st = self.ops.get(path)
                 if st is not None \
@@ -559,9 +566,13 @@ class QueryDiagnostics:
                         for m in node.metrics.values()
                         if m.value - base.get(m.name, 0)}
                     st.fallback = bool(st.metrics.get("runtimeFallbacks"))
+                if not descend:
+                    return
                 for c in node.children:
                     if isinstance(c, TpuExec):
                         walk(c)
+                for inner in node.inner_execs():
+                    walk(inner, descend=False)
 
             walk(root)
         with self._lock:
@@ -573,6 +584,14 @@ class QueryDiagnostics:
                 dot = path.rfind(".")
                 if dot > 0:
                     parent = path[:dot]
+                    inner = self.ops.get(parent + ".i0")
+                    if path[dot + 1] != "i" and inner is not None \
+                            and inner.wall_ns:
+                        # the node's children were pulled by the exec
+                        # that ran inside it (register_root); where that
+                        # one never ran (the adaptive join's broadcast
+                        # branch) they stay the node's own
+                        parent += ".i0"
                     child_wall[parent] = child_wall.get(parent, 0) \
                         + st.wall_ns
             for path in self._op_order:

@@ -578,7 +578,7 @@ def analyze_tree(root, diag, meta=None,
                      "enabled=true for counter deltas — showing operator "
                      "metrics only)")
 
-    def annotate(node, indent):
+    def annotate(node, indent, descend=True):
         st = None
         if diag is not None \
                 and getattr(node, "_diag_qid", None) == diag.query_id:
@@ -619,6 +619,11 @@ def analyze_tree(root, diag, meta=None,
         if parts:
             s += "  [" + ", ".join(parts) + "]"
         lines.append(s)
+        if not descend:
+            return
+        # the exec that ran inside this node, then the children they share
+        for inner in node.inner_execs():
+            annotate(inner, indent + 1, descend=False)
         for c in node.children:
             if isinstance(c, TpuExec):
                 annotate(c, indent + 1)

@@ -657,7 +657,7 @@ class TpuHashAggregateExec(TpuExec):
             def fn(cols, num_rows, _b=groups_cap):
                 return clone._agg_fn(cols, num_rows, groups_cap=_b)
 
-            return tpu_jit(fn), None
+            return tpu_jit(fn, "agg_groups_cap"), None
 
         return key_parts, factory
 

@@ -10,10 +10,10 @@ Metrics mirror the reference's standard names (GpuMetric / GpuTaskMetrics):
 opTime, numOutputRows, numOutputBatches, sortTime, joinTime, concatTime,
 semaphoreWaitTime, spillTime, retryCount — surfaced via .metrics and the
 explain output.
-Tracing (SURVEY.md §5.1): with ``spark.rapids.profile.enabled`` every
-operator's batch iteration is wrapped in a ``jax.profiler.TraceAnnotation``
-named after the operator — the NVTX-range analog, visible in XProf /
-Perfetto captures via ``jax.profiler.trace``.
+Tracing (SURVEY.md §5.1): every batch pull of every operator runs under
+the ``srt.op.<node_name>`` span of ``perfcounters.span`` (exec/runtime.py)
+— the NVTX-range analog: a ``jax.profiler.TraceAnnotation`` while a
+profiler session is on, and a row of the folded span table always.
 """
 from __future__ import annotations
 
@@ -55,16 +55,6 @@ class TpuMetric:
 
     def timed(self):
         return TpuMetric._Timer(self)
-
-
-def enable_operator_tracing(root: "TpuExec", on: bool = True) -> None:
-    """Mark an exec tree for jax.profiler TraceAnnotations (driven by
-    spark.rapids.profile.enabled; scoped per plan, not process-global, so
-    concurrent sessions with different settings do not interfere)."""
-    root._trace_on = on
-    for c in root.children:
-        if isinstance(c, TpuExec):
-            enable_operator_tracing(c, on)
 
 
 class _SchemaOnlyExec:
@@ -137,6 +127,13 @@ class TpuExec:
 
     def describe(self) -> str:
         return self.node_name
+
+    def inner_execs(self) -> Sequence["TpuExec"]:
+        """Execs this node runs that are not among its children (the
+        planned join inside TpuAdaptiveJoinExec, whose children are this
+        node's own): the recorder registers them under a stable path,
+        ``<path>.i<k>``, and explain("analyze") shows them."""
+        return ()
 
     def pretty(self, indent: int = 0) -> str:
         s = "  " * indent + self.describe()

@@ -14,7 +14,6 @@ this into an XLA all-to-all (parallel/).
 """
 from __future__ import annotations
 
-import time
 from typing import Iterator, List, Tuple
 
 import jax
@@ -34,6 +33,10 @@ from spark_rapids_tpu.plan.nodes import (
     RoundRobinPartitioning,
     SinglePartitioning,
 )
+
+
+# what jax calls an exchange program in a trace, by its registry kind
+_PROGRAM_NAMES = {"partsort": "partition"}
 
 
 class TpuShuffleExchangeExec(TpuExec):
@@ -114,8 +117,10 @@ class TpuShuffleExchangeExec(TpuExec):
                 cached_jit_program,
             )
 
-            jitted = cached_jit_program(self._registry_scope(kind),
-                                        builder, label=f"exchange:{kind}")
+            jitted = cached_jit_program(
+                self._registry_scope(kind), builder,
+                label=f"exchange:{kind}",
+                name="exchange_" + _PROGRAM_NAMES.get(kind, kind))
             setattr(self, attr, jitted)
         return jitted
 
@@ -137,47 +142,50 @@ class TpuShuffleExchangeExec(TpuExec):
         if isinstance(p, SinglePartitioning) or self.num_partitions == 1:
             yield 0, batch
             return
-        t0 = time.perf_counter_ns()
-        if isinstance(p, HashPartitioning):
-            ids = self._hash_ids(batch)
-        elif isinstance(p, RoundRobinPartitioning):
-            ids = (jnp.arange(batch.capacity, dtype=jnp.int32)
-                   % self.num_partitions)
-        elif isinstance(p, RangePartitioning):
-            ids = self._range_ids(batch)
-        else:
-            raise NotImplementedError(type(p).__name__)
-        # ONE device program: stable-sort rows by partition id; each
-        # partition is then a contiguous range (searchsorted bounds since
-        # ids are sorted).  One host sync for the boundary vector instead of
-        # num_partitions sequential compactions.
-        n_parts = self.num_partitions
-        schema = batch.schema   # capture only the schema, not the batch
+        # the partition-id and sort programs and the one sync for the
+        # bounds; closed before the first slice is yielded
+        with PC.span("srt.exchange.partition",
+                     feeds="exchange_partition_ns") as part:
+            if isinstance(p, HashPartitioning):
+                ids = self._hash_ids(batch)
+            elif isinstance(p, RoundRobinPartitioning):
+                ids = (jnp.arange(batch.capacity, dtype=jnp.int32)
+                       % self.num_partitions)
+            elif isinstance(p, RangePartitioning):
+                ids = self._range_ids(batch)
+            else:
+                raise NotImplementedError(type(p).__name__)
+            # ONE device program: stable-sort rows by partition id; each
+            # partition is then a contiguous range (searchsorted bounds
+            # since ids are sorted).  One host sync for the boundary
+            # vector instead of num_partitions sequential compactions.
+            n_parts = self.num_partitions
+            schema = batch.schema   # capture only the schema, not the batch
 
-        def sort_fn(cols, ids, num_rows):
-            b = ColumnarBatch(list(cols), num_rows, schema)
-            cap = b.capacity
-            key = jnp.where(b.row_mask, ids.astype(jnp.int32), n_parts)
-            perm = jax.lax.sort(
-                (key, jnp.arange(cap, dtype=jnp.int32)),
-                num_keys=1, is_stable=True)[1]
-            from spark_rapids_tpu.ops.filterops import gather_columns
+            def sort_fn(cols, ids, num_rows):
+                b = ColumnarBatch(list(cols), num_rows, schema)
+                cap = b.capacity
+                key = jnp.where(b.row_mask, ids.astype(jnp.int32), n_parts)
+                perm = jax.lax.sort(
+                    (key, jnp.arange(cap, dtype=jnp.int32)),
+                    num_keys=1, is_stable=True)[1]
+                from spark_rapids_tpu.ops.filterops import gather_columns
 
-            sorted_cols = gather_columns(perm, b.row_mask[perm], b.columns)
-            sorted_key = key[perm]
-            bounds = jnp.searchsorted(
-                sorted_key, jnp.arange(n_parts + 1, dtype=jnp.int32),
-                side="left").astype(jnp.int32)
-            return tuple(sorted_cols), bounds
+                sorted_cols = gather_columns(perm, b.row_mask[perm],
+                                             b.columns)
+                sorted_key = key[perm]
+                bounds = jnp.searchsorted(
+                    sorted_key, jnp.arange(n_parts + 1, dtype=jnp.int32),
+                    side="left").astype(jnp.int32)
+                return tuple(sorted_cols), bounds
 
-        cols, bounds = self._cached_jit("_sort_jit", "partsort", sort_fn)(
-            tuple(batch.columns), ids, jnp.int32(batch.num_rows))
-        import numpy as _np
+            cols, bounds = self._cached_jit(
+                "_sort_jit", "partsort", sort_fn)(
+                tuple(batch.columns), ids, jnp.int32(batch.num_rows))
+            import numpy as _np
 
-        bounds_np = _np.asarray(bounds).tolist()   # one transfer
-        dt = time.perf_counter_ns() - t0
-        PC.bump("exchange_partition_ns", dt)
-        self.metric("exchangePartitionTime").add(dt)
+            bounds_np = _np.asarray(bounds).tolist()   # one transfer
+        self.metric("exchangePartitionTime").add(part.ns)
         sorted_batch = ColumnarBatch(list(cols), batch.num_rows, schema)
         for pid in range(n_parts):
             lo, hi = bounds_np[pid], bounds_np[pid + 1]

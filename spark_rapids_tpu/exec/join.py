@@ -21,7 +21,7 @@ from __future__ import annotations
 from typing import Iterator, List, Optional, Tuple
 
 import jax
-from spark_rapids_tpu.perfcounters import tpu_jit
+from spark_rapids_tpu.perfcounters import span, tpu_jit
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
@@ -36,6 +36,16 @@ from spark_rapids_tpu.expr.base import EvalContext, Expression
 from spark_rapids_tpu.ops.filterops import compact_columns, gather_columns
 from spark_rapids_tpu.ops.sortkeys import _column_key_words
 from spark_rapids_tpu.plan.nodes import JoinType
+
+
+# what jax calls a join program in a trace, by the first part of its
+# registry key (the key itself stays as it is: it is fingerprinted)
+_PROGRAM_NAMES = {"mat": "materialize"}
+
+
+def _program_name(key) -> str:
+    kind = key if isinstance(key, str) else key[0]
+    return "join_" + _PROGRAM_NAMES.get(kind, kind)
 
 
 def _lex_less(a_words: List[jax.Array], b_words: List[jax.Array],
@@ -236,7 +246,8 @@ class _BaseTpuJoinExec(TpuExec):
             scope = None if unsafe else self._registry_scope()
             self._jit_cache[key] = cached_jit_program(
                 None if scope is None else scope + (key,), builder,
-                label=f"{type(self).__name__}:{key}", **jit_kw)
+                label=f"{type(self).__name__}:{key}",
+                name=_program_name(key), **jit_kw)
         return self._jit_cache[key]
 
     @property
@@ -257,27 +268,28 @@ class _BaseTpuJoinExec(TpuExec):
         program in selection-mask mode: filtered rows sort to the invalid
         tail and are never probed — the stage costs no extra launch and no
         compaction scatter."""
-        schema = in_schema or batch.schema
-        fn = self._build_fn(schema, keys, pre_ops)
-        if pre_ops is None:
-            jitted = self._cached_jit(self._build_key(schema), fn)
-            words, row_index, n_valid = jitted(tuple(batch.columns),
-                                               jnp.int32(batch.num_rows))
-            return _SortedBuildSide(words, row_index, n_valid, batch)
-        from spark_rapids_tpu.compilecache.keys import (
-            schema_fp,
-            stage_ops_fp,
-        )
+        with span("srt.join.build"):
+            schema = in_schema or batch.schema
+            fn = self._build_fn(schema, keys, pre_ops)
+            if pre_ops is None:
+                jitted = self._cached_jit(self._build_key(schema), fn)
+                words, row_index, n_valid = jitted(tuple(batch.columns),
+                                                   jnp.int32(batch.num_rows))
+                return _SortedBuildSide(words, row_index, n_valid, batch)
+            from spark_rapids_tpu.compilecache.keys import (
+                schema_fp,
+                stage_ops_fp,
+            )
 
-        ops_fp = stage_ops_fp(pre_ops)
-        jitted = self._cached_jit(
-            ("build_preops", ops_fp, schema_fp(schema)), fn,
-            unsafe=ops_fp is None)
-        words, row_index, n_valid, bcols = jitted(
-            tuple(batch.columns), jnp.int32(batch.num_rows))
-        out_batch = ColumnarBatch(list(bcols), batch.num_rows,
-                                  self._build_child().output)
-        return _SortedBuildSide(words, row_index, n_valid, out_batch)
+            ops_fp = stage_ops_fp(pre_ops)
+            jitted = self._cached_jit(
+                ("build_preops", ops_fp, schema_fp(schema)), fn,
+                unsafe=ops_fp is None)
+            words, row_index, n_valid, bcols = jitted(
+                tuple(batch.columns), jnp.int32(batch.num_rows))
+            out_batch = ColumnarBatch(list(bcols), batch.num_rows,
+                                      self._build_child().output)
+            return _SortedBuildSide(words, row_index, n_valid, out_batch)
 
     def _build_key(self, schema):
         from spark_rapids_tpu.compilecache.keys import schema_fp
@@ -349,10 +361,11 @@ class _BaseTpuJoinExec(TpuExec):
         return ("probe", schema_fp(schema))
 
     def _probe_counts(self, build: _SortedBuildSide, batch: ColumnarBatch):
-        jitted = self._cached_jit(self._probe_key(batch.schema),
-                                  self._probe_fn(batch.schema))
-        return jitted(tuple(build.words), build.n_valid,
-                      tuple(batch.columns), jnp.int32(batch.num_rows))
+        with span("srt.join.probe"):
+            jitted = self._cached_jit(self._probe_key(batch.schema),
+                                      self._probe_fn(batch.schema))
+            return jitted(tuple(build.words), build.n_valid,
+                          tuple(batch.columns), jnp.int32(batch.num_rows))
 
     # -- materialization (gather maps -> output batch) -------------------
     @staticmethod
@@ -395,21 +408,24 @@ class _BaseTpuJoinExec(TpuExec):
     def _materialize(self, build: _SortedBuildSide, probe: ColumnarBatch,
                      lo, counts, total_host: int, unmatched,
                      with_unmatched_probe: bool, unmatched_host: int):
-        out_rows = total_host + (unmatched_host if with_unmatched_probe else 0)
-        out_cap = round_up_bucket(max(out_rows, 1), DEFAULT_ROW_BUCKETS)
+        with span("srt.join.materialize"):
+            out_rows = total_host + (
+                unmatched_host if with_unmatched_probe else 0)
+            out_cap = round_up_bucket(max(out_rows, 1), DEFAULT_ROW_BUCKETS)
 
-        def fn(bwords_row_index, b_cols, p_cols, lo, counts, unmatched,
-               total, nrows):
-            return _BaseTpuJoinExec.materialize_pairs(
-                bwords_row_index, b_cols, p_cols, lo, counts, unmatched,
-                total, nrows, out_cap, with_unmatched_probe)
+            def fn(bwords_row_index, b_cols, p_cols, lo, counts, unmatched,
+                   total, nrows):
+                return _BaseTpuJoinExec.materialize_pairs(
+                    bwords_row_index, b_cols, p_cols, lo, counts, unmatched,
+                    total, nrows, out_cap, with_unmatched_probe)
 
-        jitted = self._cached_jit(("mat", out_cap, with_unmatched_probe), fn)
-        lcols, bcols = jitted(build.row_index,
-                              tuple(build.batch.columns),
-                              tuple(probe.columns), lo, counts, unmatched,
-                              jnp.int64(total_host), jnp.int64(out_rows))
-        return lcols, bcols, out_rows
+            jitted = self._cached_jit(
+                ("mat", out_cap, with_unmatched_probe), fn)
+            lcols, bcols = jitted(build.row_index,
+                                  tuple(build.batch.columns),
+                                  tuple(probe.columns), lo, counts, unmatched,
+                                  jnp.int64(total_host), jnp.int64(out_rows))
+            return lcols, bcols, out_rows
 
     def _semi_anti(self, probe: ColumnarBatch, counts, anti: bool):
         schema = probe.schema   # never capture the device batch itself
@@ -480,7 +496,9 @@ class _BaseTpuJoinExec(TpuExec):
                 return [dummy_batch_args(_schema, _cap)]
 
             out.append(AotProgram(
-                scope + (key,), lambda _fn=fn: (tpu_jit(_fn), None),
+                scope + (key,),
+                lambda _fn=fn, _name=_program_name(key): (
+                    tpu_jit(_fn, _name), None),
                 b_args, f"join-build:{self.describe()[:40]}"))
         pcaps = batch_caps(pchild)
         if bcap is not None and pcaps \
@@ -511,7 +529,9 @@ class _BaseTpuJoinExec(TpuExec):
                 return sets
 
             out.append(AotProgram(
-                scope + (key,), lambda _fn=fn: (tpu_jit(_fn), None),
+                scope + (key,),
+                lambda _fn=fn, _name=_program_name(key): (
+                    tpu_jit(_fn, _name), None),
                 p_args, f"join-probe:{self.describe()[:40]}"))
         return out
 
@@ -931,6 +951,9 @@ class TpuAdaptiveJoinExec(TpuExec):
     def output(self):
         return self.shuffled.output
 
+    def inner_execs(self):
+        return (self.shuffled,)
+
     def describe(self):
         d = f" decided={self.decision}" if self.decision else ""
         return (f"TpuAdaptiveJoin(threshold={self.threshold})"
@@ -960,6 +983,9 @@ class TpuAdaptiveJoinExec(TpuExec):
             yield from bj.execute_columnar()
             return
         self.decision = f"shuffled({size}B)"
+        # the inner join is the operator that runs: its metrics are this
+        # node's, as the broadcast branch's are
+        self.metrics.update(self.shuffled.metrics)
         # the replay child is single-shot (handles close as they re-emit):
         # restore the real build subtree afterwards so a REPEATED execute
         # of this plan re-materializes instead of replaying closed handles

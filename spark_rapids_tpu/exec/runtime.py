@@ -44,11 +44,14 @@ strictly-fewer-calls pin in tests/test_operator_runtime.py:
   (resilience/domain.py) drives the operator's raw iterator:
   classification, bounded transient/OOM restarts, runtime CPU
   fallback, breaker recording, chaos hooks.
-* ``trace`` — innermost: with ``spark.rapids.profile.enabled`` each
-  pull runs under a jax.profiler.TraceAnnotation named after the
-  operator; the check happens once per iterator start (so a fault-
-  domain restart re-reads it), and the untraced path adds ZERO frames
-  (the raw generator is returned as-is, not delegated to).
+
+The ``srt.op.<node_name>`` span (``perfcounters.span``) is part of the
+loop, not a concern with an ambient switch: it opens around every
+``next(it)`` of the fault domain's iterator and closes before the batch
+is yielded, so no span is ever held across a ``yield`` and a retry's
+time lies inside the operator that retried.  It puts the operator on
+the profiler's clock and into the folded span table; the recorder's
+``begin_op`` / ``end_op`` are called at the same point.
 
 Docs: docs/whole_plan_fusion.md (the runtime dispatch contract).
 """
@@ -61,6 +64,7 @@ from typing import Callable, Optional
 from spark_rapids_tpu.diagnostics import context as _DIAG
 from spark_rapids_tpu.governor import context as _GOV
 from spark_rapids_tpu.lifecycle.context import CURRENT as _QCTX
+from spark_rapids_tpu.perfcounters import span as _span
 from spark_rapids_tpu.progress import context as _PROG
 
 
@@ -95,8 +99,6 @@ CONCERNS = (
             lambda: _DIAG.RECORDER),
     Concern("fault_domain", "iterator",
             "classification / retries / CPU fallback / breaker"),
-    Concern("trace", "iterator",
-            "jax.profiler.TraceAnnotation per pull when enabled"),
 )
 
 # the runtime loop's probes, bound once from the registry: dispatch
@@ -107,51 +109,17 @@ _AMBIENT_PROGRESS = CONCERNS[2].ambient
 _AMBIENT_DIAGNOSTICS = CONCERNS[3].ambient
 
 
-def _trace_pulls(op, raw_fn, a, kw):
-    """The enabled-trace inner iterator: each pull of the operator's raw
-    generator runs under a TraceAnnotation (NvtxRange analog)."""
-    import jax.profiler
-
-    it = raw_fn(op, *a, **kw)
-    name = op.node_name
-    try:
-        while True:
-            with jax.profiler.TraceAnnotation(name):
-                try:
-                    b = next(it)
-                except StopIteration:
-                    return
-            yield b
-    finally:
-        close = getattr(it, "close", None)
-        if close is not None:  # the raw iterator need not be a generator
-            close()
-
-
-def _traced_start(raw_fn):
-    """The ``trace`` concern: returns the function the fault domain
-    (re)starts.  Untraced operators get the RAW generator — no
-    delegating frame — and the ``_trace_on`` flag is re-read on every
-    (re)start, matching the pre-unification wrapper."""
-
-    def start(op, *a, **kw):
-        if getattr(op, "_trace_on", False):
-            return _trace_pulls(op, raw_fn, a, kw)
-        return raw_fn(op, *a, **kw)
-
-    return start
-
-
 def make_operator_runtime(raw_fn):
     """Wrap a subclass's raw ``execute_columnar`` in the unified
     runtime (installed by ``TpuExec.__init_subclass__``)."""
-    inner_fn = _traced_start(raw_fn)
-
     @functools.wraps(raw_fn)
     def execute_columnar(self, *a, **kw):
         from spark_rapids_tpu.resilience.domain import run_fault_domain
 
-        it = run_fault_domain(self, inner_fn, a, kw)
+        it = run_fault_domain(self, raw_fn, a, kw)
+        # one span object for all pulls of this iterator: opened around
+        # next(it) and closed again before every yield
+        sp = _span("srt.op." + self.node_name)
         try:
             while True:
                 # -- per-pull concerns, in CONCERNS order ------------
@@ -165,10 +133,11 @@ def make_operator_runtime(raw_fn):
                 rec = _AMBIENT_DIAGNOSTICS()
                 if trk is None and rec is None:
                     # disabled fast path: four ambient checks, one pull
-                    try:
-                        b = next(it)
-                    except StopIteration:
-                        return
+                    with sp:
+                        try:
+                            b = next(it)
+                        except StopIteration:
+                            return
                     yield b
                     continue
                 h = trk.begin_pull(self) if trk is not None else None
@@ -179,7 +148,8 @@ def make_operator_runtime(raw_fn):
                 try:
                     try:
                         try:
-                            b = next(it)
+                            with sp:
+                                b = next(it)
                             rows = b.num_rows
                         except StopIteration:
                             done = True

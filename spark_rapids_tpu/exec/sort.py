@@ -89,7 +89,7 @@ class TpuSortExec(TpuExec):
                 out = _gather_batch(batch, perm, num_rows, schema)
                 return tuple(out.columns)
 
-            return tpu_jit(fn), None
+            return tpu_jit(fn, "sort"), None
 
         return key_parts, factory
 
@@ -356,7 +356,7 @@ class TpuSortExec(TpuExec):
                             & mmask[perm]).astype(jnp.int32))
             return tuple(out.columns), emit, jnp.stack(consumed)
 
-        return tpu_jit(fn)
+        return tpu_jit(fn, "sort_merge")
 
 
 class TpuTopNExec(TpuExec):

@@ -17,6 +17,7 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import HostColumn
 from spark_rapids_tpu.exec.base import TpuExec
+from spark_rapids_tpu.perfcounters import span
 
 
 class TpuRowToColumnarExec(TpuExec):
@@ -74,7 +75,8 @@ class TpuColumnarToRowExec(TpuExec):
         """Materialize all batches to host columns."""
         import numpy as np
 
-        batches = list(self.children[0].execute_columnar())
+        with span("srt.execute"):
+            batches = list(self.children[0].execute_columnar())
         if not batches:
             schema = self.output
             return [HostColumn.from_pylist([], f.dataType)

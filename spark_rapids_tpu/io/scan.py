@@ -239,7 +239,8 @@ class TpuFileSourceScanExec(TpuExec):
         pre_chunk_falls = PC.COUNTERS.get("chunk_decode_fallbacks", 0)
         try:
             chaos.check_decode_fault(self.node_name, file_index)
-            with self.metric("gpuDecodeTime").timed():
+            with self.metric("gpuDecodeTime").timed(), \
+                    PC.span("srt.scan.device_decode"):
                 if self.plan.fmt == "orc":
                     from spark_rapids_tpu.io.orc_device import (
                         read_orc_device)
@@ -304,7 +305,7 @@ class TpuFileSourceScanExec(TpuExec):
 
         import os
 
-        with self.metric("bufferTime").timed():
+        with self.metric("bufferTime").timed(), PC.span("srt.scan.read"):
             if os.path.isdir(path):
                 # hive-partitioned directory: dataset read (partition
                 # columns materialize from the directory names)
@@ -378,8 +379,9 @@ class TpuFileSourceScanExec(TpuExec):
             path, mode, tol)
 
     def _table_to_host_cols(self, tbl) -> List[HostColumn]:
-        return [HostColumn.from_arrow(tbl.column(f.name), f.dataType)
-                for f in self.plan.output.fields]
+        with PC.span("srt.scan.to_columns"):
+            return [HostColumn.from_arrow(tbl.column(f.name), f.dataType)
+                    for f in self.plan.output.fields]
 
     def _upload(self, tbl) -> ColumnarBatch:
         with self.metric("gpuDecodeTime").timed():  # name kept for parity
@@ -388,10 +390,8 @@ class TpuFileSourceScanExec(TpuExec):
             # transfer-wall attribution (ISSUE 6 satellite): time the
             # pad+device_put only — the arrow->HostColumn conversion
             # above is host decode, not link time
-            t0 = time.perf_counter_ns()
-            out = ColumnarBatch.from_host_columns(cols, names)
-            PC.bump("scan_transfer_ns", time.perf_counter_ns() - t0)
-            return out
+            with PC.span("srt.scan.h2d", feeds="scan_transfer_ns"):
+                return ColumnarBatch.from_host_columns(cols, names)
 
     # -- modes ----------------------------------------------------------
     @staticmethod
@@ -518,8 +518,9 @@ class TpuFileSourceScanExec(TpuExec):
                     else:
                         host_futs.append(
                             (i, p,
-                             pool.submit(self._read_host_checked,
-                                         p, i, mode)))
+                             pool.submit(
+                                 PC.bind_owner(self._read_host_checked),
+                                 p, i, mode)))
 
                 def jobs():
                     for i, p, fut in host_futs:
@@ -571,6 +572,9 @@ class TpuFileSourceScanExec(TpuExec):
         _ctx = _cur()
         owner_qid = _ctx.query_id if _ctx is not None else None
 
+        # the staging thread's spans are roots of their own: they carry
+        # this thread's ids, captured here as well
+        @PC.bind_owner
         def run_job(job):
             if PROG_CTX.TRACKER is None or owner_qid is None:
                 return job()
@@ -606,18 +610,17 @@ class TpuFileSourceScanExec(TpuExec):
                     fill()
                     overlapped = fut.done()
                     if not overlapped:
-                        t0 = time.perf_counter_ns()
-                        while True:
-                            check_cancel()
-                            try:
-                                items = fut.result(timeout=0.05)
-                                break
-                            except cf.TimeoutError:
-                                continue
-                        stall = time.perf_counter_ns() - t0
-                        PC.bump("prefetch_stall_ns", stall)
-                        self.metric("prefetchStallTime").add(stall)
-                        stats["stall_ns"] += stall
+                        with PC.span("srt.scan.prefetch_wait",
+                                     feeds="prefetch_stall_ns") as wait:
+                            while True:
+                                check_cancel()
+                                try:
+                                    items = fut.result(timeout=0.05)
+                                    break
+                                except cf.TimeoutError:
+                                    continue
+                        self.metric("prefetchStallTime").add(wait.ns)
+                        stats["stall_ns"] += wait.ns
                     else:
                         items = fut.result()
                 else:

@@ -78,6 +78,12 @@ class query_lifecycle:
         self._journaled = False
 
     def __enter__(self) -> Optional[QueryContext]:
+        from spark_rapids_tpu import perfcounters as PC
+
+        with PC.span("srt.admit"):
+            return self._admit()
+
+    def _admit(self) -> Optional[QueryContext]:
         from spark_rapids_tpu.config import (
             ADMISSION_MAX_QUEUE,
             ADMISSION_QUEUE_TIMEOUT_MS,

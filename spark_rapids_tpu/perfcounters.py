@@ -22,6 +22,12 @@ Counters (process-global, reset per query via ``snapshot``/``since``):
 - ``launch_wall_ns``    — wall time inside jitted calls (dispatch +, when
   the result is consumed synchronously, device compute).
 
+Timing is :class:`span`'s: one primitive at every layer boundary of a
+collect, folded into these counters as ``span_n|<path>``,
+``span_ns|<path>``, ``span_self_ns|<path>`` (docs/diagnostics.md
+"Spans"); the ``*_ns`` counters above and below that a span ``feeds``
+keep their names and values.
+
 Use :func:`tpu_jit` instead of ``jax.jit`` inside exec nodes; it is a
 drop-in wrapper.  The dunder patches are installed at import and cost one
 Python increment per event (~100ns) — negligible beside the 10µs-to-300ms
@@ -260,8 +266,184 @@ def since(snap: Dict[str, int]) -> Dict[str, int]:
 
 def reset() -> None:
     with _LOCK:
+        for k in [k for k in COUNTERS if k.startswith(SPAN_KEYS)]:
+            del COUNTERS[k]
         for k in COUNTERS:
             COUNTERS[k] = 0
+
+
+# ---------------------------------------------------------------------------
+# spans: the one timing primitive of a collect (docs/diagnostics.md)
+# ---------------------------------------------------------------------------
+
+SPAN_KEYS = ("span_n|", "span_ns|", "span_self_ns|")
+_clock = time.perf_counter_ns       # the tests put a fake clock here
+_TraceAnnotation = jax.profiler.TraceAnnotation
+_tracing = _TraceAnnotation.is_enabled      # is a profiler session on?
+_tls = threading.local()
+
+
+class span:
+    """``with span("srt.<layer>.<what>"):`` around one layer boundary of
+    a collect.  Always on, no conf.  It
+
+    * opens a ``jax.profiler.TraceAnnotation`` while a profiler session
+      is on: the event lies on the profiler's own clock, in the host
+      plane of the same trace as the device ops, on this thread
+      (``trace=False`` where jax opens an event of its own);
+    * folds into a thread-local table ``path -> [count, inclusive ns,
+      self ns]``, ``path`` being the names of the thread's open spans
+      from the outermost joined by ``/``; self time is inclusive minus
+      the children's inclusive;
+    * when the thread's outermost span closes, merges the table into
+      ``COUNTERS`` under one lock as ``span_n|<path>``,
+      ``span_ns|<path>``, ``span_self_ns|<path>``: ``since()`` carries
+      them, ``reset()`` drops them, and the recorder's per-operator
+      buckets never see them.
+
+    ``feeds`` names an old counter that keeps its value: the span's
+    inclusive time is bumped into it at exit.  After exit ``ns`` holds
+    the inclusive time.  Names are low-cardinality and never
+    ``collect`` (the benchmark counts host events of that name).
+
+    One object may be opened again once it is closed (the runtime loop
+    keeps one per operator iterator), never while it is open.
+
+    Open a span around a call, never across a ``yield``: a generator
+    suspended inside one would leave it on the stack of a thread that
+    goes on.  Should that happen all the same, or a span be closed by
+    another thread, nothing is corrupted: the spans left above it are
+    dropped unrecorded and so is a span closed off its thread."""
+
+    __slots__ = ("name", "ns", "ids", "_feeds", "_trace", "_ann", "_t0",
+                 "_kids_ns", "_path", "_parent")
+
+    def __init__(self, name: str, *, feeds: Optional[str] = None,
+                 trace: bool = True, **ids):
+        self.name = name
+        self.ids = ids
+        self._feeds = feeds
+        self._trace = trace
+
+    def __enter__(self):
+        tls = _tls
+        try:
+            parent = tls.top
+            while parent is not None and parent._path is None:  # dropped
+                parent = tls.top = parent._parent
+        except AttributeError:      # this thread's first span
+            parent = tls.top = None
+            tls.table = {}
+        if parent is None:
+            self._path = self.name
+            # is a profiler session on?  Asked once, by the outermost
+            # span, for all that nest in it
+            tls.tracing = _tracing()
+            if not self.ids:        # a pool job: the owner's ids
+                self.ids = getattr(tls, "ids", None) or self.ids
+        else:
+            self._path = parent._path + "/" + self.name
+        self._parent = parent
+        self._kids_ns = 0
+        if self._trace and tls.tracing:
+            ann = self._ann = _TraceAnnotation(self.name, **self.ids)
+            ann.__enter__()
+        else:
+            self._ann = None        # no profiler session: no event
+        tls.top = self
+        self._t0 = _clock()
+        return self
+
+    def annotate(self, **ids) -> None:
+        """Ids learnt after the span opened (the query id of
+        ``srt.collect``); pool jobs bound with :func:`bind_owner`
+        carry them too."""
+        self.ids = ids
+        if self._ann:
+            self._ann.set_metadata(**ids)
+
+    def __exit__(self, et, ev, tb):
+        dt = self.ns = _clock() - self._t0
+        if self._ann:
+            self._ann.__exit__(et, ev, tb)
+        tls = _tls
+        try:
+            top = tls.top
+        except AttributeError:      # a thread that never opened one
+            top = None
+        if top is not self:
+            # out of order: spans above this one were left open by a
+            # suspended generator (dropped), or this is another thread
+            while top is not None and top is not self:
+                top = top._parent
+            if top is None:
+                self._path = None       # its own thread drops it
+                return False
+            top = tls.top
+            while top is not self:
+                top._path = None
+                top = top._parent
+        parent = tls.top = self._parent
+        table = tls.table
+        try:
+            e = table[self._path]
+        except KeyError:
+            # once a path and thread: the entry stays, merged and zeroed
+            e = table[self._path] = [0, 0, 0] + [
+                k + self._path for k in SPAN_KEYS]
+        e[0] += 1
+        e[1] += dt
+        e[2] += dt - self._kids_ns
+        if self._feeds is not None:
+            bump(self._feeds, dt)
+        if parent is None:
+            _merge_spans(table)
+        else:
+            parent._kids_ns += dt
+        return False
+
+
+# a thread's table keeps its entries between merges; one that has seen
+# more paths than any plan opens (a pool thread over many plans) is dropped
+_MAX_TABLE = 512
+
+
+def _merge_spans(table) -> None:
+    with _LOCK:
+        c = COUNTERS
+        for e in table.values():
+            n, ns, self_ns, k_n, k_ns, k_self = e
+            if n:
+                try:
+                    c[k_n] += n
+                    c[k_ns] += ns
+                    c[k_self] += self_ns
+                except KeyError:    # the path's first merge, or after reset()
+                    c[k_n], c[k_ns], c[k_self] = n, ns, self_ns
+                e[0] = e[1] = e[2] = 0
+    if len(table) > _MAX_TABLE:
+        table.clear()
+
+
+def bind_owner(fn):
+    """``fn`` for a pool: the spans it opens on the pool's thread are
+    roots of their own, and carry the ids of the submitter's outermost
+    open span (captured here, at submit, as progress/ does)."""
+    top = getattr(_tls, "top", None)
+    while top is not None and top._parent is not None:
+        top = top._parent
+    ids = top.ids if top is not None else None
+    if not ids:
+        return fn
+
+    def owned(*a, **kw):
+        _tls.ids = ids
+        try:
+            return fn(*a, **kw)
+        finally:
+            _tls.ids = None
+
+    return owned
 
 
 class _CountingJit:
@@ -290,9 +472,10 @@ class _CountingJit:
 
     def __call__(self, *args, **kwargs):
         jitted = self._jitted
-        t0 = time.perf_counter_ns()
-        out = jitted(*args, **kwargs)
-        dt = time.perf_counter_ns() - t0
+        # table only: jax opens PjitFunction(<name>) in the trace itself
+        with span("srt.launch", trace=False) as sp:
+            out = jitted(*args, **kwargs)
+        dt = sp.ns
         compiled = 0
         n1 = jitted._cache_size()
         if n1 != self._seen:         # miss path only: serialize detection
@@ -326,8 +509,15 @@ class _CountingJit:
         return getattr(self._jitted, name)
 
 
-def tpu_jit(fn, **jit_kwargs):
-    """Drop-in ``jax.jit`` replacement that feeds the perf counters."""
+def tpu_jit(fn, name: Optional[str] = None, **jit_kwargs):
+    """Drop-in ``jax.jit`` replacement that feeds the perf counters.
+    ``name`` is what jax calls the program: ``PjitFunction(<name>)`` in
+    the host trace, ``jit_<name>`` on the device's module line.  It is
+    part of XLA's persistent-cache key, not of the registry's.  A named
+    ``fn`` is a plain function made for this call (a local ``def``): it
+    is renamed in place."""
+    if name is not None:
+        fn.__name__ = fn.__qualname__ = name
     return _CountingJit(jax.jit(fn, **jit_kwargs))
 
 
@@ -358,11 +548,20 @@ def _install_sync_counters() -> bool:
             rec = _DIAG.RECORDER
             if rec is not None:
                 rec.d2h(nbytes, counted_sync)
+        return counted_sync
 
     def make(real):
         def counted(self, *a, **kw):
-            _count(self)
-            return real(self, *a, **kw)
+            if not _count(self):
+                # a leaf of a batched fetch: sync_get holds the span
+                return real(self, *a, **kw)
+            # a sync of its own.  One span around the real read, which
+            # waits for the device and copies in one await: a
+            # block_until_ready() ahead of it, to time the wait apart
+            # from the copy, is a second wake-up of this thread and cost
+            # 0.3 ms a collect (my chip runs, PR 26)
+            with span("srt.sync"):
+                return real(self, *a, **kw)
 
         return counted
 
@@ -386,9 +585,6 @@ def count_h2d(nbytes: int, logical: Optional[int] = None) -> None:
     bump("bytes_h2d_logical", int(nbytes if logical is None else logical))
 
 
-_tls = threading.local()
-
-
 class sync_event:
     """Count one LOGICAL host round trip for a batched fetch.
 
@@ -400,13 +596,17 @@ class sync_event:
     Nested events count ONCE: a ``sync_get`` issued from inside another
     ``sync_event`` is part of the same logical round trip, so only the
     depth-0 entry bumps ``host_syncs`` (ISSUE 3 satellite — the old code
-    double-counted every nested batched fetch)."""
+    double-counted every nested batched fetch).
+
+    It holds no timer of its own: the recorder's batched-sync event gets
+    the time of the ``srt.sync`` span that ``sync_get`` opened inside
+    the event."""
 
     def __enter__(self):
         depth = getattr(_tls, "in_sync_event", 0)
         _tls.in_sync_event = depth + 1
         if depth == 0:
-            self._t0 = time.perf_counter_ns()
+            _tls.sync_ns = 0
             bump("host_syncs")
         return self
 
@@ -415,7 +615,7 @@ class sync_event:
         if _tls.in_sync_event == 0:
             rec = _DIAG.RECORDER
             if rec is not None:
-                rec.sync_batched(time.perf_counter_ns() - self._t0)
+                rec.sync_batched(_tls.sync_ns)
 
 
 def _in_sync_event() -> bool:
@@ -423,6 +623,11 @@ def _in_sync_event() -> bool:
 
 
 def sync_get(tree):
-    """Fetch a pytree of device arrays as ONE logical host sync."""
+    """Fetch a pytree of device arrays as ONE logical host sync, under
+    one ``srt.sync`` span (the wait for the device and the copy of every
+    leaf, as ``device_get`` does them)."""
     with sync_event():
-        return jax.device_get(tree)
+        with span("srt.sync") as sp:
+            out = jax.device_get(tree)
+        _tls.sync_ns += sp.ns
+        return out

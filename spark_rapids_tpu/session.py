@@ -15,9 +15,11 @@ is precisely what the differential test harness does to get golden results.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+from spark_rapids_tpu import perfcounters as _PC
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.accounting import context as _ACCT_CTX
 from spark_rapids_tpu.columnar.column import HostColumn
@@ -580,6 +582,10 @@ class DataFrame:
         """Apply TpuOverrides; the planned exec tree is cached per conf so
         repeated collects reuse compiled XLA programs (Spark likewise reuses
         a query's compiled stages across executions of the same plan)."""
+        with _PC.span("srt.plan"):
+            return self._planned_impl()
+
+    def _planned_impl(self):
         from spark_rapids_tpu.config import set_conf
         from spark_rapids_tpu.overrides import TpuOverrides
 
@@ -627,7 +633,12 @@ class DataFrame:
         # exec tree unwinds — even mid-batch
         from spark_rapids_tpu.lifecycle import query_lifecycle
 
-        with query_lifecycle(self.session.conf) as qctx:
+        # srt.collect: the outermost span of the client's thread; every
+        # other span of this collect nests in it and so shares its ids
+        with _PC.span("srt.collect") as sp, \
+                query_lifecycle(self.session.conf) as qctx:
+            if qctx is not None:
+                sp.annotate(query_id=qctx.query_id, trace_id=qctx.trace_id)
             # Telemetry (ISSUE 7): lifecycle-managed queries run under
             # flight-recorder + SLO observation — a few dict appends and
             # one plan walk per QUERY.  The hub check is one ambient
@@ -669,11 +680,6 @@ class DataFrame:
                 except Exception:
                     pass
         if isinstance(root, TpuExec):
-            from spark_rapids_tpu.config import PROFILE_ENABLED
-            from spark_rapids_tpu.exec.base import enable_operator_tracing
-
-            enable_operator_tracing(
-                root, bool(self.session.conf.get(PROFILE_ENABLED)))
             # Diagnostics (ISSUE 3): one QueryDiagnostics recorder spans
             # the window from AOT submission through execution — operator
             # spans, launch/sync/compile/resilience events, per-operator
@@ -791,6 +797,9 @@ class DataFrame:
                     # semaphore conf parse) would otherwise leave a
                     # ghost "running" query in the tracker forever
                     _prog_status = "error"
+                    # srt.prepare: from here until the permit is held
+                    prepare = contextlib.ExitStack()
+                    prepare.enter_context(_PC.span("srt.prepare"))
                     try:
                         # Plan-time AOT pipeline (compilecache/aot.py):
                         # enumerate the stage programs this exec tree
@@ -859,6 +868,7 @@ class DataFrame:
                                     timeout=(sem_timeout_ms / 1000.0
                                              if sem_timeout_ms > 0
                                              else None)):
+                                prepare.close()
                                 host = TpuColumnarToRowExec(
                                     root).collect_host()
                         except Exception as e:
@@ -884,6 +894,7 @@ class DataFrame:
                         _prog_status = type(_pe).__name__
                         raise
                     finally:
+                        prepare.close()
                         # progress finish INSIDE the diagnostics scope:
                         # the summary event must land before query_end.
                         # Compare-and-clear the live-explain key: a
@@ -901,8 +912,9 @@ class DataFrame:
                 # stale previous query's diagnostics as if they described
                 # the latest (failed) execution
                 self._last_diag = scope.diag
-            lists = [h.to_pylist() for h in host]
-            return list(zip(*lists)) if lists else []
+            with _PC.span("srt.rows"):
+                lists = [h.to_pylist() for h in host]
+                return list(zip(*lists)) if lists else []
         # full-oracle runs pin the session conf thread-locally too: the
         # oracle file scan reads the per-file tolerance confs (ISSUE 5)
         # through config.get_conf(), which must see THIS session's

@@ -22,7 +22,6 @@ decomposes exchange walls from these.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Tuple
 
 from spark_rapids_tpu import perfcounters as PC
@@ -77,7 +76,10 @@ class SpillBackedPartitionQueues:
         check_cancel()
         if batch is None or batch.num_rows == 0:
             return
-        t0 = time.perf_counter_ns()
+        with PC.span("srt.exchange.queue", feeds="exchange_spill_ns"):
+            self._append(pid, batch)
+
+    def _append(self, pid: int, batch: ColumnarBatch) -> None:
         nb = batch.nbytes()
         if self._device_bytes + nb <= self.device_budget:
             if _ACCT.LEDGERS is not None:
@@ -104,7 +106,6 @@ class SpillBackedPartitionQueues:
             self.host_block_bytes += len(blob)
             PC.bump("exchange_host_blocks")
             PC.bump("exchange_host_block_bytes", len(blob))
-        PC.bump("exchange_spill_ns", time.perf_counter_ns() - t0)
 
     def _host_entry(self, blob: bytes) -> Tuple[str, object]:
         """One host-tier entry: in memory up to ``host_budget``, past
@@ -253,7 +254,10 @@ class SpillBackedPartitionQueues:
             return len(x)
 
         def _drain_group():
-            t0 = time.perf_counter_ns()
+            with PC.span("srt.exchange.queue", feeds="exchange_spill_ns"):
+                return _drain_group_impl()
+
+        def _drain_group_impl():
             # stamp the DRAINING partition: restores its materialization
             # pulls up-tier — and spills that restoring displaces — bill
             # against pid, localizing out-of-core pressure (ISSUE 18)
@@ -288,7 +292,6 @@ class SpillBackedPartitionQueues:
                     _ACCT.PARTITION.reset(_tok)
             for kind, x in group:
                 self._release_entry(kind, x)
-            PC.bump("exchange_spill_ns", time.perf_counter_ns() - t0)
             return out
 
         for kind, x in entries:

@@ -106,19 +106,24 @@ class TelemetryHub:
             QueryDeadlineExceeded,
         )
 
+        from spark_rapids_tpu import perfcounters as PC
+
+        # srt.observe: this wrapper's own work, not the collect it wraps
         qid = qctx.query_id
-        self.record_event("query_start", query_id=qid,
-                          thread=threading.get_ident())
+        with PC.span("srt.observe"):
+            self.record_event("query_start", query_id=qid,
+                              thread=threading.get_ident())
         t0 = time.perf_counter_ns()
         try:
             rows = df._collect_impl(qctx)
         except BaseException as e:
             wall = time.perf_counter_ns() - t0
             status = type(e).__name__
-            self._finish(df, qid, wall, status,
-                         float(df.session.conf.get(
-                             TELEMETRY_SLO_TARGET_P95_MS)),
-                         tenant=getattr(qctx, "tenant", ""))
+            with PC.span("srt.observe"):
+                self._finish(df, qid, wall, status,
+                             float(df.session.conf.get(
+                                 TELEMETRY_SLO_TARGET_P95_MS)),
+                             tenant=getattr(qctx, "tenant", ""))
             # QueryRejected never lands here: admission raises inside
             # query_lifecycle.__enter__, before this wrapper runs — the
             # lifecycle layer records the query_rejected flight event
@@ -133,9 +138,11 @@ class TelemetryHub:
                                 detail=f"{type(e).__name__}: {e}")
             raise
         wall = time.perf_counter_ns() - t0
-        self._finish(df, qid, wall, "ok",
-                     float(df.session.conf.get(TELEMETRY_SLO_TARGET_P95_MS)),
-                     tenant=getattr(qctx, "tenant", ""))
+        with PC.span("srt.observe"):
+            self._finish(df, qid, wall, "ok",
+                         float(df.session.conf.get(
+                             TELEMETRY_SLO_TARGET_P95_MS)),
+                         tenant=getattr(qctx, "tenant", ""))
         return rows
 
     def _finish(self, df, qid: str, wall_ns: int, status: str,
