@@ -5299,6 +5299,9 @@ def _cpu_join(plan: PN._BaseJoin, ansi: bool):
         out_cols = [CpuCol(c.dtype, c.values[keep], c.validity[keep])
                     for c in out_cols]
         nrows = int(keep.sum())
+    if plan.emit is not None:
+        # a pruned plan's join (plan/pruning.py) re-tagged to the oracle
+        out_cols = [out_cols[i] for i in plan.emit]
     return out_cols, nrows
 
 

@@ -25,7 +25,13 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn, HostColumn
 from spark_rapids_tpu.exec.base import TpuExec
-from spark_rapids_tpu.expr.base import EvalContext, Expression, SparkArithmeticException
+from spark_rapids_tpu.expr.base import (
+    Alias,
+    BoundReference,
+    EvalContext,
+    Expression,
+    SparkArithmeticException,
+)
 
 
 class _StageOp:
@@ -135,6 +141,23 @@ def _fuse_filter_project(ops: List[_StageOp], ansi: bool) -> List[_StageOp]:
     return out
 
 
+def _selection(ops: Sequence[_StageOp]) -> Optional[List[int]]:
+    """The input ordinals a chain of bare-reference projections emits, or
+    None when an op computes anything: such a chain (what column pruning
+    inserts below an exchange, sort or window) selects column objects —
+    no program, no copy."""
+    cur: Optional[List[int]] = None
+    for op in ops:
+        if type(op) is not ProjectOp:
+            return None
+        refs = [e.children[0] if isinstance(e, Alias) else e
+                for e in op.exprs]
+        if not all(isinstance(r, BoundReference) for r in refs):
+            return None
+        cur = [r.ordinal if cur is None else cur[r.ordinal] for r in refs]
+    return cur
+
+
 class TpuStageExec(TpuExec):
     """A fused chain of narrow ops over one child."""
 
@@ -224,6 +247,11 @@ class TpuStageExec(TpuExec):
         return key_parts, factory
 
     def _build(self, in_schema: T.StructType):
+        sel = _selection(self.ops)
+        if sel is not None:
+            return lambda batch: ColumnarBatch(
+                [batch.columns[i] for i in sel], batch.num_rows,
+                self._out_schema)
         # host-kernel expressions (JSON, digests, ... — jax.pure_callback)
         # cannot live inside a compiled TPU program (the PJRT plugin has no
         # host-callback channel); the stage runs op-by-op eagerly instead —
@@ -275,7 +303,7 @@ class TpuStageExec(TpuExec):
             out_schema=self._out_schema,
             count_map=None if self._aot_filters_rows()
             else (lambda n: n),
-            programs_unfused=1)
+            programs_unfused=0 if _selection(self.ops) is not None else 1)
 
     # -- plan-time AOT enumeration (compilecache/aot.py) -----------------
     def _aot_filters_rows(self) -> bool:
@@ -304,7 +332,7 @@ class TpuStageExec(TpuExec):
             dummy_batch_args,
         )
 
-        if self._has_host_kernels():
+        if self._has_host_kernels() or _selection(self.ops) is not None:
             return []
         caps = self.aot_input_caps()
         if not caps:
@@ -385,33 +413,57 @@ def fuse_stages(root: TpuExec) -> TpuExec:
 class TpuLocalTableScanExec(TpuExec):
     def __init__(self, host_columns: List[HostColumn], schema: T.StructType,
                  target_batch_rows: Optional[int] = None,
-                 cache_device: bool = False, cache_slot=None):
+                 cache_device: bool = False, cache_slot=None,
+                 ordinals: Optional[List[int]] = None):
         super().__init__([])
         self.host_columns = host_columns
         self._schema = schema
         self.target_batch_rows = target_batch_rows
         self.cache_device = cache_device
-        # cache lives on the plan node so it survives re-planning
+        # cache lives on the user's plan node so it survives re-planning;
+        # it is kept per column, under the column's ordinal in that node,
+        # so the narrowed scans of different queries (plan/pruning.py)
+        # share what they both read and upload only what is missing
         self._slot = cache_slot if cache_slot is not None else self
+        self._ordinals = list(ordinals) if ordinals is not None \
+            else list(range(len(host_columns)))
+        self._resident_cache = None     # this exec's batches, assembled
 
     @property
     def output(self):
         return self._schema
 
     def execute_columnar(self):
-        cached = getattr(self._slot, "_device_cache", None)
-        if cached is not None:
-            for b in cached:
+        if not self.cache_device or not self._ordinals:
+            for b in self._materialize(range(len(self.host_columns))):
                 yield self._count_output(b)
             return
-        if self.cache_device:
-            acc = []
-            for b in self._materialize():
-                acc.append(b)
-                yield b
-            self._slot._device_cache = acc
-            return
-        yield from self._materialize()
+        if self._resident_cache is None:
+            self._resident_cache = self._resident_batches()
+        for b in self._resident_cache:
+            yield self._count_output(b)
+
+    def _resident_batches(self) -> List[ColumnarBatch]:
+        """This scan's batches over the slot's per-column cache, uploading
+        the columns no earlier planning or query left there."""
+        cache = getattr(self._slot, "_device_cache", None)
+        if cache is None:
+            cache = self._slot._device_cache = {"rows": [], "cols": {}}
+        missing = [i for i, o in enumerate(self._ordinals)
+                   if o not in cache["cols"]]
+        if missing:
+            fresh = list(self._materialize(missing))
+            # rows before columns: a concurrent collect that finds every
+            # column it reads also finds the row counts
+            cache["rows"] = [b.num_rows for b in fresh]
+            for k, i in enumerate(missing):
+                cache["cols"][self._ordinals[i]] = [
+                    b.columns[k] for b in fresh]
+        schema = T.StructType([T.StructField(f.name, f.dataType)
+                               for f in self._schema.fields])
+        return [ColumnarBatch([cache["cols"][o][k] for o in self._ordinals],
+                              n, schema)
+                for k, n in enumerate(cache["rows"])]
 
     def aot_output_rows(self):
         """Exact per-batch row counts (mirrors _materialize's chunking) —
@@ -425,7 +477,8 @@ class TpuLocalTableScanExec(TpuExec):
                 break
         return out
 
-    def _materialize(self):
+    def _materialize(self, which):
+        """Upload the columns ``which`` (positions in this scan), chunked."""
         n = self.host_columns[0].num_rows if self.host_columns else 0
         step = self.target_batch_rows or max(n, 1)
         names = self._schema.field_names()
@@ -433,9 +486,10 @@ class TpuLocalTableScanExec(TpuExec):
             end = min(start + step, n)
             if n == 0 and start > 0:
                 break
-            chunk = [h.slice_rows(start, end) for h in self.host_columns]
-            yield self._count_output(
-                ColumnarBatch.from_host_columns(chunk, names))
+            chunk = [self.host_columns[i].slice_rows(start, end)
+                     for i in which]
+            yield ColumnarBatch.from_host_columns(
+                chunk, [names[i] for i in which])
             if n == 0:
                 break
 

@@ -45,6 +45,7 @@ from spark_rapids_tpu.exec.join import (
     _key_words_of,
     _multiword_searchsorted,
     _SortedBuildSide,
+    arranged,
 )
 from spark_rapids_tpu.expr.base import EvalContext
 from spark_rapids_tpu.perfcounters import sync_get, tpu_jit
@@ -393,19 +394,24 @@ class TpuJoinAggFusedExec(TpuExec):
         out_cap = round_up_bucket(max(out_rows, 1), DEFAULT_ROW_BUCKETS)
         agg_fn = agg.detached_for_trace()._agg_fn   # no subtree capture
 
+        slots = join._mat_slots
+
         def fn(row_index, b_cols, p_cols, lo, counts, unmatched, total,
                nrows):
             lcols, bcols = _BaseTpuJoinExec.materialize_pairs(
                 row_index, b_cols, p_cols, lo, counts, unmatched, total,
                 nrows, out_cap, with_um)
-            joined = tuple(list(lcols) + list(bcols))
+            joined = tuple(arranged(slots, lcols, bcols))
             return agg_fn(joined, nrows.astype(jnp.int32))
 
         jitted = self._cached(("mat_agg", out_cap, with_um,
                                self._agg_tag(agg)), fn)
-        cols, nrows = jitted(build.row_index, tuple(build.batch.columns),
-                             tuple(probe.columns), lo, counts, unmatched,
-                             jnp.int64(total), jnp.int64(out_rows))
+        cols, nrows = jitted(
+            build.row_index,
+            tuple(build.batch.columns[i] for i in join._b_sel),
+            tuple(probe.columns[i] for i in join._p_sel),
+            lo, counts, unmatched,
+            jnp.int64(total), jnp.int64(out_rows))
         return self._finish(agg, cols, nrows)
 
     def _unique_probe_agg(self, build, probe, agg) -> ColumnarBatch:
@@ -419,6 +425,7 @@ class TpuJoinAggFusedExec(TpuExec):
         schema = probe.schema
         ansi, left_keys = join.ansi, join.left_keys
         agg_fn = agg.detached_for_trace()._agg_fn   # no subtree capture
+        slots, p_sel = join._mat_slots, join._p_sel
 
         def mk(groups_cap):
             def fn(bwords, row_index, n_valid, b_cols, p_cols, num_rows):
@@ -458,7 +465,8 @@ class TpuJoinAggFusedExec(TpuExec):
                     brow = jnp.where(found, row_index[loc], 0)
                     bcols = [_mask_col(c.gather(brow), found)
                              for c in b_cols]
-                joined = tuple(list(p_cols) + bcols)
+                joined = tuple(arranged(
+                    slots, [p_cols[i] for i in p_sel], bcols))
                 row_valid = b.row_mask if left_outer \
                     else (b.row_mask & found)
                 return agg_fn(joined, num_rows, row_valid=row_valid,
@@ -467,8 +475,8 @@ class TpuJoinAggFusedExec(TpuExec):
             return fn
 
         args = (tuple(build.words), build.row_index, build.n_valid,
-                tuple(build.batch.columns), tuple(probe.columns),
-                jnp.int32(probe.num_rows))
+                tuple(build.batch.columns[i] for i in join._b_sel),
+                tuple(probe.columns), jnp.int32(probe.num_rows))
         cap = probe.capacity
         B = agg._bounded_groups_cap(cap)
         tag = self._agg_tag(agg)

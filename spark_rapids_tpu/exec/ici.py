@@ -513,6 +513,10 @@ class TpuIciShuffleJoinExec(TpuExec):
         from spark_rapids_tpu.plan.nodes import JoinType
 
         self._orig_output = join.output
+        # the mesh programs take no emit list: they run the join at its
+        # full output and the emitted columns are selected at the end
+        self._emit_sel = join.emit
+        join = join.with_full_output()
         self._mirror_nl = None
         if join.join_type == JoinType.RIGHT_OUTER:
             from spark_rapids_tpu.exec.join import (
@@ -997,6 +1001,8 @@ class TpuIciShuffleJoinExec(TpuExec):
         if self._mirror_nl is not None:
             nl = self._mirror_nl
             cols = cols[nl:] + cols[:nl]
+        if self._emit_sel is not None:
+            cols = [cols[i] for i in self._emit_sel]
         return self._count_output(
             ColumnarBatch(list(cols), ng, self._orig_output))
 

@@ -32,10 +32,18 @@ from spark_rapids_tpu.columnar.column import (
     round_up_bucket,
 )
 from spark_rapids_tpu.exec.base import TpuExec
-from spark_rapids_tpu.expr.base import EvalContext, Expression
+from spark_rapids_tpu.expr.base import (
+    BoundReference,
+    EvalContext,
+    Expression,
+)
 from spark_rapids_tpu.ops.filterops import compact_columns, gather_columns
 from spark_rapids_tpu.ops.sortkeys import _column_key_words
-from spark_rapids_tpu.plan.nodes import JoinType
+from spark_rapids_tpu.plan.nodes import (
+    JoinType,
+    describe_emit,
+    join_full_output,
+)
 
 
 # what jax calls a join program in a trace, by the first part of its
@@ -189,7 +197,54 @@ class _MaterializedExec(TpuExec):
             yield b
 
 
-class _BaseTpuJoinExec(TpuExec):
+class _EmitLayout:
+    """What a join gathers and where it lands (plan/pruning.py): ``emit``
+    names the ordinals of ``left ++ right`` the parent reads, in output
+    order (None = all).  Only those are gathered, plus what an INNER
+    join's condition reads, which is dropped again once it is applied
+    (``_apply_condition``).  Keys are read from the child batches by the
+    build and probe programs and are gathered only if emitted."""
+
+    def _set_emit(self, left, right, join_type, condition, emit,
+                  output_schema):
+        from spark_rapids_tpu.plan.pruning import rebind
+
+        nl = len(left.output.fields)
+        self.emit = None if emit is None else list(emit)
+        self._full_output = output_schema if emit is None else \
+            join_full_output(left.output, right.output, join_type)
+        out = list(range(len(self._full_output.fields))) if emit is None \
+            else self.emit
+        mat = list(out)
+        self._mat_condition = condition
+        if condition is not None and emit is not None \
+                and join_type == JoinType.INNER:
+            reads = {r.ordinal for r in condition.collect(
+                lambda x: isinstance(x, BoundReference))}
+            mat += sorted(reads - set(out))
+            self._mat_condition = rebind(
+                condition, {o: i for i, o in enumerate(mat)})
+        self._n_emit = len(out)
+        # child ordinals gathered, ascending, and where each materialized
+        # column sits in ``probe gathers ++ build gathers``
+        self._p_sel = sorted({o for o in mat if o < nl})
+        self._b_sel = sorted({o - nl for o in mat if o >= nl})
+        slot = {o: i for i, o in enumerate(self._p_sel)}
+        slot.update({nl + o: len(self._p_sel) + i
+                     for i, o in enumerate(self._b_sel)})
+        self._mat_slots = [slot[o] for o in mat]
+        self._mat_schema = output_schema if len(mat) == len(out) else \
+            T.StructType([self._full_output.fields[o] for o in mat])
+
+
+
+def arranged(slots, lcols, bcols) -> list:
+    """Gathered probe and build columns in materialized order."""
+    cols = list(lcols) + list(bcols)
+    return [cols[s] for s in slots]
+
+
+class _BaseTpuJoinExec(_EmitLayout, TpuExec):
     # GpuShuffledHashJoinExec metric set: build + stream/probe time
     EXTRA_METRICS = {"buildTime": "MODERATE",
                      "joinTime": "MODERATE"}
@@ -198,7 +253,8 @@ class _BaseTpuJoinExec(TpuExec):
                  left_keys: List[Expression], right_keys: List[Expression],
                  join_type: JoinType, condition: Optional[Expression],
                  output_schema: T.StructType, ansi: bool = False,
-                 sub_partition_bytes: int = 1 << 30):
+                 sub_partition_bytes: int = 1 << 30,
+                 emit: Optional[List[int]] = None):
         super().__init__([left, right])
         self.left_keys = left_keys
         self.right_keys = right_keys
@@ -208,6 +264,19 @@ class _BaseTpuJoinExec(TpuExec):
         self.ansi = ansi
         self.sub_partition_bytes = sub_partition_bytes
         self._jit_cache = {}
+        self._set_emit(left, right, join_type, condition, emit,
+                       output_schema)
+
+    def with_full_output(self) -> "_BaseTpuJoinExec":
+        """This join emitting all of ``left ++ right`` (for a consumer
+        that takes no emit list and selects columns itself)."""
+        if self.emit is None:
+            return self
+        return type(self)(
+            self.children[0], self.children[1], self.left_keys,
+            self.right_keys, self.join_type, self.condition,
+            self._full_output, self.ansi,
+            sub_partition_bytes=self.sub_partition_bytes)
 
     def _registry_scope(self):
         """Fingerprint prefix identifying this join's program family (the
@@ -233,7 +302,9 @@ class _BaseTpuJoinExec(TpuExec):
                      lk, rk, cond,
                      schema_fp(self.children[0].output),
                      schema_fp(self.children[1].output),
-                     schema_fp(self._output), bool(self.ansi), conf_fp())
+                     schema_fp(self._output),
+                     None if self.emit is None else tuple(self.emit),
+                     bool(self.ansi), conf_fp())
         self._reg_scope = scope
         return scope
 
@@ -257,7 +328,8 @@ class _BaseTpuJoinExec(TpuExec):
     def describe(self):
         keys = ", ".join(f"{l.sql_string()}={r.sql_string()}"
                          for l, r in zip(self.left_keys, self.right_keys))
-        return f"{self.node_name} {self.join_type.value} [{keys}]"
+        return (f"{self.node_name} {self.join_type.value} [{keys}]"
+                + describe_emit(self.emit, self._full_output))
 
     # -- build side -----------------------------------------------------
     def _prepare_build(self, batch: ColumnarBatch, keys: List[Expression],
@@ -421,20 +493,23 @@ class _BaseTpuJoinExec(TpuExec):
 
             jitted = self._cached_jit(
                 ("mat", out_cap, with_unmatched_probe), fn)
-            lcols, bcols = jitted(build.row_index,
-                                  tuple(build.batch.columns),
-                                  tuple(probe.columns), lo, counts, unmatched,
-                                  jnp.int64(total_host), jnp.int64(out_rows))
+            lcols, bcols = jitted(
+                build.row_index,
+                tuple(build.batch.columns[i] for i in self._b_sel),
+                tuple(probe.columns[i] for i in self._p_sel),
+                lo, counts, unmatched,
+                jnp.int64(total_host), jnp.int64(out_rows))
             return lcols, bcols, out_rows
 
     def _semi_anti(self, probe: ColumnarBatch, counts, anti: bool):
         schema = probe.schema   # never capture the device batch itself
+        p_sel = self._p_sel
 
         def fn(cols, counts, num_rows):
             b = ColumnarBatch(list(cols), num_rows, schema)
             keep = (counts == 0) if anti else (counts > 0)
             keep = keep & b.row_mask
-            out, cnt = compact_columns(keep, b.columns)
+            out, cnt = compact_columns(keep, [b.columns[i] for i in p_sel])
             return tuple(out), cnt
 
         from spark_rapids_tpu.compilecache.keys import schema_fp
@@ -445,7 +520,8 @@ class _BaseTpuJoinExec(TpuExec):
                           jnp.int32(probe.num_rows))
         # int(cnt) is irreducible: the compacted row count labels the
         # output batch and nothing else in this path syncs to fold it into
-        return ColumnarBatch(list(out), int(cnt), self._output)
+        return ColumnarBatch(arranged(self._mat_slots, out, []), int(cnt),
+                             self._output)
 
     # -- driver ----------------------------------------------------------
     @staticmethod
@@ -615,7 +691,8 @@ class _BaseTpuJoinExec(TpuExec):
                     _MaterializedExec(build_buckets[pid], bschema),
                     self.left_keys, self.right_keys, self.join_type,
                     self.condition, self._output, self.ansi,
-                    sub_partition_bytes=1 << 62)  # buckets never re-partition
+                    sub_partition_bytes=1 << 62,  # buckets never re-partition
+                    emit=self.emit)
                 for out in sub.execute_columnar():
                     yield self._count_output(out)
                 for s in build_buckets[pid] + probe_buckets[pid]:
@@ -704,8 +781,8 @@ class _BaseTpuJoinExec(TpuExec):
             lcols, bcols, nrows = self._materialize(
                 build, probe, lo, counts, total_host, unmatched,
                 with_um, um_host)
-            out = ColumnarBatch(list(lcols) + list(bcols), nrows,
-                                self._output)
+            out = ColumnarBatch(arranged(self._mat_slots, lcols, bcols), nrows,
+                                self._mat_schema)
             return self._apply_condition(out)
 
         for probe in self._probe_child().execute_columnar():
@@ -739,11 +816,12 @@ class _BaseTpuJoinExec(TpuExec):
 
     def _unmatched_build_tail(self, build_batch, build, matched_any):
         schema = build_batch.schema   # never capture the device batch
+        b_sel = self._b_sel
 
         def fn(cols, matched, num_rows):
             b = ColumnarBatch(list(cols), num_rows, schema)
             keep = b.row_mask & ~matched
-            out, cnt = compact_columns(keep, b.columns)
+            out, cnt = compact_columns(keep, [b.columns[i] for i in b_sel])
             return tuple(out), cnt
 
         out, cnt = self._cached_jit("build_tail", fn)(
@@ -753,7 +831,8 @@ class _BaseTpuJoinExec(TpuExec):
         if n == 0:
             return None
         # null left side
-        lfields = self._output.fields[: len(self._probe_child().output)]
+        pfields = self._probe_child().output.fields
+        lfields = [pfields[i] for i in self._p_sel]
         lcols = []
         cap = build_batch.capacity
         for f in lfields:
@@ -766,42 +845,41 @@ class _BaseTpuJoinExec(TpuExec):
                 lcols.append(DeviceColumn(
                     f.dataType, jnp.zeros(cap, jnp.bool_),
                     data=jnp.zeros(cap, T.storage_dtype(f.dataType))))
-        return ColumnarBatch(lcols + list(out), n, self._output)
+        return ColumnarBatch(arranged(self._mat_slots, lcols, out), n,
+                             self._output)
 
     def _execute_right_outer(self):
-        """RIGHT OUTER = LEFT OUTER with sides swapped, columns reordered."""
-        swapped_schema = T.StructType(
-            list(self._build_child().output.fields)
-            + [T.StructField(f.name, f.dataType, True)
-               for f in self._probe_child().output.fields])
+        """RIGHT OUTER = LEFT OUTER with sides swapped, emitting this
+        join's columns in this join's order (right cols as they are, left
+        cols nullable): the swap costs no reordering of its own."""
+        nl = len(self.children[0].output.fields)
+        nr = len(self.children[1].output.fields)
+        out = range(nl + nr) if self.emit is None else self.emit
         swapped = TpuShuffledSymmetricHashJoinExec(
             self.children[1], self.children[0],
             self.right_keys, self.left_keys,
             JoinType.LEFT_OUTER, self.condition,
-            swapped_schema, self.ansi,
-            sub_partition_bytes=self.sub_partition_bytes)
-        nl = len(self._build_child().output.fields)
+            self._output, self.ansi,
+            sub_partition_bytes=self.sub_partition_bytes,
+            emit=[nr + o if o < nl else o - nl for o in out])
         for b in swapped.execute_columnar():
-            cols = b.columns[nl:] + b.columns[:nl]
-            # right-outer output: left cols (nullable) then right cols
-            reordered = T.StructType(
-                [T.StructField(f.name, f.dataType, True)
-                 for f in self._probe_child().output.fields]
-                + list(self._build_child().output.fields))
-            out = ColumnarBatch(cols, b.num_rows, reordered)
-            yield self._count_output(self._apply_condition(out))
+            yield self._count_output(b)
 
     def _apply_condition(self, batch: ColumnarBatch) -> ColumnarBatch:
+        """Filter materialized pairs by an INNER join's condition; the
+        columns gathered for the condition alone are not compacted."""
         if self.condition is None or self.join_type != JoinType.INNER:
             return batch
-        out_schema, cond, ansi = self._output, self.condition, self.ansi
+        mat_schema, cond, ansi = (self._mat_schema, self._mat_condition,
+                                  self.ansi)
+        n_emit = self._n_emit
 
         def fn(cols, num_rows):
-            b = ColumnarBatch(list(cols), num_rows, out_schema)
+            b = ColumnarBatch(list(cols), num_rows, mat_schema)
             ctx = EvalContext(b, ansi=ansi)
             pred = cond.eval_tpu(ctx)
             keep = pred.data & pred.validity & b.row_mask
-            out, cnt = compact_columns(keep, b.columns)
+            out, cnt = compact_columns(keep, b.columns[:n_emit])
             return tuple(out), cnt
 
         jitted = self._cached_jit("cond", fn)
@@ -821,18 +899,21 @@ class TpuBroadcastHashJoinExec(_BaseTpuJoinExec):
     device (parallel/bcast)."""
 
 
-class TpuCartesianProductExec(TpuExec):
+class TpuCartesianProductExec(_EmitLayout, TpuExec):
     """CROSS join: index-arithmetic expansion (GpuCartesianProductExec)."""
 
     def __init__(self, left: TpuExec, right: TpuExec,
                  output_schema: T.StructType,
-                 condition: Optional[Expression] = None, ansi: bool = False):
+                 condition: Optional[Expression] = None, ansi: bool = False,
+                 emit: Optional[List[int]] = None):
         super().__init__([left, right])
         self._output = output_schema
         self.condition = condition
         self.join_type = JoinType.INNER  # for _apply_condition reuse
         self.ansi = ansi
         self._jit_cache = {}
+        self._set_emit(left, right, JoinType.INNER, condition, emit,
+                       output_schema)
 
     _cached_jit = _BaseTpuJoinExec._cached_jit
     _apply_condition = _BaseTpuJoinExec._apply_condition
@@ -854,13 +935,18 @@ class TpuCartesianProductExec(TpuExec):
             scope = ("cartesian", cond,
                      schema_fp(self.children[0].output),
                      schema_fp(self.children[1].output),
-                     schema_fp(self._output), bool(self.ansi), conf_fp())
+                     schema_fp(self._output),
+                     None if self.emit is None else tuple(self.emit),
+                     bool(self.ansi), conf_fp())
         self._reg_scope = scope
         return scope
 
     @property
     def output(self):
         return self._output
+
+    def describe(self):
+        return self.node_name + describe_emit(self.emit, self._full_output)
 
     def execute_columnar(self):
         right_batches = list(self.children[1].execute_columnar())
@@ -884,12 +970,14 @@ class TpuCartesianProductExec(TpuExec):
                 return tuple(lo + ro)
 
             jitted = self._cached_jit(("cart", out_cap), fn)
-            cols = jitted(tuple(lb.columns), tuple(rbatch.columns),
+            nlc = len(self._p_sel)
+            cols = jitted(tuple(lb.columns[i] for i in self._p_sel),
+                          tuple(rbatch.columns[i] for i in self._b_sel),
                           jnp.int64(rbatch.num_rows), jnp.int64(total))
-            out = ColumnarBatch(list(cols), total, self._output)
-            if self.condition is not None:
-                out = self._apply_condition(out)
-            yield self._count_output(out)
+            out = ColumnarBatch(
+                arranged(self._mat_slots, cols[:nlc], cols[nlc:]), total,
+                self._mat_schema)
+            yield self._count_output(self._apply_condition(out))
 
 
 class _ReplayExec(TpuExec):
@@ -978,7 +1066,8 @@ class TpuAdaptiveJoinExec(TpuExec):
                 self.shuffled.left_keys, self.shuffled.right_keys,
                 self.shuffled.join_type, self.shuffled.condition,
                 self.shuffled.output, self.shuffled.ansi,
-                sub_partition_bytes=self.shuffled.sub_partition_bytes)
+                sub_partition_bytes=self.shuffled.sub_partition_bytes,
+                emit=self.shuffled.emit)
             self.metrics.update(bj.metrics)
             yield from bj.execute_columnar()
             return

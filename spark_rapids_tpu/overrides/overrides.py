@@ -1631,7 +1631,8 @@ def _convert_node(meta: SparkPlanMeta, tpu_children, ansi: bool):
         return X.TpuLocalTableScanExec(
             plan.host_columns, plan.output,
             target_batch_rows=rows_cap if rows_cap < 2147483647 else None,
-            cache_device=meta.conf.get(TPU_SCAN_CACHE), cache_slot=plan)
+            cache_device=meta.conf.get(TPU_SCAN_CACHE),
+            cache_slot=plan.origin, ordinals=plan.ordinals)
     if isinstance(plan, PN.FileSourceScan):
         return TpuFileSourceScanExec(plan, meta.conf)
     if isinstance(plan, PN.RangeNode):
@@ -1649,11 +1650,13 @@ def _convert_node(meta: SparkPlanMeta, tpu_children, ansi: bool):
     if isinstance(plan, (PN.SortMergeJoin, PN.ShuffledHashJoin)):
         if plan.join_type == PN.JoinType.CROSS:
             return TpuCartesianProductExec(tpu_children[0], tpu_children[1],
-                                           plan.output, plan.condition, ansi)
+                                           plan.output, plan.condition, ansi,
+                                           emit=plan.emit)
         shuffled = X.TpuShuffledSymmetricHashJoinExec(
             tpu_children[0], tpu_children[1], plan.left_keys, plan.right_keys,
             plan.join_type, plan.condition, plan.output, ansi,
-            sub_partition_bytes=meta.conf.get(BATCH_SIZE_BYTES))
+            sub_partition_bytes=meta.conf.get(BATCH_SIZE_BYTES),
+            emit=plan.emit)
         # AQE: runtime join-strategy switch when both sides are planned
         # exchanges (spark.sql.adaptive.enabled, default on like Spark)
         from spark_rapids_tpu.exec.exchange import TpuShuffleExchangeExec
@@ -1675,7 +1678,8 @@ def _convert_node(meta: SparkPlanMeta, tpu_children, ansi: bool):
         return X.TpuBroadcastHashJoinExec(
             tpu_children[0], tpu_children[1], plan.left_keys, plan.right_keys,
             plan.join_type, plan.condition, plan.output, ansi,
-            sub_partition_bytes=meta.conf.get(BATCH_SIZE_BYTES))
+            sub_partition_bytes=meta.conf.get(BATCH_SIZE_BYTES),
+            emit=plan.emit)
     if isinstance(plan, PN.Sample):
         from spark_rapids_tpu.exec.limit import TpuSampleExec
 

@@ -219,6 +219,9 @@ COUNTERS: Dict[str, int] = {
     "result_cache_evictions": 0,
     "tenant_sheds": 0,
     "tenant_preempts": 0,
+    # column pruning (plan/pruning.py): columns dropped, summed over the
+    # nodes narrowed, once per planning (never per collect)
+    "plan_columns_pruned": 0,
 }
 
 

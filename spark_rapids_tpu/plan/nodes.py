@@ -58,10 +58,24 @@ class LocalTableScan(SparkPlan):
         super().__init__([])
         self.host_columns = host_columns  # List[HostColumn]
         self._schema = schema
+        # a narrowed copy (plan/pruning.py) names the user's node and the
+        # ordinals it emits of it: the device cache stays on that node,
+        # one entry per column, so re-planning uploads nothing twice
+        self.origin = self
+        self.ordinals = list(range(len(schema.fields)))
 
     @property
     def output(self):
         return self._schema
+
+    def narrowed(self, keep: Sequence[int]) -> "LocalTableScan":
+        """The scan of the columns ``keep`` (ordinals of this node)."""
+        n = LocalTableScan([self.host_columns[i] for i in keep],
+                           T.StructType([self._schema.fields[i]
+                                         for i in keep]))
+        n.origin = self.origin
+        n.ordinals = [self.ordinals[i] for i in keep]
+        return n
 
     def describe(self):
         return f"LocalTableScan {self._schema.simpleString}"
@@ -106,6 +120,14 @@ class FileSourceScan(SparkPlan):
     @property
     def output(self):
         return self._schema
+
+    def narrowed(self, keep: Sequence[int]) -> "FileSourceScan":
+        """The scan of the columns ``keep``: the output schema is the
+        ``columns=`` of the read, so decode, padding and H2D shrink too."""
+        return FileSourceScan(
+            self.fmt, self.paths,
+            T.StructType([self._schema.fields[i] for i in keep]),
+            self.pushed_filters, self.options)
 
     def describe(self):
         return f"FileSourceScan {self.fmt} {len(self.paths)} files"
@@ -348,12 +370,17 @@ class _BaseJoin(SparkPlan):
     def __init__(self, left: SparkPlan, right: SparkPlan,
                  left_keys: List[Expression], right_keys: List[Expression],
                  join_type: JoinType,
-                 condition: Optional[Expression] = None):
+                 condition: Optional[Expression] = None,
+                 emit: Optional[List[int]] = None):
         super().__init__([left, right])
         self.left_keys = left_keys
         self.right_keys = right_keys
         self.join_type = join_type
         self.condition = condition
+        # the ordinals of ``full_output`` the parent reads, in output
+        # order (plan/pruning.py); None = all.  Keys and the condition
+        # are bound to the children, not to this list
+        self.emit = emit
 
     @property
     def left(self):
@@ -365,22 +392,43 @@ class _BaseJoin(SparkPlan):
 
     @property
     def output(self):
-        lt, rt = self.join_type, JoinType
-        lf = list(self.left.output.fields)
-        rf = list(self.right.output.fields)
-        if self.join_type in (JoinType.LEFT_SEMI, JoinType.LEFT_ANTI):
-            return T.StructType(lf)
-        if self.join_type in (JoinType.LEFT_OUTER, JoinType.FULL_OUTER):
-            rf = [T.StructField(f.name, f.dataType, True) for f in rf]
-        if self.join_type in (JoinType.RIGHT_OUTER, JoinType.FULL_OUTER):
-            lf = [T.StructField(f.name, f.dataType, True) for f in lf]
-        return T.StructType(lf + rf)
+        full = self.full_output
+        if self.emit is None:
+            return full
+        return T.StructType([full.fields[i] for i in self.emit])
+
+    @property
+    def full_output(self):
+        return join_full_output(self.left.output, self.right.output,
+                                self.join_type)
 
     def describe(self):
         keys = ", ".join(
             f"{l.sql_string()}={r.sql_string()}"
             for l, r in zip(self.left_keys, self.right_keys))
-        return f"{self.node_name} {self.join_type.value} [{keys}]"
+        return (f"{self.node_name} {self.join_type.value} [{keys}]"
+                + describe_emit(self.emit, self.full_output))
+
+
+def join_full_output(left: T.StructType, right: T.StructType,
+                     join_type: JoinType) -> T.StructType:
+    """``left ++ right`` as the join type shapes it, before any emit."""
+    lf, rf = list(left.fields), list(right.fields)
+    if join_type in (JoinType.LEFT_SEMI, JoinType.LEFT_ANTI):
+        return T.StructType(lf)
+    if join_type in (JoinType.LEFT_OUTER, JoinType.FULL_OUTER):
+        rf = [T.StructField(f.name, f.dataType, True) for f in rf]
+    if join_type in (JoinType.RIGHT_OUTER, JoinType.FULL_OUTER):
+        lf = [T.StructField(f.name, f.dataType, True) for f in lf]
+    return T.StructType(lf + rf)
+
+
+def describe_emit(emit, full_output: T.StructType) -> str:
+    """`` emit=[names]`` for a join that emits part of its output."""
+    if emit is None:
+        return ""
+    return " emit=[" + ", ".join(full_output.fields[i].name
+                                 for i in emit) + "]"
 
 
 class SortMergeJoin(_BaseJoin):

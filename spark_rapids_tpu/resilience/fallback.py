@@ -88,14 +88,15 @@ def build_cpu_subplan(op) -> Optional[object]:
         sh = op.shuffled
         return PN.SortMergeJoin(_mat(op.children[0]), _mat(op.children[1]),
                                 sh.left_keys, sh.right_keys, sh.join_type,
-                                sh.condition)
+                                sh.condition, emit=sh.emit)
     if isinstance(op, XJ._BaseTpuJoinExec):
         return PN.SortMergeJoin(_mat(op.children[0]), _mat(op.children[1]),
                                 op.left_keys, op.right_keys, op.join_type,
-                                op.condition)
+                                op.condition, emit=op.emit)
     if isinstance(op, XJ.TpuCartesianProductExec):
         return PN.SortMergeJoin(_mat(op.children[0]), _mat(op.children[1]),
-                                [], [], PN.JoinType.CROSS, op.condition)
+                                [], [], PN.JoinType.CROSS, op.condition,
+                                emit=op.emit)
     if isinstance(op, TpuJoinAggFusedExec):
         # the agg kept the join as its child; materialize the join's TPU
         # output and aggregate it on CPU

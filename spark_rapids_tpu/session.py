@@ -621,7 +621,12 @@ class DataFrame:
         cached = getattr(self, "_plan_cache", None)
         if cached is not None and cached[0] == cache_key:
             return cached[1], cached[2]
-        root, meta = TpuOverrides.apply(self.plan, conf)
+        # column pruning (plan/pruning.py) on the TPU path only, and once
+        # per cached plan: the oracle above keeps running the user's
+        # unpruned plan, an independent check of the pass
+        from spark_rapids_tpu.plan.pruning import prune_columns
+
+        root, meta = TpuOverrides.apply(prune_columns(self.plan), conf)
         self._plan_cache = (cache_key, root, meta)
         return root, meta
 
