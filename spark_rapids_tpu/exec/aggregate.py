@@ -20,7 +20,7 @@ from __future__ import annotations
 from typing import Iterator, List, Optional
 
 import jax
-from spark_rapids_tpu.perfcounters import tpu_jit
+from spark_rapids_tpu.perfcounters import bump, tpu_jit
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
@@ -629,6 +629,7 @@ class TpuHashAggregateExec(TpuExec):
                 B2 = min(max(1 << (n - 1).bit_length(), B * 2),
                          batch.capacity)
                 self._groups_cap_hint = B2
+                bump("agg_groups_cap_regrows")
                 if B2 >= batch.capacity:
                     cols, nrows = self._agg_jit(None)(*args)
                     n = int(nrows)

@@ -11,8 +11,9 @@ materialization program:
   * general path: [build] [probe: lo/counts/sizes] -> ONE host sync for the
     pair count -> [materialize+aggregate fused].  3 programs, 1 sync.
   * unique-build fast path: when the build side's keys are unique (the
-    star-schema dim-table case — learned from the first probe's size sync
-    and cached on the exec), pairs == matched probe rows, so the output
+    star-schema dim-table case — asked of the sorted build side by a
+    sort-free program once a plan, before its first probe, and cached on
+    the exec), pairs == matched probe rows, so the output
     capacity is the probe capacity: probe search, build gather, and the
     whole aggregation run in ONE program with NO size sync.  The unmatched
     probe rows of a LEFT join stay in place with null build columns; an
@@ -48,7 +49,8 @@ from spark_rapids_tpu.exec.join import (
     arranged,
 )
 from spark_rapids_tpu.expr.base import EvalContext
-from spark_rapids_tpu.perfcounters import sync_get, tpu_jit
+from spark_rapids_tpu.ops import mxugather as MG
+from spark_rapids_tpu.perfcounters import bump, span, sync_get, tpu_jit
 from spark_rapids_tpu.plan.nodes import AggregateMode, JoinType
 
 
@@ -61,6 +63,24 @@ def _mask_col(c: DeviceColumn, keep) -> DeviceColumn:
     return DeviceColumn(c.dtype, c.validity & keep, data=c.data,
                         chars=c.chars, lengths=c.lengths,
                         elem_valid=c.elem_valid)
+
+
+def _has_dup_key(bwords, n_valid):
+    """Traced: does any adjacent pair among the first ``n_valid`` sorted
+    build keys compare equal (the build side's keys are not unique)?"""
+    cap_b = bwords[0].shape[0]
+    adj_eq = jnp.ones(cap_b - 1, jnp.bool_)
+    for w in bwords:
+        adj_eq = adj_eq & (w[:-1] == w[1:])
+    in_valid = (jnp.arange(cap_b - 1) + 1) < n_valid
+    return jnp.any(adj_eq & in_valid)
+
+
+def _use_mxu(cap_b: int) -> bool:
+    """The unique-build path's dimension lookup, chosen by the build
+    side's CAPACITY: small tables ride the MXU one-hot contraction
+    (ops/mxugather.py), larger ones the VPU gathers."""
+    return cap_b <= MG.MAX_TABLE_ROWS
 
 
 # process-unique tags for unfingerprintable agg variants (never reused,
@@ -81,18 +101,23 @@ class TpuJoinAggFusedExec(TpuExec):
         self.agg = agg
         self.join = join
         self._jit_cache = {}
-        # None = unknown; True/False learned from the first size sync and
+        # None = unknown; True/False learned from the first build side and
         # reused across collects of the same plan (device-cached scans make
         # repeat execution the hot path)
         self._build_unique: Optional[bool] = None
+        # (path, lookup, build capacity) of the last probe, for describe()
+        self._last_probe: Optional[tuple] = None
 
     @property
     def output(self):
         return self.agg.output
 
     def describe(self):
+        took = ""
+        if self._last_probe is not None:
+            took = " path=%s lookup=%s build_cap=%d" % self._last_probe
         return (f"TpuJoinAggFused[{self.agg.describe()} <- "
-                f"{self.join.describe()}]")
+                f"{self.join.describe()}]{took}")
 
     def _registry_scope(self):
         cached = getattr(self, "_reg_scope", False)
@@ -331,19 +356,33 @@ class TpuJoinAggFusedExec(TpuExec):
     # ------------------------------------------------------------------
     def _probe_agg_one(self, build: _SortedBuildSide, probe: ColumnarBatch,
                        agg) -> ColumnarBatch:
-        if self._build_unique:
-            return self._unique_probe_agg(build, probe, agg)
-        lo, counts, unmatched, sizes = self._probe_sizes(build, probe)
-        total, n_um, has_dup = (int(x) for x in sync_get(sizes))
+        cap_b = build.words[0].shape[0]
         if self._build_unique is None:
-            self._build_unique = has_dup == 0
-        return self._mat_agg(build, probe, lo, counts, unmatched,
-                             total, n_um, agg)
+            # asked of the sorted build side itself, once a plan, by a
+            # sort-free program and a one-scalar sync: a star join's first
+            # collect then takes the one-program path too, and never
+            # compiles or runs the general path's two sort-bearing programs
+            has_dup = self._cached("build_has_dup", _has_dup_key)(
+                tuple(build.words), build.n_valid)
+            self._build_unique = not bool(sync_get(has_dup))
+        if self._build_unique:
+            bump("joinagg_unique_probes")
+            with span("srt.joinagg.unique"):
+                return self._unique_probe_agg(build, probe, agg)
+        bump("joinagg_general_probes")
+        # the pair expansion gathers on the VPU whatever the build's size
+        self._last_probe = ("general", "vpu", cap_b)
+        with span("srt.joinagg.probe_sizes"):
+            lo, counts, unmatched, sizes = self._probe_sizes(build, probe)
+            total, n_um = (int(x) for x in sync_get(sizes))
+        with span("srt.joinagg.mat_agg"):
+            return self._mat_agg(build, probe, lo, counts, unmatched,
+                                 total, n_um, agg)
 
     def _probe_sizes(self, build: _SortedBuildSide, probe: ColumnarBatch):
         """Probe program: lo/counts plus ONE packed sizes vector
-        [total_pairs, n_unmatched, build_has_dup] so sizing costs a single
-        host round trip."""
+        [total_pairs, n_unmatched] so sizing costs a single host round
+        trip."""
         join = self.join
         schema = probe.schema
         ansi, left_keys = join.ansi, join.left_keys   # locals only
@@ -364,16 +403,7 @@ class TpuJoinAggFusedExec(TpuExec):
             total = jnp.sum(counts.astype(jnp.int64))
             unmatched = b.row_mask & (counts == 0)
             n_um = jnp.sum(unmatched.astype(jnp.int64))
-            # build-key uniqueness: any adjacent equal pair among the first
-            # n_valid sorted keys
-            cap_b = bwords[0].shape[0]
-            idx = jnp.arange(cap_b - 1)
-            adj_eq = jnp.ones(cap_b - 1, jnp.bool_)
-            for w in bwords:
-                adj_eq = adj_eq & (w[:-1] == w[1:])
-            in_valid = (idx + 1) < n_valid
-            has_dup = jnp.any(adj_eq & in_valid).astype(jnp.int64)
-            sizes = jnp.stack([total, n_um, has_dup])
+            sizes = jnp.stack([total, n_um])
             return lo, counts, unmatched, sizes
 
         jitted = self._cached("probe_sizes", fn)
@@ -426,6 +456,12 @@ class TpuJoinAggFusedExec(TpuExec):
         ansi, left_keys = join.ansi, join.left_keys
         agg_fn = agg.detached_for_trace()._agg_fn   # no subtree capture
         slots, p_sel = join._mat_slots, join._p_sel
+        # for the counters and describe() only: the registry shares ``fn``
+        # among execs whose build sides differ in capacity, so the trace
+        # asks the operand shapes itself and closes over nothing of them
+        cap_b = build.words[0].shape[0]
+        lookup = "mxu" if _use_mxu(cap_b) else "vpu"
+        self._last_probe = ("unique", lookup, cap_b)
 
         def mk(groups_cap):
             def fn(bwords, row_index, n_valid, b_cols, p_cols, num_rows):
@@ -444,9 +480,7 @@ class TpuJoinAggFusedExec(TpuExec):
                 # random gather costs ~300ms per column at 20M probe rows
                 # while the fused one_hot@table contraction is ~5ms
                 # (ops/mxugather.py)
-                from spark_rapids_tpu.ops import mxugather as MG
-
-                use_mxu = cap_b <= MG.MAX_TABLE_ROWS
+                use_mxu = _use_mxu(cap_b)
                 eq = jnp.ones(lo.shape, jnp.bool_)
                 for w, q in zip(bwords, qwords):
                     wl = MG.mxu_gather(w, loc) if use_mxu else w[loc]
@@ -478,27 +512,31 @@ class TpuJoinAggFusedExec(TpuExec):
                 tuple(build.batch.columns[i] for i in join._b_sel),
                 tuple(probe.columns), jnp.int32(probe.num_rows))
         cap = probe.capacity
-        B = agg._bounded_groups_cap(cap)
         tag = self._agg_tag(agg)
+
+        def run(groups_cap):
+            # one bump a call of the fused program, by the branch it took
+            bump("join_lookups_" + lookup)
+            return self._cached(("uniq_agg", tag, groups_cap),
+                                mk(groups_cap))(*args)
+
+        B = agg._bounded_groups_cap(cap)
         if B:
-            cols, nrows = self._cached(("uniq_agg", tag, B),
-                                       mk(B))(*args)
+            cols, nrows = run(B)
             n = int(nrows)
             while n > B:
                 B2 = min(max(1 << (n - 1).bit_length(), B * 2), cap)
                 agg._groups_cap_hint = B2
+                bump("agg_groups_cap_regrows")
                 if B2 >= cap:
                     B2 = None
-                cols, nrows = self._cached(("uniq_agg", tag, B2),
-                                           mk(B2))(*args)
-                if B2 is None:
-                    n = int(nrows)
-                    break
+                cols, nrows = run(B2)
                 n = int(nrows)
+                if B2 is None:
+                    break
                 B = B2
             return self._finish(agg, cols, n)
-        cols, nrows = self._cached(("uniq_agg", tag, None),
-                                   mk(None))(*args)
+        cols, nrows = run(None)
         return self._finish(agg, cols, nrows)
 
 
@@ -661,6 +699,7 @@ class TpuWindowChainFusedExec(TpuExec):
                     B2 = min(max(1 << (g - 1).bit_length(), B * 2),
                              b.capacity)
                     self.pre_agg._groups_cap_hint = B2
+                    bump("agg_groups_cap_regrows")
                     if B2 >= b.capacity:
                         B2 = None
                     cols, count, ng = self._cached(

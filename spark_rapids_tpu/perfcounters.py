@@ -222,6 +222,18 @@ COUNTERS: Dict[str, int] = {
     # column pruning (plan/pruning.py): columns dropped, summed over the
     # nodes narrowed, once per planning (never per collect)
     "plan_columns_pruned": 0,
+    # join -> aggregate fusion (exec/fused.py): probe batches through the
+    # unique-build one-program path / the general three-program path;
+    # calls of the one-program path by the dimension lookup its build
+    # capacity chose (MXU one-hot contraction up to
+    # ops/mxugather.MAX_TABLE_ROWS, VPU gathers beyond); and re-runs of an
+    # aggregate on a wider rung of its groups-cap ladder (fused.py,
+    # aggregate.py)
+    "joinagg_unique_probes": 0,
+    "joinagg_general_probes": 0,
+    "join_lookups_mxu": 0,
+    "join_lookups_vpu": 0,
+    "agg_groups_cap_regrows": 0,
 }
 
 

@@ -302,7 +302,8 @@ SPAN_TABLE = {
     "srt.execute", "srt.launch", "srt.sync", "srt.rows", "srt.scan.read", "srt.scan.to_columns", "srt.scan.h2d",
     "srt.scan.device_decode", "srt.scan.prefetch_wait",
     "srt.exchange.partition", "srt.exchange.queue", "srt.join.build",
-    "srt.join.probe", "srt.join.materialize"}
+    "srt.join.probe", "srt.join.materialize", "srt.joinagg.unique",
+    "srt.joinagg.probe_sizes", "srt.joinagg.mat_agg"}
 
 
 def test_span_names_are_the_documented_table_and_none_is_collect():
@@ -325,6 +326,7 @@ def test_span_names_are_the_documented_table_and_none_is_collect():
     ("srt.launch", ["perfcounters.py"]),
     ("srt.scan.h2d", ["io/scan.py"]),
     ("srt.exchange.partition", ["exec/exchange.py"]),
+    ("srt.joinagg.unique", ["exec/fused.py"]),
 ])
 def test_a_span_has_one_site(name, files):
     assert _span_names()[name] == [
