@@ -44,8 +44,10 @@ from spark_rapids_tpu.exec.base import TpuExec
 from spark_rapids_tpu.exec.join import (
     _BaseTpuJoinExec,
     _key_words_of,
+    _merge_lookup,
     _multiword_searchsorted,
     _SortedBuildSide,
+    _takes_merge,
     arranged,
 )
 from spark_rapids_tpu.expr.base import EvalContext
@@ -105,17 +107,15 @@ class TpuJoinAggFusedExec(TpuExec):
         # reused across collects of the same plan (device-cached scans make
         # repeat execution the hot path)
         self._build_unique: Optional[bool] = None
-        # (path, lookup, build capacity) of the last probe, for describe()
-        self._last_probe: Optional[tuple] = None
+        # what the last probe took ("path=... build_cap=N"), for describe()
+        self._last_probe: Optional[str] = None
 
     @property
     def output(self):
         return self.agg.output
 
     def describe(self):
-        took = ""
-        if self._last_probe is not None:
-            took = " path=%s lookup=%s build_cap=%d" % self._last_probe
+        took = "" if self._last_probe is None else " " + self._last_probe
         return (f"TpuJoinAggFused[{self.agg.describe()} <- "
                 f"{self.join.describe()}]{took}")
 
@@ -371,7 +371,7 @@ class TpuJoinAggFusedExec(TpuExec):
                 return self._unique_probe_agg(build, probe, agg)
         bump("joinagg_general_probes")
         # the pair expansion gathers on the VPU whatever the build's size
-        self._last_probe = ("general", "vpu", cap_b)
+        self._last_probe = f"path=general lookup=vpu build_cap={cap_b}"
         with span("srt.joinagg.probe_sizes"):
             lo, counts, unmatched, sizes = self._probe_sizes(build, probe)
             total, n_um = (int(x) for x in sync_get(sizes))
@@ -449,7 +449,15 @@ class TpuJoinAggFusedExec(TpuExec):
         in ONE program; no size sync (output capacity == probe capacity).
         The aggregate runs through its bounded-cardinality ladder
         (groups_cap) — the synced output row count is the overflow
-        check."""
+        check.
+
+        Past the binary search's sizes (``_takes_merge``) the probe's one
+        merge sort says whether a probe row matched and at which SORTED
+        build position (``_merge_lookup``): no key word is gathered to
+        compare it.  The payload is then fetched by that position from
+        build columns permuted into key order, where the permute is the
+        smaller gather (build capacity <= probe capacity); else through
+        ``row_index[loc]`` from the columns as they are."""
         join = self.join
         left_outer = join.join_type == JoinType.LEFT_OUTER
         schema = probe.schema
@@ -461,7 +469,9 @@ class TpuJoinAggFusedExec(TpuExec):
         # asks the operand shapes itself and closes over nothing of them
         cap_b = build.words[0].shape[0]
         lookup = "mxu" if _use_mxu(cap_b) else "vpu"
-        self._last_probe = ("unique", lookup, cap_b)
+        match = "merge" if _takes_merge(cap_b, probe.capacity) else "gather"
+        self._last_probe = (f"path=unique lookup={lookup} match={match} "
+                            f"build_cap={cap_b}")
 
         def mk(groups_cap):
             def fn(bwords, row_index, n_valid, b_cols, p_cols, num_rows):
@@ -472,33 +482,44 @@ class TpuJoinAggFusedExec(TpuExec):
                 for kc in key_cols:
                     valid = valid & kc.validity
                 qwords = _key_words_of(key_cols)
-                lo = _multiword_searchsorted(list(bwords), n_valid, qwords,
-                                             "left")
-                cap_b = bwords[0].shape[0]
-                loc = jnp.clip(lo, 0, cap_b - 1)
+                cap_b, cap_p = bwords[0].shape[0], qwords[0].shape[0]
                 # small build tables ride the MXU one-hot gather: a VPU
                 # random gather costs ~300ms per column at 20M probe rows
                 # while the fused one_hot@table contraction is ~5ms
                 # (ops/mxugather.py)
                 use_mxu = _use_mxu(cap_b)
-                eq = jnp.ones(lo.shape, jnp.bool_)
-                for w, q in zip(bwords, qwords):
-                    wl = MG.mxu_gather(w, loc) if use_mxu else w[loc]
-                    eq = eq & (wl == q)
-                found = valid & (lo < n_valid) & eq
-                if use_mxu:
-                    brow = jnp.where(found, MG.mxu_gather(row_index, loc),
-                                     0)
-                    bcols = []
-                    for c in b_cols:
-                        g = MG.mxu_gather_col(c, brow)
-                        if g is None:
-                            g = c.gather(brow)
-                        bcols.append(_mask_col(g, found))
+
+                def at(table, idx):
+                    return MG.mxu_gather(table, idx) if use_mxu \
+                        else table[idx]
+
+                merge = _takes_merge(cap_b, cap_p)
+                if merge:
+                    loc, matched = _merge_lookup(list(bwords), n_valid,
+                                                 qwords)
+                    found = valid & matched
                 else:
-                    brow = jnp.where(found, row_index[loc], 0)
-                    bcols = [_mask_col(c.gather(brow), found)
-                             for c in b_cols]
+                    lo = _multiword_searchsorted(list(bwords), n_valid,
+                                                 qwords, "left")
+                    loc = jnp.clip(lo, 0, cap_b - 1)
+                    eq = jnp.ones(lo.shape, jnp.bool_)
+                    for w, q in zip(bwords, qwords):
+                        eq = eq & (at(w, loc) == q)
+                    found = valid & (lo < n_valid) & eq
+                if merge and cap_b <= cap_p:
+                    # payload in key order: one build-sized gather a
+                    # column, then ``loc`` indexes it directly
+                    brow = jnp.where(found, loc, 0)
+                    src = [c.gather(row_index) for c in b_cols]
+                else:
+                    brow = jnp.where(found, at(row_index, loc), 0)
+                    src = b_cols
+                bcols = []
+                for c in src:
+                    g = MG.mxu_gather_col(c, brow) if use_mxu else None
+                    if g is None:
+                        g = c.gather(brow)
+                    bcols.append(_mask_col(g, found))
                 joined = tuple(arranged(
                     slots, [p_cols[i] for i in p_sel], bcols))
                 row_valid = b.row_mask if left_outer \
@@ -515,8 +536,10 @@ class TpuJoinAggFusedExec(TpuExec):
         tag = self._agg_tag(agg)
 
         def run(groups_cap):
-            # one bump a call of the fused program, by the branch it took
+            # one bump each a call of the fused program, by the payload
+            # lookup and the key match it took
             bump("join_lookups_" + lookup)
+            bump("join_matches_" + match)
             return self._cached(("uniq_agg", tag, groups_cap),
                                 mk(groups_cap))(*args)
 

@@ -66,6 +66,13 @@ def _lex_less(a_words: List[jax.Array], b_words: List[jax.Array],
     return lt | eq if or_equal else lt
 
 
+def _takes_merge(n: int, nq: int) -> bool:
+    """Static: a build side of capacity ``n`` probed by ``nq`` rows takes
+    the shared sort (``_merge_rank``, ``_merge_lookup``); smaller inputs
+    the binary-search gather loop."""
+    return n >= (1 << 14) or nq >= (1 << 14)
+
+
 def _multiword_searchsorted(sorted_words: List[jax.Array], n_valid,
                             query_words: List[jax.Array],
                             side: str) -> jax.Array:
@@ -84,7 +91,7 @@ def _multiword_searchsorted(sorted_words: List[jax.Array], n_valid,
     """
     n = sorted_words[0].shape[0]
     nq = query_words[0].shape[0]
-    if n >= (1 << 14) or nq >= (1 << 14):
+    if _takes_merge(n, nq):
         return _merge_rank(sorted_words, n_valid, query_words, side)
     lo = jnp.zeros(nq, jnp.int32)
     hi = jnp.broadcast_to(n_valid.astype(jnp.int32), (nq,))
@@ -133,6 +140,56 @@ def _merge_rank(sorted_words: List[jax.Array], n_valid,
     nb_before = jnp.cumsum(is_build) - is_build
     qpos = jnp.where(is_build == 1, nq, pos - n)
     return jnp.zeros(nq, jnp.int32).at[qpos].set(nb_before, mode="drop")
+
+
+def _merge_lookup(sorted_words: List[jax.Array], n_valid,
+                  query_words: List[jax.Array]
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """(loc, matched) per query row from the shared sort alone: whether a
+    valid sorted build key equals the query key, and that key's sorted
+    position.  ``_merge_rank``'s sort and operands, with the tie
+    ordering a build key BEFORE equal query keys, so in sorted order
+
+      * a run of equal keys (``invalid`` included) holds a build key iff
+        its FIRST element is one: one running max over the run starts
+        carries that bit to every query of the run;
+      * the number of build keys at or before a query, less one, is the
+        position of the last build key <= it: the equal one where there
+        is one (the last of them where the build keys repeat).
+
+    No gather from the build side: ``words[loc] == query`` costs a
+    full-width random gather a 32-bit word (~36 ms at 2^22 rows on a
+    v5e, PERF.md), the compare of neighbours and the scan are sequential
+    passes.  The build tail (rows >= n_valid: filtered and null keys)
+    sorts after every query and never matches.  ``loc`` is meaningful
+    only where ``matched``; both ride back to query order in the ONE
+    scatter ``_merge_rank`` makes too."""
+    n = sorted_words[0].shape[0]
+    nq = query_words[0].shape[0]
+    assert n + nq < (1 << 30)     # position and match bit share an int32
+    b_inv = (jnp.arange(n, dtype=jnp.int32)
+             >= n_valid.astype(jnp.int32)).astype(jnp.int32)
+    words = [jnp.concatenate([b_inv, jnp.zeros(nq, jnp.int32)])]
+    for sw, qw in zip(sorted_words, query_words):
+        words.append(jnp.concatenate([sw, qw]))
+    words.append(jnp.concatenate([jnp.zeros(n, jnp.int32),
+                                  jnp.ones(nq, jnp.int32)]))
+    iota = jnp.arange(n + nq, dtype=jnp.int32)
+    srt = jax.lax.sort(tuple(words) + (iota,), num_keys=len(words),
+                       is_stable=False)
+    pos = srt[-1]
+    is_build = (pos < n).astype(jnp.int32)
+    differs = jnp.zeros(n + nq - 1, jnp.bool_)
+    for k in srt[:-2]:            # invalid and the key words, not the tie
+        differs = differs | (k[1:] != k[:-1])
+    new_run = jnp.concatenate([jnp.ones(1, jnp.bool_), differs])
+    # lax.cummax, never associative_scan (see _slots_to_probe_rows)
+    head = jax.lax.cummax(jnp.where(new_run, 2 * iota + is_build, 0))
+    loc = jnp.maximum(jnp.cumsum(is_build) - 1, 0)
+    qpos = jnp.where(is_build == 1, nq, pos - n)
+    packed = jnp.zeros(nq, jnp.int32).at[qpos].set(
+        2 * loc + (head & 1), mode="drop")
+    return packed >> 1, (packed & 1) == 1
 
 
 def _slots_to_probe_rows(excl, counts, out_cap: int) -> jax.Array:

@@ -226,13 +226,17 @@ COUNTERS: Dict[str, int] = {
     # unique-build one-program path / the general three-program path;
     # calls of the one-program path by the dimension lookup its build
     # capacity chose (MXU one-hot contraction up to
-    # ops/mxugather.MAX_TABLE_ROWS, VPU gathers beyond); and re-runs of an
-    # aggregate on a wider rung of its groups-cap ladder (fused.py,
-    # aggregate.py)
+    # ops/mxugather.MAX_TABLE_ROWS, VPU gathers beyond) and by where the
+    # key match came from (the probe's merge sort itself, join.py
+    # _merge_lookup, or the binary search's gathered key words for small
+    # inputs); and re-runs of an aggregate on a wider rung of its
+    # groups-cap ladder (fused.py, aggregate.py)
     "joinagg_unique_probes": 0,
     "joinagg_general_probes": 0,
     "join_lookups_mxu": 0,
     "join_lookups_vpu": 0,
+    "join_matches_merge": 0,
+    "join_matches_gather": 0,
     "agg_groups_cap_regrows": 0,
 }
 

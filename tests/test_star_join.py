@@ -1,14 +1,22 @@
 """The TPC-DS star join (benchmark cell ``ds_broadcast_join_agg``) through
 the engine against its plain numpy reference, on both dimension lookups
 of ``TpuJoinAggFusedExec``'s one-program path: a 2,555-row calendar pads
-to 8,192 rows and rides the MXU one-hot contraction, the specification's
-73,049 rows pad to 262,144 and take the VPU gathers behind a merge-rank
-search; each with keys and measure nullable and not.
+to 8,192 rows and rides the MXU one-hot contraction behind a binary
+search that gathers the key words, the specification's 73,049 rows pad
+to 262,144 and take the VPU gathers, match and position from the merge
+sort; each with keys and measure nullable and not.  The merge branch on
+both sides of its payload rule: the fact rows' 8,192-row bucket under
+the calendar's 262,144 (``row_index[loc]``), and 10,000 fact rows
+against 10,000 days, both at 65,536 (the payload permuted into key
+order); and under the MXU lookup: 1,000 days, which end before the fact
+rows' dates do, in the 1,024-row bucket against 65,536.
 
 Collected twice: the first collect asks the sorted build side whether
 its keys are unique and takes the one-program path at once (the general
 path, with its size sync and pair expansion, is for a build side with
 duplicate keys: tests/test_fusion_perf.py), the second runs it again."""
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
@@ -20,7 +28,21 @@ from chip_smoke import _find_exec
 
 FACT_ROWS = 3000
 COUNTERS = ("joinagg_general_probes", "joinagg_unique_probes",
-            "join_lookups_mxu", "join_lookups_vpu", "agg_groups_cap_regrows")
+            "join_lookups_mxu", "join_lookups_vpu", "join_matches_merge",
+            "join_matches_gather", "agg_groups_cap_regrows")
+
+
+def _spec_slice(n_dates, fact_rows):
+    """Stands in for a calendar module: ``n_dates`` days of the
+    specification's calendar from 1998-01-01 on (the fact rows' first
+    year), joined by ``fact_rows`` fact rows instead of FACT_ROWS."""
+    def make(n, rng):
+        whole = date_dim_spec.make(date_dim_spec.N_DATES, rng)
+        first = int(np.searchsorted(whole["d_year"], 1998))
+        return {c: v[first:first + n] for c, v in whole.items()}
+
+    return SimpleNamespace(N_DATES=n_dates, FACT_ROWS=fact_rows,
+                           TYPES=date_dim_spec.TYPES, make=make)
 
 
 @pytest.fixture(scope="module")
@@ -36,7 +58,8 @@ def session():
 def _tables(calendar, nullable, seed=29):
     rng = np.random.default_rng(seed)
     dd = calendar.make(calendar.N_DATES, rng)
-    ss = store_sales_star.make(FACT_ROWS, rng)
+    ss = store_sales_star.make(
+        getattr(calendar, "FACT_ROWS", FACT_ROWS), rng)
     types = list(store_sales_star.TYPES)
     if not nullable:
         ss = {c: np.ma.getdata(v) for c, v in ss.items()}
@@ -57,11 +80,15 @@ def _without_mask(tables, column):
 
 
 @pytest.mark.parametrize("nullable", [False, True], ids=["plain", "nullable"])
-@pytest.mark.parametrize("calendar,lookup,build_cap", [
-    (date_dim, "mxu", 8192), (date_dim_spec, "vpu", 262144)],
-    ids=["2555_rows_mxu", "73049_rows_vpu"])
-def test_engine_matches_the_reference(session, calendar, lookup, build_cap,
-                                      nullable):
+@pytest.mark.parametrize("calendar,lookup,match,build_cap", [
+    (date_dim, "mxu", "gather", 8192),
+    (date_dim_spec, "vpu", "merge", 262144),
+    (_spec_slice(10000, 10000), "vpu", "merge", 65536),
+    (_spec_slice(1000, 10000), "mxu", "merge", 1024)],
+    ids=["2555_rows_mxu", "73049_rows_vpu", "10000_rows_equal_caps",
+         "1000_rows_mxu_merge"])
+def test_engine_matches_the_reference(session, calendar, lookup, match,
+                                      build_cap, nullable):
     from spark_rapids_tpu import perfcounters as PC
     from spark_rapids_tpu.exec.exchange import (
         TpuBroadcastExchangeExec,
@@ -77,7 +104,7 @@ def test_engine_matches_the_reference(session, calendar, lookup, build_cap,
                                       calendar.TYPES, "date_dim")}
     df = QA.build(frames)
     want = QA.reference(tables)
-    assert len(want) > 40 and _no_null_sum(want)
+    assert len(want) > 30 and _no_null_sum(want)
 
     moved = []
     for _ in range(2):
@@ -87,10 +114,12 @@ def test_engine_matches_the_reference(session, calendar, lookup, build_cap,
         moved.append((delta["programs_launched"], delta["host_syncs"])
                      + tuple(delta[k] for k in COUNTERS))
     # one probe batch a collect, one call of the one-program path on the
-    # lookup the build capacity chose; the first collect's third program
-    # and second sync ask whether the build keys are unique
+    # lookup the build capacity chose and the match both capacities chose;
+    # the first collect's third program and second sync ask whether the
+    # build keys are unique
     unique = tuple(int(k in ("joinagg_unique_probes",
-                             "join_lookups_" + lookup)) for k in COUNTERS)
+                             "join_lookups_" + lookup,
+                             "join_matches_" + match)) for k in COUNTERS)
     assert moved == [(3, 3) + unique, (2, 2) + unique]
 
     root = df._planned()[0]
@@ -99,12 +128,12 @@ def test_engine_matches_the_reference(session, calendar, lookup, build_cap,
     assert _find_exec(root, TpuBroadcastExchangeExec) is not None
     assert _find_exec(root, TpuShuffleExchangeExec) is None
     assert fused.describe().endswith(
-        f" path=unique lookup={lookup} build_cap={build_cap}")
-    assert f"lookup={lookup}" in root.pretty()
+        f" path=unique lookup={lookup} match={match} build_cap={build_cap}")
+    assert f"lookup={lookup} match={match}" in root.pretty()
 
     if nullable:
         # a null store is a group of its own, in more than one year
-        assert sum(k[1] is None for k in want) >= 4
+        assert sum(k[1] is None for k in want) >= 3
         # and the test would see the reference's null handling planted
         # wrong: a null date key matched by the value under its mask, a
         # null measure added, the null-store rows given to a store
