@@ -7,7 +7,7 @@ columns (data + validity bitmask + offsets) as Spark ColumnVectors.
 TPU-first design decisions (NOT a translation of the cuDF layout):
 
 * **Padded capacities.** XLA compiles per shape.  Every column is padded to a
-  row-capacity bucket (pow2 ladder, ``spark.rapids.tpu.batch.rowBuckets``) so
+  row-capacity bucket (pow2 ladder, ``DEFAULT_ROW_BUCKETS`` below) so
   a query sees a handful of compiled programs, not one per batch size.  The
   logical row count rides alongside (host int) and as a device scalar inside
   fused programs; rows past ``num_rows`` are garbage and masked off.
@@ -21,7 +21,7 @@ TPU-first design decisions (NOT a translation of the cuDF layout):
   (chars, offsets); offset-indirection defeats XLA's static-shape tiling, so
   strings here are a ``(capacity, width)`` uint8 matrix plus an int32 length
   vector, with ``width`` drawn from a bucket ladder
-  (``spark.rapids.tpu.string.widthBuckets``).  Lexicographic compare, hash,
+  (``DEFAULT_WIDTH_BUCKETS`` below).  Lexicographic compare, hash,
   substring etc. become dense vector ops.  Memory overhead is bounded by the
   ladder and by width re-bucketing at coalesce time.
 

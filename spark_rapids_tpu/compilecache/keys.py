@@ -11,7 +11,7 @@ difference only costs a hit).  Every key is built from:
   * static mode flags (ansi, aggregate mode, join type, frame, ...) passed
     by the call site,
   * the ambient conf fingerprint (sorted settings) — conf knobs are read at
-    trace time (hasNans, groups-cap, ...), so two sessions with different
+    trace time (ansi, groups-cap, ...), so two sessions with different
     settings never share an executable.
 
 Expressions that close over arbitrary Python state (UDFs, host-kernel
@@ -113,7 +113,7 @@ def _nested_seeds(e, acc=None):
 
 def conf_fp() -> str:
     """Fingerprint of the ambient execution conf (config.get_conf()) —
-    trace-time conf reads (hasNans, smallGroupsCap, buckets...) make the
+    trace-time conf reads (ansi, smallGroupsCap, ...) make the
     settings part of the program identity."""
     from spark_rapids_tpu.config import get_conf
 

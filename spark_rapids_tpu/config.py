@@ -106,7 +106,6 @@ def _parse_bytes(s: str) -> int:
                 return int(float(num) * _UNITS[suffix])
     return int(s)
 
-
 # ---------------------------------------------------------------------------
 # The registry (RapidsConf.scala analog).  Grouped as the reference groups its
 # docs: general / memory / sql / io / shuffle / tpu runtime / testing.
@@ -119,27 +118,9 @@ EXPLAIN = conf("spark.rapids.sql.explain").doc(
     "NONE, NOT_ON_GPU, or ALL: log why (parts of) a plan did or did not run "
     "on the TPU. NOT_ON_GPU prints only fallback reasons.").string_conf("NONE")
 
-INCOMPATIBLE_OPS = conf("spark.rapids.sql.incompatibleOps.enabled").doc(
-    "Enable ops whose TPU results differ from Spark in corner cases "
-    "(e.g. float ordering in aggregations).").boolean_conf(True)
-
 ANSI_ENABLED = conf("spark.sql.ansi.enabled").doc(
     "Spark ANSI mode: overflow/invalid-cast raise instead of null/wrap."
 ).boolean_conf(False)
-
-CASE_SENSITIVE = conf("spark.sql.caseSensitive").doc(
-    "Spark column-name case sensitivity.").boolean_conf(False)
-
-HAS_NANS = conf("spark.rapids.sql.hasNans").doc(
-    "Assume floating point data may contain NaNs (affects min/max/joins)."
-).boolean_conf(True)
-
-IMPROVED_FLOAT_OPS = conf("spark.rapids.sql.improvedFloatOps.enabled").doc(
-    "Allow float ops that may differ from Spark in ULPs.").boolean_conf(True)
-
-VARIABLE_FLOAT_AGG = conf("spark.rapids.sql.variableFloatAgg.enabled").doc(
-    "Allow float aggregation whose result may vary with parallelism "
-    "(non-deterministic order of adds).").boolean_conf(True)
 
 # --- memory / runtime (GpuDeviceManager / RapidsConf memory group) ---------
 
@@ -154,10 +135,6 @@ BATCH_SIZE_BYTES = conf("spark.rapids.sql.batchSizeBytes").doc(
 MAX_READER_BATCH_SIZE_ROWS = conf(
     "spark.rapids.sql.reader.batchSizeRows").doc(
     "Soft cap on rows per batch produced by readers.").integer_conf(2147483647)
-
-MAX_READER_BATCH_SIZE_BYTES = conf(
-    "spark.rapids.sql.reader.batchSizeBytes").doc(
-    "Soft cap on bytes per batch produced by readers.").bytes_conf(1 << 31)
 
 HBM_POOL_FRACTION = conf("spark.rapids.memory.gpu.allocFraction").doc(
     "Fraction of HBM the arena may use for batches.").double_conf(0.9)
@@ -175,10 +152,6 @@ SPILL_DIR = conf("spark.rapids.memory.spillDir").doc(
 RETRY_MAX_ATTEMPTS = conf("spark.rapids.tpu.retry.maxAttempts").doc(
     "Max OOM-retry attempts per batch before giving up (reference: "
     "RmmRapidsRetryIterator).").integer_conf(8)
-
-SPLIT_UNTIL_ROWS = conf("spark.rapids.tpu.retry.minSplitRows").doc(
-    "Do not split batches below this many rows on SplitAndRetry."
-).integer_conf(8)
 
 # --- query lifecycle (admission control / deadlines / cancellation) --------
 
@@ -412,12 +385,6 @@ DISTRIBUTED_REDRIVE_MAX = conf(
     "operator fault domain — which falls back to the CPU oracle "
     "without indicting the operator's breaker key.").long_conf(4)
 
-DISTRIBUTED_WORKER_MEM = conf(
-    "spark.rapids.tpu.distributed.workerMemoryBytes").doc(
-    "Default per-worker block-store memory budget handed to spawned "
-    "workers; blocks past it overflow to the worker's spill "
-    "directory (the netty shuffle-file analog).").bytes_conf(64 << 20)
-
 DISTRIBUTED_LOSS_BREAKER_THRESHOLD = conf(
     "spark.rapids.tpu.distributed.lossBreakerThreshold").doc(
     "Loss declarations that OPEN a worker's circuit-breaker entry.  "
@@ -536,16 +503,6 @@ RECOVERY_LEASE_TTL_MS = conf("spark.rapids.tpu.recovery.leaseTtlMs").doc(
     "A reborn driver retires anything older (recovery_leases_expired) "
     "and re-executes from scratch — orphaned worker partitions must "
     "not pin memory forever.").long_conf(120_000)
-
-RECOVERY_WORKER_REATTACH_MS = conf(
-    "spark.rapids.tpu.recovery.workerReattachMs").doc(
-    "How long a worker that lost its driver (heartbeat socket died) "
-    "keeps its store alive and retries re-attaching through the "
-    "recovery-dir endpoint file before giving up and exiting.  The "
-    "re-HELLO enumerates held (exchange, partition, seq-range) "
-    "inventory so the reborn coordinator can rebuild placement.  "
-    "0 keeps the pre-recovery behavior: a dead control socket ends "
-    "the worker.").long_conf(30_000)
 
 # --- resilience (stage-level fault domains) --------------------------------
 
@@ -680,38 +637,10 @@ AGG_SMALL_GROUPS_CAP = conf("spark.rapids.tpu.agg.smallGroupsCap").doc(
 
 # --- plan / exec switches --------------------------------------------------
 
-ENABLE_CAST_FLOAT_TO_STRING = conf(
-    "spark.rapids.sql.castFloatToString.enabled").doc(
-    "Float->string cast may differ from Spark in digits.").boolean_conf(True)
-
-ENABLE_CAST_STRING_TO_FLOAT = conf(
-    "spark.rapids.sql.castStringToFloat.enabled").doc(
-    "String->float cast compat switch.").boolean_conf(True)
-
 ENABLE_CAST_STRING_TO_TIMESTAMP = conf(
     "spark.rapids.sql.castStringToTimestamp.enabled").doc(
     "String->timestamp cast compat switch (device civil parser; named "
     "timezones parse as null).").boolean_conf(True)
-
-ENABLE_FLOAT_AGG = conf("spark.rapids.sql.castFloatToDecimal.enabled").doc(
-    "Float->decimal cast compat switch.").boolean_conf(True)
-
-STABLE_SORT = conf("spark.rapids.sql.stableSort.enabled").doc(
-    "Force stable sort (adds row-index tiebreaker column).").boolean_conf(False)
-
-SORT_OOC_ENABLED = conf("spark.rapids.sql.sort.outOfCore.enabled").doc(
-    "Enable out-of-core sort (spill sorted runs + N-way merge; reference: "
-    "GpuOutOfCoreSortIterator).").boolean_conf(True)
-
-AGG_FALLBACK_PARTIALS = conf(
-    "spark.rapids.sql.agg.skipAggPassReductionRatio").doc(
-    "Skip partial agg when it is not reducing rows by at least this ratio."
-).double_conf(0.9)
-
-JOIN_SUBPARTITION_THRESHOLD = conf(
-    "spark.rapids.sql.join.subPartition.numRowsThreshold").doc(
-    "Build side larger than this triggers sub-partitioned join "
-    "(reference: GpuSubPartitionHashJoin).").integer_conf(1 << 22)
 
 # --- IO --------------------------------------------------------------------
 
@@ -723,13 +652,6 @@ PARQUET_MULTITHREAD_READ_NUM_THREADS = conf(
     "spark.rapids.sql.multiThreadedRead.numThreads").doc(
     "Host threads fetching/decoding files in parallel.").integer_conf(20)
 
-PARQUET_MAX_NUM_FILES_PARALLEL = conf(
-    "spark.rapids.sql.format.parquet.multiThreadedRead.maxNumFilesParallel"
-).doc("Cap on files in flight per task.").integer_conf(2147483647)
-
-PARQUET_ENABLED = conf("spark.rapids.sql.format.parquet.enabled").doc(
-    "Enable TPU parquet scan/write.").boolean_conf(True)
-
 PARQUET_READ_ENABLED = conf("spark.rapids.sql.format.parquet.read.enabled").doc(
     "Enable TPU parquet scans.").boolean_conf(True)
 
@@ -737,12 +659,8 @@ PARQUET_WRITE_ENABLED = conf(
     "spark.rapids.sql.format.parquet.write.enabled").doc(
     "Enable TPU parquet writes.").boolean_conf(True)
 
-CSV_ENABLED = conf("spark.rapids.sql.format.csv.enabled").boolean_conf(True)
 CSV_READ_ENABLED = conf("spark.rapids.sql.format.csv.read.enabled").boolean_conf(True)
-JSON_ENABLED = conf("spark.rapids.sql.format.json.enabled").boolean_conf(True)
 JSON_READ_ENABLED = conf("spark.rapids.sql.format.json.read.enabled").boolean_conf(True)
-ORC_ENABLED = conf("spark.rapids.sql.format.orc.enabled").boolean_conf(True)
-AVRO_ENABLED = conf("spark.rapids.sql.format.avro.enabled").boolean_conf(True)
 PARQUET_DEVICE_DECODE = conf(
     "spark.rapids.sql.format.parquet.decode.device").doc(
     "Decode Parquet pages with the Pallas kernels (bit-unpack + run "
@@ -1067,8 +985,6 @@ ICI_CROSS_SLICE_HOSTS = conf(
 
 SHUFFLE_MT_WRITER_THREADS = conf(
     "spark.rapids.shuffle.multiThreaded.writer.threads").integer_conf(20)
-SHUFFLE_MT_READER_THREADS = conf(
-    "spark.rapids.shuffle.multiThreaded.reader.threads").integer_conf(20)
 
 SHUFFLE_PARTITIONS = conf("spark.sql.shuffle.partitions").doc(
     "Number of shuffle partitions.").integer_conf(16)
@@ -1347,18 +1263,6 @@ TEST_RETRY_OOM_INJECTION_MODE = conf(
 
 # --- TPU-specific ----------------------------------------------------------
 
-TPU_ROW_BUCKETS = conf("spark.rapids.tpu.batch.rowBuckets").doc(
-    "Comma-separated pow2 row-capacity buckets batches are padded to, so XLA "
-    "recompiles are bounded (static shapes).").string_conf(
-    "1024,8192,65536,262144,1048576,4194304")
-
-TPU_STRING_WIDTH_BUCKETS = conf("spark.rapids.tpu.string.widthBuckets").doc(
-    "Char-width buckets for the padded string layout.").string_conf(
-    "8,32,128,512,2048")
-
-TPU_DONATE_BUFFERS = conf("spark.rapids.tpu.donateInputBuffers").doc(
-    "Donate input HBM buffers to XLA where legal.").boolean_conf(True)
-
 ORC_DEVICE_DECODE = conf(
     "spark.rapids.sql.format.orc.decode.device").doc(
     "Decode ORC stripe numerics on device: host parses protobuf footers "
@@ -1441,14 +1345,6 @@ class TpuConf:
     @property
     def shuffle_partitions(self):
         return self.get(SHUFFLE_PARTITIONS)
-
-    @property
-    def row_buckets(self) -> List[int]:
-        return sorted(int(x) for x in self.get(TPU_ROW_BUCKETS).split(","))
-
-    @property
-    def string_width_buckets(self) -> List[int]:
-        return sorted(int(x) for x in self.get(TPU_STRING_WIDTH_BUCKETS).split(","))
 
 
 _lock = threading.Lock()

@@ -17,9 +17,8 @@ semantics the differential harness exercises, entirely as fused vector ops:
   * string -> timestamp/date: vectorized variable-width civil parsing of
     Spark's stringToTimestamp grammar (see _FieldCursor for the documented
     subset; named timezones fall out as nulls).
-  * float->string remains a plan-time fallback, gated exactly like the
-    reference gates castFloatToString
-    (spark.rapids.sql.castFloatToString.enabled) — see overrides/.
+  * float<->string run as host kernels (Java shortest-repr formatting,
+    Spark's float grammar) — see ``Cast.is_host_kernel``.
 """
 from __future__ import annotations
 

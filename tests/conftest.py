@@ -39,6 +39,11 @@ def pytest_configure(config):
         "markers", "profiling: calibration-store / cost-model / advisor "
         "feedback-loop tests (ISSUE 8; unmarked slow, so they run in "
         "tier-1)")
+    # ISSUE 31: every run ends with where its time went (the driver's
+    # log and a builder's own), unless the command line asks otherwise;
+    # ROADMAP D1 reads this table
+    if config.option.durations is None:
+        config.option.durations = 25
 
 
 @pytest.hookimpl(tryfirst=True, hookwrapper=True)

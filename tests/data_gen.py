@@ -27,6 +27,19 @@ class DataGen:
         self.nullable = nullable
         self.null_prob = null_prob if nullable else 0.0
 
+    def __repr__(self):
+        """What defines the generator, never an address: a test id made
+        of it is the same in every process and every run."""
+        parts = [self.data_type.simpleString]
+        if not self.nullable:
+            parts.append("not null")
+        # bounds or lengths, for the classes a file may hold twice
+        if hasattr(self, "lo"):
+            parts.append(f"{self.lo}..{self.hi}")
+        if hasattr(self, "min_len"):
+            parts.append(f"len {self.min_len}..{self.max_len}")
+        return f"{type(self).__name__}({', '.join(parts)})"
+
     def gen_value(self, rng: random.Random):
         raise NotImplementedError
 

@@ -176,28 +176,36 @@ def test_shipped_baseline_every_entry_justified():
 # determinism + the tier-1 repo gate
 # ---------------------------------------------------------------------------
 
-def test_json_determinism_over_repo():
+def _lint_repo():
+    return run_paths([os.path.join(REPO, "spark_rapids_tpu"),
+                      os.path.join(REPO, "tools")],
+                     REPO, rules=default_rules(include_docs=True))
+
+
+@pytest.fixture(scope="module")
+def repo_lint():
+    """THE whole-repo pass of this file (all rules incl. doc-drift) and
+    its wall: (findings, seconds).  A test that needs the repo's
+    findings takes them from here; only the determinism test runs a
+    second pass."""
+    t0 = time.monotonic()
+    findings = _lint_repo()
+    return findings, time.monotonic() - t0
+
+
+def test_json_determinism_over_repo(repo_lint):
     """Two runs over the repo produce byte-identical JSON findings."""
-    paths = [os.path.join(REPO, "spark_rapids_tpu"),
-             os.path.join(REPO, "tools")]
-    a = to_json(run_paths(paths, REPO,
-                          rules=default_rules(include_docs=False)))
-    b = to_json(run_paths(paths, REPO,
-                          rules=default_rules(include_docs=False)))
+    a = to_json(repo_lint[0])
+    b = to_json(_lint_repo())
     assert a == b
     json.loads(a)             # well-formed
 
 
-def test_repo_lint_gate():
+def test_repo_lint_gate(repo_lint):
     """The tier-1 gate: zero non-baselined findings over
     spark_rapids_tpu/ + tools/ (all rules incl. doc-drift), bounded
     runtime."""
-    t0 = time.monotonic()
-    findings = run_paths(
-        [os.path.join(REPO, "spark_rapids_tpu"),
-         os.path.join(REPO, "tools")],
-        REPO, rules=default_rules(include_docs=True))
-    elapsed = time.monotonic() - t0
+    findings, elapsed = repo_lint
     new, stale = Baseline.load(BASELINE).split(findings)
     assert new == [], "non-baselined findings:\n" + "\n".join(
         f.render() for f in new)
@@ -236,7 +244,10 @@ def _cli(args, cwd=REPO):
 
 
 def test_cli_clean_repo_exits_zero():
-    r = _cli(["--fail-on-new"])
+    """A real process over a clean tree exits 0 (scoped to tools/: the
+    whole repo's cleanliness is test_repo_lint_gate's, in this
+    process)."""
+    r = _cli(["--fail-on-new", os.path.join(REPO, "tools")])
     assert r.returncode == 0, r.stdout + r.stderr
 
 
@@ -262,12 +273,20 @@ def test_cli_new_finding_exits_one(tmp_path):
 # tracelint (ISSUE 11): fusibility manifest, SARIF, CLI satellites
 # ---------------------------------------------------------------------------
 
-def test_fusibility_manifest_covers_every_registered_exec():
-    """Every EXECS plan class has a classification; none is unknown."""
+@pytest.fixture(scope="module")
+def repo_manifest():
+    """THE fusibility manifest of this file; only the byte-identity
+    test builds a second one."""
     from spark_rapids_tpu.analysis.fusibility import build_manifest
+
+    return build_manifest(REPO)
+
+
+def test_fusibility_manifest_covers_every_registered_exec(repo_manifest):
+    """Every EXECS plan class has a classification; none is unknown."""
     from spark_rapids_tpu.overrides.overrides import EXECS
 
-    m = build_manifest(REPO)
+    m = repo_manifest
     ops = m["operators"]
     for cls in EXECS:
         assert cls.__name__ in ops, f"{cls.__name__} missing"
@@ -284,52 +303,53 @@ def test_fusibility_manifest_covers_every_registered_exec():
     assert "TpuStageExec" in m["execs"]
 
 
-def test_fusibility_manifest_byte_identical():
+def test_fusibility_manifest_byte_identical(repo_manifest):
     from spark_rapids_tpu.analysis.fusibility import (
         build_manifest,
         manifest_json,
     )
 
-    a = manifest_json(build_manifest(REPO))
+    a = manifest_json(repo_manifest)
     b = manifest_json(build_manifest(REPO))
     assert a == b
     json.loads(a)
 
 
-def test_fusibility_manifest_drift_gate():
+def test_fusibility_manifest_drift_gate(repo_manifest):
     """ISSUE 17: the committed tools/fusibility_manifest.json must stay
     byte-identical to a fresh regeneration — the whole-plan fusion pass
     derives its eligible set from it, so a stale manifest silently
     changes what fuses.  Regenerate with
     ``python tools/fusibility.py --out tools/fusibility_manifest.json``."""
-    from spark_rapids_tpu.analysis.fusibility import (
-        build_manifest,
-        manifest_json,
-    )
+    from spark_rapids_tpu.analysis.fusibility import manifest_json
 
     committed = os.path.join(REPO, "tools", "fusibility_manifest.json")
     with open(committed, "r", encoding="utf-8") as f:
         on_disk = f.read()
-    assert on_disk == manifest_json(build_manifest(REPO)), (
+    assert on_disk == manifest_json(repo_manifest), (
         "tools/fusibility_manifest.json is stale — regenerate with "
         "python tools/fusibility.py --out tools/fusibility_manifest.json")
 
 
-def test_fusibility_cli_check_flag(tmp_path):
-    """--check: exit 0 against the committed manifest, exit 1 on drift."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    tool = os.path.join(REPO, "tools", "fusibility.py")
-    r = subprocess.run([sys.executable, tool, "--check"], cwd=REPO,
-                       capture_output=True, text=True, env=env,
-                       timeout=120)
-    assert r.returncode == 0, r.stdout + r.stderr
+def test_fusibility_cli_check_flag(tmp_path, monkeypatch, capsys,
+                                   repo_manifest):
+    """--check: exit 0 against the committed manifest, exit 1 on drift
+    (the tool's ``main`` in this process, on the manifest the file
+    already built)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "_fusibility_cli", os.path.join(REPO, "tools", "fusibility.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    monkeypatch.setattr(tool, "build_manifest",
+                        lambda repo_root: repo_manifest)
+    assert tool.main(["--check"]) == 0, capsys.readouterr().err
     stale = tmp_path / "stale.json"
     stale.write_text("{}\n")
-    r = subprocess.run([sys.executable, tool, "--check", str(stale)],
-                       cwd=REPO, capture_output=True, text=True, env=env,
-                       timeout=120)
-    assert r.returncode == 1
-    assert "stale" in r.stderr
+    capsys.readouterr()
+    assert tool.main(["--check", str(stale)]) == 1
+    assert "stale" in capsys.readouterr().err
 
 
 def test_sarif_deterministic_and_well_formed(tmp_path):
@@ -704,3 +724,25 @@ def test_arm_conf_spec_bad_spec_mutates_nothing():
             ("TpuFilterExec", "oom")]
     finally:
         F.clear_faults()
+
+
+# ---------------------------------------------------------------------------
+# the suite's own ids (ISSUE 31)
+# ---------------------------------------------------------------------------
+
+def test_test_ids_are_stable_and_unique(request):
+    """No id of the session holds an object address (an id made of a
+    default ``repr`` differs between two runs, so a failed name cannot be
+    found again, and between two xdist workers, so ``-n`` cannot collect)
+    and no two tests share one."""
+    import re
+
+    ids = [item.nodeid for item in request.session.items]
+    addressed = [i for i in ids if re.search(r"0x[0-9a-fA-F]{6,}", i)]
+    assert addressed == [], (
+        "ids made of an object address (give the parameter a __repr__ "
+        f"or an ids=): {addressed[:10]}")
+    seen, twice = set(), set()
+    for i in ids:
+        (twice if i in seen else seen).add(i)
+    assert twice == set(), f"duplicate test ids: {sorted(twice)[:10]}"
