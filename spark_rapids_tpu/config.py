@@ -701,13 +701,15 @@ PARQUET_COMPRESSED_TRANSFER = conf(
 ).boolean_conf(True)
 
 SCAN_PREFETCH_DEPTH = conf("spark.rapids.tpu.scan.prefetch.depth").doc(
-    "Async H2D prefetch ring depth for the COALESCING/MULTITHREADED "
-    "readers: up to this many upcoming batches are decoded+uploaded on a "
-    "staging thread while the query computes on the current batch "
-    "(double-buffering at the default 2).  Overlap efficiency is "
-    "observable via `bytes_h2d_overlapped` / `prefetch_stall_ns` and the "
-    "`scan_prefetch` diagnostics event.  0 disables (strictly "
-    "sequential transfer-then-compute).").integer_conf(2)
+    "Depth of the queues between the scan's three staging threads (read, "
+    "to_columns, H2D): a file is read by units (runs of whole parquet row "
+    "groups; one unit a file for other formats), and up to this many units "
+    "wait between two stages, so a unit is read while its predecessors are "
+    "decoded, uploaded and computed on (double-buffering at the default "
+    "2).  Overlap efficiency is observable via `bytes_h2d_overlapped` / "
+    "`prefetch_stall_ns`, `scan_units` and the `scan_prefetch` diagnostics "
+    "event.  0 disables (read, decode and upload strictly one after the "
+    "other on the query's thread).").integer_conf(2)
 
 SCAN_HOT_CACHE = conf("spark.rapids.tpu.scan.hotTableCache.enabled").doc(
     "Device-resident hot-table cache: completed file scans register "

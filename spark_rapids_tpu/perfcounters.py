@@ -87,11 +87,16 @@ COUNTERS: Dict[str, int] = {
     "scan_transfer_ns": 0,        # wall inside scan H2D upload sites
     "pages_device_decompressed": 0,
     "chunk_decode_fallbacks": 0,  # compressed->decoded per-chunk falls
-    # H2D prefetch ring (io/scan.py): bytes whose transfer fully
-    # overlapped query compute, and wall the consumer stalled waiting on
-    # an in-flight prefetch
+    # the scan's stage threads (io/scan.py _prefetched): bytes of the
+    # batches found ready when the consumer asked, and wall the consumer
+    # stalled waiting for one
     "bytes_h2d_overlapped": 0,
     "prefetch_stall_ns": 0,
+    # units the scan's host reader handed on (a parquet file is read by
+    # runs of whole row groups, one unit a run; any other file is one
+    # unit), and files cut into more than one unit
+    "scan_units": 0,
+    "scan_files_streamed": 0,
     # device-resident hot-table cache (io/hot_cache.py)
     "hot_cache_hits": 0,
     "hot_cache_misses": 0,

@@ -563,20 +563,19 @@ def test_parquet_scan_reads_only_the_named_columns(tmp_path, monkeypatch):
     import pyarrow as pa
     import pyarrow.parquet as pq
 
-    from spark_rapids_tpu.io import scan as SCAN
-
     path = str(tmp_path / "sales.parquet")
     pq.write_table(pa.table({k: pa.array(v, pa.int64())
                              for k, v in _SALES.items()}), path)
     read_columns = []
-    orig = SCAN.read_parquet_file
+    orig = pq.ParquetFile.read_row_groups
 
-    def spy(p, columns, filters=None):
-        tbl = orig(p, columns, filters)
+    # the scan reads a file by runs of row groups (io/scan.py _open_units)
+    def spy(self, row_groups, columns=None, **kw):
+        tbl = orig(self, row_groups, columns=columns, **kw)
         read_columns.append(tbl.column_names)
         return tbl
 
-    monkeypatch.setattr(SCAN, "read_parquet_file", spy)
+    monkeypatch.setattr(pq.ParquetFile, "read_row_groups", spy)
     conf = {"spark.rapids.sql.format.parquet.deviceDecode.enabled": False}
 
     def q(s, cols):

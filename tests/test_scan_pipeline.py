@@ -354,13 +354,13 @@ def test_prefetch_overlap_beats_sequential(tmp_path):
 
     def run(depth):
         ex = _scan_exec([p], schema, depth)
-        real_upload = ex._upload
+        real_upload = ex._to_device
 
-        def slow_upload(tbl):
+        def slow_upload(cols):
             time.sleep(t_upload)
-            return real_upload(tbl)
+            return real_upload(cols)
 
-        ex._upload = slow_upload
+        ex._to_device = slow_upload
         rows = 0
         t0 = time.perf_counter()
         for batch in ex.execute_columnar():
@@ -404,6 +404,281 @@ def test_prefetch_emits_diagnostics_event(tmp_path):
     pf = [e for e in events if e["ev"] == "scan_prefetch"]
     assert pf, "no scan_prefetch event recorded"
     assert pf[0]["depth"] == 2 and pf[0]["batches"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 32: a parquet file streams by runs of row groups through three
+# stage threads (read | to_columns | h2d)
+# ---------------------------------------------------------------------------
+
+_Q6_SCHEMA = T.StructType([
+    T.StructField("id", T.LONG, True), T.StructField("price", T.LONG, True),
+    T.StructField("disc", T.LONG, True), T.StructField("qty", T.LONG, True),
+    T.StructField("ship", T.INT, True)])
+
+
+def _write_q6(tmp_path, name, n=2048, row_group=256, first_id=0):
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    rng = np.random.default_rng(32 + first_id)
+    tbl = pa.table({
+        "id": np.arange(first_id, first_id + n, dtype=np.int64),
+        "price": rng.integers(90_000, 10_500_000, n).astype(np.int64),
+        "disc": rng.integers(0, 11, n).astype(np.int64),
+        "qty": rng.integers(100, 5100, n).astype(np.int64),
+        "ship": rng.integers(8400, 9500, n).astype(np.int32)})
+    p = str(tmp_path / f"{name}.parquet")
+    pq.write_table(tbl, p, row_group_size=row_group)
+    return p
+
+
+def _q6_shaped(session, paths):
+    from spark_rapids_tpu.session import col, lit
+
+    return (session.read.parquet(*paths)
+            .filter((col("ship") >= lit(8766)) & (col("ship") < lit(9131))
+                    & (col("disc") >= lit(5)) & (col("disc") <= lit(7))
+                    & (col("qty") < lit(2400)))
+            .select((col("price") * col("disc")).alias("revenue"))
+            .agg(sum_("revenue", "revenue")).collect())
+
+
+def _unit_exec(paths, mode="COALESCING", depth=2, rows=512, **conf):
+    from spark_rapids_tpu.config import TpuConf
+    from spark_rapids_tpu.io.scan import TpuFileSourceScanExec
+    from spark_rapids_tpu.plan.nodes import FileSourceScan
+
+    return TpuFileSourceScanExec(
+        FileSourceScan("parquet", paths, _Q6_SCHEMA), TpuConf({
+            "spark.rapids.sql.format.parquet.reader.type": mode,
+            "spark.rapids.sql.reader.batchSizeRows": str(rows),
+            "spark.rapids.tpu.scan.prefetch.depth": str(depth), **conf}))
+
+
+@pytest.fixture
+def counted_reads(monkeypatch):
+    """Every ``ParquetFile.read_row_groups`` call as its list of row
+    groups; ``calls.hook`` runs first in each (a sleep, a fault)."""
+    import pyarrow.parquet as pq
+
+    real = pq.ParquetFile.read_row_groups
+
+    class Calls(list):
+        hook = staticmethod(lambda groups: None)
+
+    calls = Calls()
+
+    def read_row_groups(self, row_groups, *a, **kw):
+        calls.append(list(row_groups))
+        calls.hook(list(row_groups))
+        return real(self, row_groups, *a, **kw)
+
+    monkeypatch.setattr(pq.ParquetFile, "read_row_groups", read_row_groups)
+    return calls
+
+
+def _scan_threads():
+    import threading
+
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith("srt-scan")]
+
+
+@pytest.mark.parametrize("mode,files", [
+    ("COALESCING", 1), ("PERFILE", 1), ("MULTITHREADED", 2)])
+def test_a_file_streams_by_runs_of_row_groups(tmp_path, mode, files,
+                                              counted_reads):
+    paths = [_write_q6(tmp_path, f"rg{k}", first_id=2048 * k)
+             for k in range(files)]
+    ex = _unit_exec(paths, mode)
+    snap = PC.snapshot()
+    batches = list(ex.execute_columnar())
+    d = PC.since(snap)
+    # 8 row groups of 256 rows under 512 rows a unit: 4 units a file of
+    # two row groups each, handed on in file order
+    assert [b.num_rows for b in batches] == [512] * (4 * files)
+    ids = np.concatenate([np.asarray(b.columns[0].data)[:512]
+                          for b in batches])
+    assert ids.tolist() == list(range(2048 * files))
+    assert sorted(counted_reads) == sorted(
+        [[0, 1], [2, 3], [4, 5], [6, 7]] * files)
+    assert d["scan_units"] == 4 * files
+    assert d["scan_files_streamed"] == files
+    assert f"units={4 * files}" in ex.describe()
+    assert _scan_threads() == []
+    # the Q6-shaped answer over the units is the one-batch answer and
+    # the CPU oracle's
+    conf = {"spark.rapids.sql.enabled": True,
+            "spark.rapids.sql.format.parquet.reader.type": mode}
+    units = _q6_shaped(TpuSession({
+        **conf, "spark.rapids.sql.reader.batchSizeRows": "512"}), paths)
+    snap = PC.snapshot()
+    whole = _q6_shaped(TpuSession(conf), paths)
+    assert PC.since(snap)["scan_units"] == files
+    oracle = _q6_shaped(
+        TpuSession({"spark.rapids.sql.enabled": False}), paths)
+    assert units == whole == oracle and units[0][0] > 0
+
+
+def test_a_file_of_one_row_group_reads_once(tmp_path, counted_reads):
+    p = _write_q6(tmp_path, "one", n=600, row_group=600)
+    ex = _unit_exec([p], rows=2147483647)
+    snap = PC.snapshot()
+    batches = list(ex.execute_columnar())
+    d = PC.since(snap)
+    assert [b.num_rows for b in batches] == [600]
+    assert counted_reads == [[0]]
+    assert d["scan_units"] == 1 and d["scan_files_streamed"] == 0
+    assert "units=1" in ex.describe()
+
+
+def test_the_three_stages_overlap(tmp_path, counted_reads):
+    """Read, to_columns and upload each slowed (the idiom of
+    test_prefetch_overlap_beats_sequential): N units take the slowest
+    stage N times plus the other two once, not the sum N times; depth 0
+    takes the sum."""
+    p = _write_q6(tmp_path, "ov", n=6 * 256, row_group=256)
+    t_read, t_cols, t_h2d, n = 0.06, 0.12, 0.08, 6
+    counted_reads.hook = lambda groups: time.sleep(t_read)
+
+    def run(depth):
+        ex = _unit_exec([p], depth=depth, rows=256)
+        to_cols, to_dev = ex._table_to_host_cols, ex._to_device
+
+        def slow_cols(tbl):
+            time.sleep(t_cols)
+            return to_cols(tbl)
+
+        def slow_dev(cols):
+            time.sleep(t_h2d)
+            return to_dev(cols)
+
+        ex._table_to_host_cols, ex._to_device = slow_cols, slow_dev
+        t0 = time.perf_counter()
+        rows = sum(b.num_rows for b in ex.execute_columnar())
+        return time.perf_counter() - t0, rows
+
+    run(2)                                  # imports, the first upload
+    seq_wall, seq_rows = run(0)
+    snap = PC.snapshot()
+    ov_wall, ov_rows = run(2)
+    d = PC.since(snap)
+    assert seq_rows == ov_rows == n * 256 and d["scan_units"] == n
+    total = t_read + t_cols + t_h2d
+    assert seq_wall >= n * total                        # 1.56 s
+    # 6 x 0.12 + 0.06 + 0.08 = 0.86 s against 1.56: the margin is
+    # decisive, not a scheduler tick
+    assert ov_wall < n * t_cols + t_read + t_h2d + 0.3, (ov_wall, seq_wall)
+    assert ov_wall < seq_wall - 0.4, (ov_wall, seq_wall)
+    # the stages' spans are roots on their own threads
+    for name in ("srt.scan.read", "srt.scan.to_columns", "srt.scan.h2d"):
+        assert d[f"span_n|{name}"] >= n, name
+
+
+def test_a_closed_scan_reads_no_further_unit_and_leaves_no_thread(
+        tmp_path, counted_reads):
+    p = _write_q6(tmp_path, "lim", n=32 * 64, row_group=64)
+    ex = _unit_exec([p], depth=1, rows=64)       # 32 units
+    it = ex.execute_columnar()
+    assert next(it).num_rows == 64
+    it.close()
+    read = len(counted_reads)
+    # what the three queues of depth 1 and the stages hold, not the file
+    assert 1 <= read <= 8
+    assert _scan_threads() == []
+    time.sleep(0.3)
+    assert len(counted_reads) == read
+    # the same through a query: a limit stops pulling after one batch
+    s = TpuSession({"spark.rapids.sql.enabled": True,
+                    "spark.rapids.sql.reader.batchSizeRows": "64"})
+    assert len(s.read.parquet(p).limit(3).collect()) == 3
+    assert _scan_threads() == []
+
+
+@pytest.mark.parametrize("mode", ["COALESCING", "MULTITHREADED"])
+def test_a_unit_that_fails_mid_file(tmp_path, counted_reads, mode):
+    good = _write_q6(tmp_path, "good", n=1024)
+    bad = _write_q6(tmp_path, "bad", n=2048, first_id=5000)
+
+    def third_unit_of_bad_is_corrupt(groups):
+        if groups == [4, 5]:        # good has four row groups, bad eight
+            raise OSError("Corrupt snappy compressed data.")
+
+    counted_reads.hook = third_unit_of_bad_is_corrupt
+    from spark_rapids_tpu.io import faults as IOF
+
+    conf = {"spark.rapids.sql.enabled": True,
+            "spark.rapids.sql.format.parquet.reader.type": mode,
+            "spark.rapids.sql.reader.batchSizeRows": "512",
+            "spark.rapids.tpu.scan.hotTableCache.enabled": True}
+    # default conf: the annotated fault names the file
+    with pytest.raises(IOF.CorruptFile) as ei:
+        TpuSession({**conf, "spark.rapids.tpu.resilience.enabled": "false"}
+                   ).read.parquet(good, bad).collect()
+    assert ei.value.path == bad
+    assert _scan_threads() == []
+    # tolerated: the file is absent as a whole, not cut at the fault
+    snap = PC.snapshot()
+    s = TpuSession({**conf, "spark.sql.files.ignoreCorruptFiles": "true"})
+    df = s.read.parquet(good, bad)
+    rows = df.collect()
+    d = PC.since(snap)
+    assert sorted(r[0] for r in rows) == list(range(1024))
+    assert d["files_skipped_corrupt"] == 1
+    assert d["scan_units"] == 2             # the good file's, no more
+    from spark_rapids_tpu.io.hot_cache import peek_hot_cache
+
+    cache = peek_hot_cache()
+    assert cache is None or cache.stats()["entries"] == 0
+    s.close()
+
+
+def test_a_missing_column_is_still_a_schema_mismatch(tmp_path):
+    from spark_rapids_tpu.io import faults as IOF
+
+    p = _write_q6(tmp_path, "drift")
+    schema = T.StructType(list(_Q6_SCHEMA.fields)
+                          + [T.StructField("absent", T.LONG, True)])
+    from spark_rapids_tpu.config import TpuConf
+    from spark_rapids_tpu.io.scan import TpuFileSourceScanExec
+    from spark_rapids_tpu.plan.nodes import FileSourceScan
+
+    ex = TpuFileSourceScanExec(
+        FileSourceScan("parquet", [p], schema),
+        TpuConf({"spark.rapids.sql.reader.batchSizeRows": "512"}))
+    with pytest.raises(IOF.SchemaMismatch) as ei:
+        list(ex.execute_columnar())
+    assert "absent" in str(ei.value) and ei.value.path == p
+    assert _scan_threads() == []
+
+
+def test_a_paused_governor_lets_no_upload_run_ahead(tmp_path):
+    """Under YELLOW/RED a unit crosses the link when the client waits
+    for it, never before: no batch is found ready."""
+    from spark_rapids_tpu.governor import context as GOV
+
+    class Paused:
+        def pause_background(self):
+            return True
+
+        def batch_pull_checkpoint(self):    # the runtime's per-pull hook
+            pass
+
+    p = _write_q6(tmp_path, "gov", n=1024)
+    ex = _unit_exec([p])
+    prev, GOV.GOVERNOR = GOV.GOVERNOR, Paused()
+    try:
+        snap = PC.snapshot()
+        n = 0
+        for b in ex.execute_columnar():
+            time.sleep(0.1)         # the stages could run ahead here
+            n += b.num_rows
+        d = PC.since(snap)
+    finally:
+        GOV.GOVERNOR = prev
+    assert n == 1024 and d["scan_units"] == 2
+    assert d["bytes_h2d_overlapped"] == 0
 
 
 # ---------------------------------------------------------------------------
