@@ -57,11 +57,14 @@ from spark_rapids_tpu.columnar.column import (
 from spark_rapids_tpu.exec.base import TpuExec
 from spark_rapids_tpu.exec.join import (
     _BaseTpuJoinExec,
+    _has_dup_key,
     _key_words_of,
-    _merge_lookup,
+    _lookup,
+    _mask_col,
     _multiword_searchsorted,
     _SortedBuildSide,
     _takes_merge,
+    _use_mxu,
     arranged,
 )
 from spark_rapids_tpu.expr.base import (
@@ -69,87 +72,9 @@ from spark_rapids_tpu.expr.base import (
     EvalContext,
     Expression,
 )
-from spark_rapids_tpu.ops import mxugather as MG
 from spark_rapids_tpu.ops.sortkeys import _column_key_words
 from spark_rapids_tpu.perfcounters import bump, span, sync_get, tpu_jit
 from spark_rapids_tpu.plan.nodes import AggregateMode, JoinType
-
-
-def _mask_col(c: DeviceColumn, keep) -> DeviceColumn:
-    """AND a row mask into a column's validity (recursing into structs)."""
-    if c.is_struct:
-        return DeviceColumn(c.dtype, c.validity & keep,
-                            children=tuple(_mask_col(k, keep)
-                                           for k in c.children))
-    return DeviceColumn(c.dtype, c.validity & keep, data=c.data,
-                        chars=c.chars, lengths=c.lengths,
-                        elem_valid=c.elem_valid)
-
-
-def _has_dup_key(bwords, n_valid):
-    """Traced: does any adjacent pair among the first ``n_valid`` sorted
-    build keys compare equal (the build side's keys are not unique)?"""
-    cap_b = bwords[0].shape[0]
-    adj_eq = jnp.ones(cap_b - 1, jnp.bool_)
-    for w in bwords:
-        adj_eq = adj_eq & (w[:-1] == w[1:])
-    in_valid = (jnp.arange(cap_b - 1) + 1) < n_valid
-    return jnp.any(adj_eq & in_valid)
-
-
-def _use_mxu(cap_b: int) -> bool:
-    """The unique-build path's dimension lookup, chosen by the build
-    side's CAPACITY: small tables ride the MXU one-hot contraction
-    (ops/mxugather.py), larger ones the VPU gathers."""
-    return cap_b <= MG.MAX_TABLE_ROWS
-
-
-def _lookup(bwords, row_index, n_valid, b_cols, qwords, valid):
-    """Traced, one dimension of the unique-build path: (found, the
-    payload ``b_cols`` at every probe row, null where nothing matched).
-
-    Past the binary search's sizes (``_takes_merge``) the probe's one
-    merge sort says whether a probe row matched and at which SORTED
-    build position (``_merge_lookup``): no key word is gathered to
-    compare it.  The payload is then fetched by that position from build
-    columns permuted into key order, where the permute is the smaller
-    gather (build capacity <= probe capacity); else through
-    ``row_index[loc]`` from the columns as they are."""
-    cap_b, cap_p = bwords[0].shape[0], qwords[0].shape[0]
-    # small build tables ride the MXU one-hot gather: a VPU random
-    # gather costs ~300ms per column at 20M probe rows while the fused
-    # one_hot@table contraction is ~5ms (ops/mxugather.py)
-    use_mxu = _use_mxu(cap_b)
-
-    def at(table, idx):
-        return MG.mxu_gather(table, idx) if use_mxu else table[idx]
-
-    merge = _takes_merge(cap_b, cap_p)
-    if merge:
-        loc, matched = _merge_lookup(list(bwords), n_valid, qwords)
-        found = valid & matched
-    else:
-        lo = _multiword_searchsorted(list(bwords), n_valid, qwords, "left")
-        loc = jnp.clip(lo, 0, cap_b - 1)
-        eq = jnp.ones(lo.shape, jnp.bool_)
-        for w, q in zip(bwords, qwords):
-            eq = eq & (at(w, loc) == q)
-        found = valid & (lo < n_valid) & eq
-    if merge and cap_b <= cap_p:
-        # payload in key order: one build-sized gather a column, then
-        # ``loc`` indexes it directly
-        brow = jnp.where(found, loc, 0)
-        src = [c.gather(row_index) for c in b_cols]
-    else:
-        brow = jnp.where(found, at(row_index, loc), 0)
-        src = b_cols
-    bcols = []
-    for c in src:
-        g = MG.mxu_gather_col(c, brow) if use_mxu else None
-        if g is None:
-            g = c.gather(brow)
-        bcols.append(_mask_col(g, found))
-    return found, bcols
 
 
 def _group_code(key_cols) -> DeviceColumn:

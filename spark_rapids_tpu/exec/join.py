@@ -13,6 +13,12 @@ by offset within the match run).  Everything is jitted; only the total pair
 count syncs to host (to pick the output capacity bucket) — the exact analog
 of the reference's JoinGatherer.getTotalRows sizing step.
 
+A LEFT OUTER join whose valid build keys are unique (asked of each sorted
+build side by a sort-free program) skips the expansion: its output is the
+probe batch, columns passed through, beside the build columns looked up
+at every probe row from one merge sort (``_lookup``, shared with the
+fused star join).
+
 Sort-merge join at the plan level is converted to this shuffled-sort join —
 mirroring GpuSortMergeJoinMeta, which converts SMJ to shuffled-hash on GPU.
 """
@@ -21,7 +27,7 @@ from __future__ import annotations
 from typing import Iterator, List, Optional, Tuple
 
 import jax
-from spark_rapids_tpu.perfcounters import bump, span, tpu_jit
+from spark_rapids_tpu.perfcounters import bump, span, sync_get, tpu_jit
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
@@ -37,6 +43,7 @@ from spark_rapids_tpu.expr.base import (
     EvalContext,
     Expression,
 )
+from spark_rapids_tpu.ops import mxugather as MG
 from spark_rapids_tpu.ops.filterops import compact_columns, gather_columns
 from spark_rapids_tpu.ops.sortkeys import _column_key_words
 from spark_rapids_tpu.plan.nodes import (
@@ -192,6 +199,83 @@ def _merge_lookup(sorted_words: List[jax.Array], n_valid,
     return packed >> 1, (packed & 1) == 1
 
 
+def _mask_col(c: DeviceColumn, keep) -> DeviceColumn:
+    """AND a row mask into a column's validity (recursing into structs)."""
+    if c.is_struct:
+        return DeviceColumn(c.dtype, c.validity & keep,
+                            children=tuple(_mask_col(k, keep)
+                                           for k in c.children))
+    return DeviceColumn(c.dtype, c.validity & keep, data=c.data,
+                        chars=c.chars, lengths=c.lengths,
+                        elem_valid=c.elem_valid)
+
+
+def _has_dup_key(bwords, n_valid):
+    """Traced: does any adjacent pair among the first ``n_valid`` sorted
+    build keys compare equal (the build side's keys are not unique)?"""
+    cap_b = bwords[0].shape[0]
+    adj_eq = jnp.ones(cap_b - 1, jnp.bool_)
+    for w in bwords:
+        adj_eq = adj_eq & (w[:-1] == w[1:])
+    in_valid = (jnp.arange(cap_b - 1) + 1) < n_valid
+    return jnp.any(adj_eq & in_valid)
+
+
+def _use_mxu(cap_b: int) -> bool:
+    """The unique-build lookup, chosen by the build side's CAPACITY:
+    small tables ride the MXU one-hot contraction (ops/mxugather.py),
+    larger ones the VPU gathers."""
+    return cap_b <= MG.MAX_TABLE_ROWS
+
+
+def _lookup(bwords, row_index, n_valid, b_cols, qwords, valid):
+    """Traced, one build side of unique keys: (found, the payload
+    ``b_cols`` at every probe row, null where nothing matched).
+
+    Past the binary search's sizes (``_takes_merge``) the probe's one
+    merge sort says whether a probe row matched and at which SORTED
+    build position (``_merge_lookup``): no key word is gathered to
+    compare it.  The payload is then fetched by that position from build
+    columns permuted into key order, where the permute is the smaller
+    gather (build capacity <= probe capacity); else through
+    ``row_index[loc]`` from the columns as they are."""
+    cap_b, cap_p = bwords[0].shape[0], qwords[0].shape[0]
+    # small build tables ride the MXU one-hot gather: a VPU random
+    # gather costs ~300ms per column at 20M probe rows while the fused
+    # one_hot@table contraction is ~5ms (ops/mxugather.py)
+    use_mxu = _use_mxu(cap_b)
+
+    def at(table, idx):
+        return MG.mxu_gather(table, idx) if use_mxu else table[idx]
+
+    merge = _takes_merge(cap_b, cap_p)
+    if merge:
+        loc, matched = _merge_lookup(list(bwords), n_valid, qwords)
+        found = valid & matched
+    else:
+        lo = _multiword_searchsorted(list(bwords), n_valid, qwords, "left")
+        loc = jnp.clip(lo, 0, cap_b - 1)
+        eq = jnp.ones(lo.shape, jnp.bool_)
+        for w, q in zip(bwords, qwords):
+            eq = eq & (at(w, loc) == q)
+        found = valid & (lo < n_valid) & eq
+    if merge and cap_b <= cap_p:
+        # payload in key order: one build-sized gather a column, then
+        # ``loc`` indexes it directly
+        brow = jnp.where(found, loc, 0)
+        src = [c.gather(row_index) for c in b_cols]
+    else:
+        brow = jnp.where(found, at(row_index, loc), 0)
+        src = b_cols
+    bcols = []
+    for c in src:
+        g = MG.mxu_gather_col(c, brow) if use_mxu else None
+        if g is None:
+            g = c.gather(brow)
+        bcols.append(_mask_col(g, found))
+    return found, bcols
+
+
 def _slots_to_probe_rows(excl, counts, out_cap: int) -> jax.Array:
     """probe_row[j] for every output pair slot j: scatter each matched
     probe row's index at its first slot, then a running-max scan.
@@ -321,6 +405,9 @@ class _BaseTpuJoinExec(_EmitLayout, TpuExec):
         self.ansi = ansi
         self.sub_partition_bytes = sub_partition_bytes
         self._jit_cache = {}
+        # what the last probe batch took ("lookup" or "pairs"), for
+        # describe()
+        self._path: Optional[str] = None
         self._set_emit(left, right, join_type, condition, emit,
                        output_schema)
 
@@ -385,8 +472,9 @@ class _BaseTpuJoinExec(_EmitLayout, TpuExec):
     def describe(self):
         keys = ", ".join(f"{l.sql_string()}={r.sql_string()}"
                          for l, r in zip(self.left_keys, self.right_keys))
+        took = "" if self._path is None else f" path={self._path}"
         return (f"{self.node_name} {self.join_type.value} [{keys}]"
-                + describe_emit(self.emit, self._full_output))
+                + describe_emit(self.emit, self._full_output) + took)
 
     # -- build side -----------------------------------------------------
     def _prepare_build(self, batch: ColumnarBatch, keys: List[Expression],
@@ -495,6 +583,62 @@ class _BaseTpuJoinExec(_EmitLayout, TpuExec):
                                       self._probe_fn(batch.schema))
             return jitted(tuple(build.words), build.n_valid,
                           tuple(batch.columns), jnp.int32(batch.num_rows))
+
+    # -- unique build side: lookup instead of pairs ----------------------
+    def _unique_build(self, build: _SortedBuildSide) -> bool:
+        """Are the valid build keys unique?  Asked of every build side
+        afresh (this node rebuilds each collect) by a sort-free program
+        and ONE sync; null and filtered rows sort past ``n_valid`` and
+        never count."""
+        def has_dup(bwords, n_valid):
+            # a function of this program's own: tpu_jit names it in
+            # place, and the fused join's program is ``_has_dup_key``
+            return _has_dup_key(bwords, n_valid)
+
+        with span("srt.join.unique"):
+            dup = self._cached_jit("has_dup", has_dup)(
+                tuple(build.words), build.n_valid)
+            return not bool(sync_get(dup))
+
+    def _lookup_fn(self, schema):
+        """The lookup program body: the build columns of ``_b_sel`` at
+        every probe row, null where its key matched nothing.  Locals
+        only; no ``self`` capture (see _build_fn)."""
+        left_keys = self.left_keys
+        ansi = self.ansi
+
+        def fn(bwords, row_index, n_valid, b_cols, cols, num_rows):
+            b = ColumnarBatch(list(cols), num_rows, schema)
+            ctx = EvalContext(b, ansi=ansi)
+            key_cols = [k.eval_tpu(ctx) for k in left_keys]
+            valid = b.row_mask
+            for kc in key_cols:
+                valid = valid & kc.validity
+            _, bcols = _lookup(bwords, row_index, n_valid, b_cols,
+                               _key_words_of(key_cols), valid)
+            return tuple(bcols)
+
+        return fn
+
+    def _lookup_unique(self, build: _SortedBuildSide,
+                       probe: ColumnarBatch) -> ColumnarBatch:
+        """LEFT OUTER against unique build keys: the output is the probe
+        batch itself, its columns passed through ungathered, beside the
+        build's columns looked up at every probe row.  One program, and
+        no size sync: the row count is the probe's."""
+        from spark_rapids_tpu.compilecache.keys import schema_fp
+
+        with span("srt.join.lookup"):
+            bump("join_lookups_unique")
+            jitted = self._cached_jit(("lookup", schema_fp(probe.schema)),
+                                      self._lookup_fn(probe.schema))
+            bcols = jitted(tuple(build.words), build.row_index,
+                           build.n_valid,
+                           tuple(build.batch.columns[i] for i in self._b_sel),
+                           tuple(probe.columns), jnp.int32(probe.num_rows))
+        lcols = [probe.columns[i] for i in self._p_sel]
+        return ColumnarBatch(arranged(self._mat_slots, lcols, bcols),
+                             probe.num_rows, self._mat_schema)
 
     # -- materialization (gather maps -> output batch) -------------------
     @staticmethod
@@ -752,6 +896,7 @@ class _BaseTpuJoinExec(_EmitLayout, TpuExec):
                     sub_partition_bytes=1 << 62,  # buckets never re-partition
                     emit=self.emit)
                 for out in sub.execute_columnar():
+                    self._path = sub._path
                     yield self._count_output(out)
                 for s in build_buckets[pid] + probe_buckets[pid]:
                     s.close()
@@ -804,6 +949,10 @@ class _BaseTpuJoinExec(_EmitLayout, TpuExec):
         del build_spill
         with self.metric("buildTime").timed():
             build = self._prepare_build(build_batch, self.right_keys)
+        # LEFT OUTER (RIGHT OUTER arrives swapped) against unique build
+        # keys outputs exactly the probe rows: a lookup, no pairs
+        lookup = (jt == JoinType.LEFT_OUTER and self.condition is None
+                  and self._unique_build(build))
         matched_build_any = None
         if jt == JoinType.FULL_OUTER:
             matched_build_any = jnp.zeros(build_batch.capacity, jnp.bool_)
@@ -814,6 +963,10 @@ class _BaseTpuJoinExec(_EmitLayout, TpuExec):
             reference splits the stream side on SplitAndRetryOOM; FULL
             OUTER's coverage update is an idempotent OR)."""
             nonlocal matched_build_any
+            self._path = "lookup" if lookup else "pairs"
+            if lookup:
+                return (self._lookup_unique(build, probe)
+                        if probe.num_rows else None)
             lo, counts, total, unmatched, n_um = self._probe_counts(
                 build, probe)
             if jt == JoinType.LEFT_SEMI:
@@ -921,6 +1074,7 @@ class _BaseTpuJoinExec(_EmitLayout, TpuExec):
             sub_partition_bytes=self.sub_partition_bytes,
             emit=[nr + o if o < nl else o - nl for o in out])
         for b in swapped.execute_columnar():
+            self._path = swapped._path
             yield self._count_output(b)
 
     def _apply_condition(self, batch: ColumnarBatch) -> ColumnarBatch:

@@ -238,7 +238,9 @@ COUNTERS: Dict[str, int] = {
     # groups-cap ladder (fused.py, aggregate.py); the dimension lookups
     # done inside a fused program (one a join of the star it fused, each
     # call), and the rows a join wrote out as a joined batch outside one
-    # (exec/join.py _materialize)
+    # (exec/join.py _materialize); and the probe batches an unfused LEFT
+    # OUTER join looked up in a build side of unique keys instead of
+    # expanding pairs (exec/join.py _lookup_unique)
     "joinagg_unique_probes": 0,
     "joinagg_general_probes": 0,
     "join_lookups_mxu": 0,
@@ -248,6 +250,7 @@ COUNTERS: Dict[str, int] = {
     "agg_groups_cap_regrows": 0,
     "joinagg_fused_lookups": 0,
     "join_rows_materialized": 0,
+    "join_lookups_unique": 0,
 }
 
 

@@ -447,7 +447,9 @@ def _gather_words(jaxpr) -> int:
 def test_materialize_pairs_gathers_only_the_emitted_columns(monkeypatch):
     """qb reads store_sk, ext_sales and return_amt of the join's ten
     columns: 5 words + 3 validity = 8 column gathers beside the 4 index
-    gathers of the pair expansion, not 26 + 4."""
+    gathers of the pair expansion, not 26 + 4.  (qb's build keys are
+    unique, so the join looks them up instead; the pair path is forced
+    here.)"""
     import jax
 
     from spark_rapids_tpu.exec.join import _BaseTpuJoinExec
@@ -463,6 +465,8 @@ def test_materialize_pairs_gathers_only_the_emitted_columns(monkeypatch):
 
     monkeypatch.setattr(_BaseTpuJoinExec, "materialize_pairs",
                         staticmethod(spy))
+    monkeypatch.setattr(_BaseTpuJoinExec, "_unique_build",
+                        lambda self, build: False)
     s = TpuSession({**SHUFFLED,
                     "spark.rapids.tpu.scan.cacheDeviceBatches": True})
     df, _, (ss, sr) = _qb(s)

@@ -302,8 +302,9 @@ SPAN_TABLE = {
     "srt.execute", "srt.launch", "srt.sync", "srt.rows", "srt.scan.read", "srt.scan.to_columns", "srt.scan.h2d",
     "srt.scan.device_decode", "srt.scan.prefetch_wait",
     "srt.exchange.partition", "srt.exchange.queue", "srt.join.build",
-    "srt.join.probe", "srt.join.materialize", "srt.joinagg.unique",
-    "srt.joinagg.probe_sizes", "srt.joinagg.mat_agg"}
+    "srt.join.probe", "srt.join.materialize", "srt.join.unique",
+    "srt.join.lookup", "srt.joinagg.unique", "srt.joinagg.probe_sizes",
+    "srt.joinagg.mat_agg"}
 
 
 def test_span_names_are_the_documented_table_and_none_is_collect():
@@ -459,7 +460,7 @@ REACHED = {
         "srt.op.TpuHashAggregateExec", "srt.op.TpuAdaptiveJoinExec",
         "srt.op.TpuShuffledSymmetricHashJoinExec",
         "srt.op.TpuShuffleExchangeExec", "srt.op.TpuLocalTableScanExec",
-        "srt.join.build", "srt.join.probe", "srt.join.materialize"},
+        "srt.join.build", "srt.join.unique", "srt.join.lookup"},
 }
 
 
@@ -589,13 +590,15 @@ def test_the_join_cells_programs_have_names_of_their_own(tmp_path):
     join = _find_exec(df._planned()[0], "TpuAdaptiveJoinExec").shuffled
     names = sorted(j.__wrapped__.__name__
                    for j in (c._jitted for c in join._jit_cache.values()))
-    assert names == ["join_build", "join_materialize", "join_probe"]
+    # the store_returns keys are unique: a lookup, no pairs
+    assert names == ["join_build", "join_has_dup", "join_lookup"]
 
 
 @pytest.mark.parametrize("key,name", [
     ("covered", "join_covered"), (("mat", 8, True), "join_materialize"),
     (("build", "fp"), "join_build"), (("probe", "fp"), "join_probe"),
-    (("semi", True, "fp"), "join_semi")])
+    (("semi", True, "fp"), "join_semi"), ("has_dup", "join_has_dup"),
+    (("lookup", "fp"), "join_lookup")])
 def test_join_program_names(key, name):
     from spark_rapids_tpu.exec.join import _program_name
 

@@ -11,8 +11,8 @@ the merge sort past it); a null key matches nothing in either dimension;
 the store's two history rows of one (name, id) are ONE group, whose
 strings come back from a code carried through the lookup; a dimension
 whose keys repeat runs the joins unfused, with the same answer; and the
-one-dimension program of ``ds_broadcast_join_agg`` keeps the registry key
-it had."""
+programs of ``ds_broadcast_join_agg`` and of this star keep the registry
+keys and the code they had."""
 import numpy as np
 import pytest
 
@@ -250,33 +250,102 @@ def test_a_third_dimension_joins_the_same_program(session):
     assert len(fused.joins) == 3 and len(fused.children) == 4
 
 
-def test_the_one_dimension_program_keeps_its_registry_key(session):
-    """``ds_broadcast_join_agg``'s program: scope and key as the parent
-    commit built them, nothing computed per build row."""
+# sha256 of each program's StableHLO (the IR before XLA optimizes it;
+# values numbered by position, no source locations) at the tiny sizes of
+# test_the_fused_programs_keep_their_keys_and_their_code, by program kind
+# and, for a join's build sort, the join's place in the star
+FUSED_PROGRAMS = {
+    "one_dim": {
+        "build_has_dup":
+            "8dffdfb03ed231a3a31dfa9cae4bde75c3eea7bca4b41c10c52b54f91cd5a117",
+        "uniq_agg":
+            "88ccd6df6373c73939d1694dcb60b43b92d4fb3edf953a370258cd2c12ca35cf",
+        "join0:build_preops":
+            "b2603c0d3fa06c4d39fe17e2e4c69804b33b89883db7d0fd9c2741bb1aa54b6f",
+    },
+    "two_dim": {
+        "build_has_dup":
+            "9e4fdd914f4274ac8fa840f54ad03b96bdbc6101158da403900cb10f607b3110",
+        "uniq_agg":
+            "cb7723736fb50fc48da04863415ea0b7efde33665538b642ab1e5f40f80f8c19",
+        "join0:build_preops":
+            "177d0bbfdc09097dc862839fa71b5faf4c14fca517a4e4a88c23225d1c389dab",
+        "join1:build_preops":
+            "9f9366c7f928e26a1fb29954d5b71b6260ec6570eb627134a7d660936f6385fe",
+    },
+}
+
+
+def _program_digests(programs, calls):
+    """{kind: sha256 of the StableHLO} of the jitted ``programs`` ({registry
+    key: program}) as they were last called (``calls``)."""
+    import hashlib
+
+    out = {}
+    for key, jitted in programs.items():
+        args, kwargs = calls[id(jitted)]
+        text = jitted.lower(*args, **kwargs).as_text()
+        kind = key if isinstance(key, str) else key[0]
+        out[kind] = hashlib.sha256(text.encode()).hexdigest()
+    return out
+
+
+@pytest.mark.parametrize("star", ["one_dim", "two_dim"])
+def test_the_fused_programs_keep_their_keys_and_their_code(
+        session, monkeypatch, star):
+    """``ds_broadcast_join_agg``'s and ``ds_star_join_2dim``'s programs:
+    scope, keys and code (each build sort, the has-dup program, the one
+    program) as pinned, however the unfused join beside them changes."""
     from benchmark.datagen import date_dim_spec
     from benchmark.queries import qa_broadcast_join_agg as QA
+    from spark_rapids_tpu import perfcounters as PC
     from spark_rapids_tpu.exec.fused import TpuJoinAggFusedExec
 
-    rng = np.random.default_rng(1)
-    tables = {"store_sales": store_sales_star.make(3000, rng),
-              "date_dim": date_dim_spec.make(date_dim_spec.N_DATES, rng)}
-    frames = {"store_sales": C._resident_frame(
-        session, tables["store_sales"], store_sales_star.TYPES),
-        "date_dim": C._resident_frame(session, tables["date_dim"],
-                                      date_dim_spec.TYPES)}
-    df = QA.build(frames)
-    assert QA.answer(df.collect()) == QA.reference(tables)
+    calls = {}
+    counted = PC._CountingJit.__call__
+
+    def spy(self, *args, **kwargs):
+        calls[id(self)] = (args, kwargs)
+        return counted(self, *args, **kwargs)
+
+    monkeypatch.setattr(PC._CountingJit, "__call__", spy)
+    if star == "one_dim":
+        rng = np.random.default_rng(1)
+        tables = {"store_sales": store_sales_star.make(3000, rng),
+                  "date_dim": date_dim_spec.make(date_dim_spec.N_DATES,
+                                                 rng)}
+        frames = {"store_sales": C._resident_frame(
+            session, tables["store_sales"], store_sales_star.TYPES),
+            "date_dim": C._resident_frame(session, tables["date_dim"],
+                                          date_dim_spec.TYPES)}
+        df = QA.build(frames)
+        assert QA.answer(df.collect()) == QA.reference(tables)
+    else:
+        tables = _tables(3000)
+        df = Q.build(_frames(session, tables))
+        assert Q.answer(df.collect()) == Q.reference(tables)
     fused = _find_exec(df._planned()[0], TpuJoinAggFusedExec)
-    join, agg = fused.join, fused.agg
-    assert fused.joins == [join] and fused._hoist(agg) is None
+    agg = fused.agg
     assert fused._registry_scope() == (
-        ("joinagg",) + join._registry_scope() + (agg._program_fp(),))
-    B = agg._bounded_groups_cap(4096)
-    assert set(fused._jit_cache) == {
-        "build_has_dup", ("uniq_agg", agg._program_fp(), B)}
-    assert fused.describe() == (
-        f"TpuJoinAggFused[{agg.describe()} <- {join.describe()}] "
-        "path=unique lookup=vpu match=merge build_cap=262144")
+        ("joinagg",) + sum((j._registry_scope()
+                            for j in reversed(fused.joins)), ())
+        + (agg._program_fp(),))
+    if star == "one_dim":
+        join, = fused.joins
+        assert fused._hoist(agg) is None
+        B = agg._bounded_groups_cap(4096)
+        assert set(fused._jit_cache) == {
+            "build_has_dup", ("uniq_agg", agg._program_fp(), B)}
+        assert fused.describe() == (
+            f"TpuJoinAggFused[{agg.describe()} <- {join.describe()}] "
+            "path=unique lookup=vpu match=merge build_cap=262144")
+    digests = _program_digests(fused._jit_cache, calls)
+    for i, join in enumerate(fused.joins):
+        # the unfused join never ran: its build sort, nothing else
+        assert [k[0] for k in join._jit_cache] == ["build_preops"]
+        digests.update({f"join{i}:{kind}": d for kind, d in
+                        _program_digests(join._jit_cache, calls).items()})
+    assert digests == FUSED_PROGRAMS[star]
 
 
 def test_the_group_code_names_the_first_row_of_each_tuple():

@@ -140,6 +140,34 @@ def _lower_sort_merge_join_probe(cap, one_chip):
     return jax.jit(join._probe_fn(pschema)).lower(*_placed(args, one_chip))
 
 
+def _lower_left_outer_join_lookup(cap, one_chip):
+    """The LEFT OUTER join's lookup program against a unique build side,
+    both sides of ``cap`` rows: the binary search and the MXU gather at
+    2^13 rows, the merge sort and the VPU gathers past 2^14."""
+    from spark_rapids_tpu.compilecache.aot import (
+        abstract_array,
+        abstract_scalar,
+        dummy_columns,
+    )
+    from spark_rapids_tpu.exec.join import TpuAdaptiveJoinExec
+    from spark_rapids_tpu.session import TpuSession
+
+    s = TpuSession({"spark.rapids.sql.enabled": True,
+                    "spark.sql.autoBroadcastJoinThreshold": "-1"})
+    df = _long_df(s, k=50, v=100).join(_long_df(s, k=50, w=100), on=["k"],
+                                       how="left")
+    join = _find_exec(df._planned()[0], TpuAdaptiveJoinExec).shuffled
+    pschema = join._probe_child().output
+    bcols = dummy_columns(join._build_child().output, cap)
+    args = ((abstract_array((cap,), jnp.int64),),
+            abstract_array((cap,), jnp.int32),
+            abstract_scalar(jnp.int32),
+            tuple(bcols[i] for i in join._b_sel),
+            dummy_columns(pschema, cap),
+            abstract_scalar(jnp.int32))
+    return jax.jit(join._lookup_fn(pschema)).lower(*_placed(args, one_chip))
+
+
 def _lower_ici_epoch(cap, topo):
     """The epoch program of TpuIciShuffleAggExec — local partial
     aggregate, murmur3 all-to-all over ICI, merge — on a 4-device mesh of
@@ -199,6 +227,9 @@ _SORT_PROGRAMS = {
     "sort_merge_join_probe": (
         lambda rows, topo, chip: _lower_sort_merge_join_probe(rows, chip),
         ()),
+    "left_outer_join_lookup": (
+        lambda rows, topo, chip: _lower_left_outer_join_lookup(rows, chip),
+        ()),
     "ici_epoch_4_chips": (
         lambda rows, topo, chip: _lower_ici_epoch(rows, topo),
         ("all-to-all", "all_to_all")),
@@ -229,6 +260,13 @@ def test_bounded_group_by_for_v5e_at_2_25_rows(one_chip, full_compile):
 def test_sort_merge_join_probe_for_v5e_at_2_25_rows(one_chip, full_compile):
     _lower_or_compile(
         full_compile, _lower_sort_merge_join_probe(REAL_ROWS, one_chip))
+
+
+@SORT_PROGRAM
+def test_left_outer_join_lookup_for_v5e_at_2_25_rows(one_chip, full_compile):
+    text = _lower_or_compile(
+        full_compile, _lower_left_outer_join_lookup(REAL_ROWS, one_chip))
+    assert "sort" in text
 
 
 @SORT_PROGRAM
