@@ -7,12 +7,16 @@ RapidsShuffleClient/Server).  TPU-first replacement: the stage pair
     HashAggregate(FINAL) <- [Coalesce] <- ShuffleExchange <-
     HashAggregate(PARTIAL, fused scan ops)
 
-compiles to ONE shard_map program over the device mesh:
+compiles to two shard_map programs over the device mesh with one host
+sync between them:
 
-    per device:  local partial _agg_fn (the unchanged single-chip program)
-              -> spark murmur3 partition ids over the group keys
-              -> all-to-all of every partial-buffer column over ICI
-              -> local final _agg_fn on the received buffer rows
+    (a) per device:  local partial _agg_fn (the unchanged single-chip program)
+                  -> spark murmur3 partition ids over the group keys
+                  -> the row of the send matrix: groups for each peer
+    host:            reads the n_dev x n_dev matrix; the per-peer quota is
+                     its largest entry on the row-bucket ladder
+    (b) per device:  all-to-all of every partial-buffer column over ICI at
+                     that quota -> final _agg_fn on the received buffer rows
 
 The per-device program IS the single-chip code path — shard_map only wires
 the collectives around it (the "same program, sharded data" SPMD design the
@@ -24,9 +28,9 @@ The Spark-async vs SPMD-collective impedance mismatch (SURVEY.md §7 hard
 part #1) is resolved by epoching: an exchange is already a full barrier in
 Spark semantics, so executing it as one collective step loses no generality.
 
-Current quota layout: the all-to-all reserves local-cap slots per peer
-(received capacity = global cap).  jax.lax.ragged_all_to_all is the planned
-upgrade for skewed partitions.
+Quota layout: the aggregate's all-to-all reserves the counted quota per
+peer (received capacity = n_dev x quota); the join, sort, window and
+repartition stages reserve their input's local capacity per peer.
 """
 from __future__ import annotations
 
@@ -47,28 +51,62 @@ from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.exec.base import TpuExec
 
 
-def _mesh_program(per_device, **shard_map_kwargs):
+def _mesh_program(per_device, name: Optional[str] = None,
+                  **shard_map_kwargs):
     """One mesh stage program: the ``shard_map`` body compiled as ONE
-    jitted SPMD program.  Called un-jitted, shard_map's eager path
-    compiles every primitive of the body as a program of its own and
-    keeps none of them: ~1,200 compiles on EVERY collect of a grouped
-    aggregate (measured on 4 virtual devices, PR 23) — minutes per
-    collect where a compile costs what it does on the chip."""
+    jitted SPMD program (``name``: what the trace calls it).  Called
+    un-jitted, shard_map's eager path compiles every primitive of the
+    body as a program of its own and keeps none of them: ~1,200 compiles
+    on EVERY collect of a grouped aggregate (measured on 4 virtual
+    devices, PR 23) — minutes per collect where a compile costs what it
+    does on the chip."""
+    if name is not None:
+        per_device.__name__ = per_device.__qualname__ = name
     return tpu_jit(shard_map(per_device, **shard_map_kwargs))
+
+
+# ---------------------------------------------------------------------------
+# The balanced row layout of a mesh stage's input
+# ---------------------------------------------------------------------------
+
+def _balanced_shards(batch: ColumnarBatch, mesh, axis: str):
+    """(columns, per): the batch's columns row-sharded over ``mesh`` with
+    its rows spread evenly: device d holds rows [d * per, (d + 1) * per)
+    at the front of its shard of capacity / n_dev rows (the contiguous
+    blocks of the padded batch would leave the last devices short)."""
+    n_dev = int(mesh.devices.size)
+    batch = _ceil_to_mesh(batch, n_dev)
+    cap = batch.capacity // n_dev
+    per = max(-(-batch.num_rows // n_dev), 1)
+    rows = NamedSharding(mesh, P(axis))
+
+    def lay(arr):
+        a = arr[:n_dev * per].reshape((n_dev, per) + arr.shape[1:])
+        a = jnp.pad(a, [(0, 0), (0, cap - per)] + [(0, 0)] * (arr.ndim - 1))
+        return jax.device_put(a.reshape(arr.shape), rows)
+
+    return [_map_col_arrays(c, lay) for c in batch.columns], per
 
 
 # ---------------------------------------------------------------------------
 # ICI shuffle accounting + the host boundary (ISSUE 10)
 # ---------------------------------------------------------------------------
 
+def _row_bytes(cols) -> int:
+    """Bytes one row takes in every array of ``cols`` (an exchange routes
+    each of them)."""
+    return sum(a.dtype.itemsize * int(np.prod(a.shape[1:]))
+               for a in jax.tree_util.tree_leaves(list(cols)))
+
+
 def _ici_account(stage: str, n_dev: int, rows: int, nbytes: int,
                  dur_ns: int) -> None:
     """Per-collective-epoch accounting shared by every ICI stage exec:
-    the ``ici_*`` counters (epochs / rows / bytes exchanged device-to-
-    device, wall inside the collective program) and the per-query
-    ``ici_shuffle`` diagnostics event.  The exchanged bytes never cross
-    the host — the zero-host-bytes pin in tests/test_multichip.py holds
-    the all-device path to that."""
+    the ``ici_*`` counters (epochs; rows and bytes that left their chip,
+    read from the program's own counts; wall of the collective step) and
+    the per-query ``ici_shuffle`` diagnostics event.  The exchanged bytes
+    never cross the host — the zero-host-bytes pin in
+    tests/test_multichip.py holds the all-device path to that."""
     PC.bump("ici_epochs")
     PC.bump("ici_rows_exchanged", int(rows))
     PC.bump("ici_bytes_moved", int(nbytes))
@@ -216,19 +254,32 @@ def _shard_cols(batch: ColumnarBatch, mesh, axis: str):
 class TpuIciShuffleAggExec(TpuExec):
     """Fused distributed aggregation stage over a jax Mesh.
 
-    Epoch-streamed: the child's batches
-    flow through the collective program in bounded epochs —
+    Epoch-streamed: the child's batches flow through the collective
+    programs in bounded epochs.  Grouped, per epoch:
 
-        per epoch, per device:
-          local partial agg -> all-to-all by key hash -> MERGE the received
-          partial buffers into the device-resident accumulator (the
-          unfinalized buffer form, bounded by distinct keys per device)
+      (a) ``ici_agg_partial``, per device: local partial agg, the
+          murmur3 partition id of each of its groups, and how many groups
+          go to each peer (one row of the n_dev x n_dev send matrix);
+      host: ONE sync reads the matrix.  The quota Q is its largest entry
+          on the row-bucket ladder, and G the ladder rung of the most
+          groups a device holds;
+      (b) ``ici_agg_exchange``, per device: all-to-all of the partial's
+          first G rows at Q slots per peer (received capacity n_dev x Q),
+          then MERGE with the device-resident accumulator (an epoch with
+          more to come; the unfinalized buffer form, re-bucketed to the
+          smallest pow2 per-device capacity that holds every device's
+          groups) or, in the last epoch, the FINAL aggregate over the
+          accumulator and the received rows: merge and finalize in one
+          sort, so a one-epoch stage runs two programs.
 
-    and one finalize program runs after the last epoch.  Per-device peak
-    memory is one epoch shard + the accumulator: the merge runs at full
-    concat capacity (never truncating), then the accumulator re-buckets to
-    the smallest pow2 per-device capacity that holds every device's
-    groups."""
+    Global (no-key) aggregates run one epoch program (partial ->
+    all-gather -> merge) and one finalize program after the last epoch.
+
+    A grouped epoch is row-sharded in the balanced layout
+    (``_balanced_shards``), counted in ``mesh_reshard_bytes``.  The shards of
+    a resident table's batches (``scan.cacheDeviceBatches``: the same
+    arrays every collect) are kept, so only the first collect moves the
+    table between chips."""
 
     def __init__(self, partial, final, mesh, axis: str = "dp",
                  epoch_bytes: int = 1 << 28):
@@ -240,6 +291,7 @@ class TpuIciShuffleAggExec(TpuExec):
         self.epoch_bytes = epoch_bytes
         self._programs = {}
         self._finalize_p = None
+        self._shards = {}       # a resident input's shards, by its arrays
 
     @property
     def output(self):
@@ -247,212 +299,288 @@ class TpuIciShuffleAggExec(TpuExec):
 
     def describe(self):
         n = self.mesh.devices.size
-        return (f"TpuIciShuffleAgg[{n}dev] partial=({self.partial.describe()})"
+        return (f"TpuIciShuffleAgg[{n}dev] "
+                f"partial=({self.partial.describe()})"
                 f" final=({self.final.describe()})")
 
-    # ------------------------------------------------------------------
-    def _build_epoch_program(self, first: bool, acc_cap_local: int = 0):
-        """One epoch: partial -> all-to-all -> merge into the accumulator.
-
-        ``first`` epochs have no accumulator input; later epochs concat
-        the accumulator's buffer rows with the received partials before
-        the merge.  Returns per-device (acc buffer cols, group count)."""
+    # -- grouped: programs (a) and (b) ----------------------------------
+    def _build_partial_program(self):
         axis = self.axis
         n_dev = int(self.mesh.devices.size)
         partial = self.partial
-        final = self.final
-        grouped = bool(final.grouping)
         nkeys = len(partial.grouping)
 
-        def per_device(cols, num_rows, *acc):
+        def per_device(cols, num_rows, per):
+            from spark_rapids_tpu.ops.hashing import spark_partition_ids
+            from spark_rapids_tpu.parallel.mesh import peer_counts
+
+            idx = jax.lax.axis_index(axis).astype(jnp.int32)
+            nloc = jnp.clip(num_rows - idx * per, 0, per)
+            pcols, ng = partial._agg_fn(cols, nloc)
+            grows = jnp.arange(pcols[0].capacity) < ng
+            tgt = spark_partition_ids(list(pcols[:nkeys]), n_dev)
+            sent = peer_counts(grows, tgt, n_dev)
+            return tuple(pcols), tgt, sent.reshape(1, n_dev)
+
+        return _mesh_program(
+            per_device, "ici_agg_partial", mesh=self.mesh,
+            in_specs=(P(axis), P(), P()),
+            out_specs=(P(axis), P(axis), P(axis)),
+            check_vma=False)
+
+    def _build_exchange_program(self, groups_cap: int, quota: int,
+                                acc_cap_local: int, last: bool):
+        axis = self.axis
+        n_dev = int(self.mesh.devices.size)
+        final = self.final
+
+        def per_device(pcols, tgt, sent, *acc):
             from spark_rapids_tpu.parallel.mesh import ici_all_to_all_columns
 
-            local_cap = cols[0].capacity
-            idx = jax.lax.axis_index(axis)
-            nloc = jnp.clip(num_rows - idx.astype(jnp.int32) * local_cap,
-                            0, local_cap)
-            pcols, ng = partial._agg_fn(cols, nloc)
-            pcols = list(pcols)
-            grows = jnp.arange(pcols[0].capacity) < ng
-            if grouped:
-                from spark_rapids_tpu.ops.hashing import spark_partition_ids
-
-                tgt = spark_partition_ids(pcols[:nkeys], n_dev)
-                rcols, rok = ici_all_to_all_columns(pcols, grows, tgt,
-                                                    n_dev, axis)
-            else:
-                rcols = []
-                for c in pcols:
-                    validity = jax.lax.all_gather(c.validity, axis,
-                                                  tiled=True)
-                    if c.is_string:
-                        rcols.append(DeviceColumn(
-                            c.dtype, validity,
-                            chars=jax.lax.all_gather(c.chars, axis,
-                                                     tiled=True),
-                            lengths=jax.lax.all_gather(c.lengths, axis,
-                                                       tiled=True)))
-                    else:
-                        rcols.append(DeviceColumn(
-                            c.dtype, validity,
-                            data=jax.lax.all_gather(c.data, axis,
-                                                    tiled=True)))
-                rok = jax.lax.all_gather(grows, axis, tiled=True)
-            if not first:
+            grows = jnp.arange(groups_cap) < jnp.sum(sent)
+            rcols, rok = ici_all_to_all_columns(
+                list(_fit_cols(pcols, groups_cap)), grows,
+                tgt[:groups_cap], n_dev, axis, quota=quota)
+            if acc_cap_local:
                 acc_cols, acc_ng = acc
                 acc_ok = (jnp.arange(acc_cap_local, dtype=jnp.int32)
                           < acc_ng[0])
                 rcols = [_concat_cols(a, r)
                          for a, r in zip(acc_cols, rcols)]
                 rok = jnp.concatenate([acc_ok, rok])
-            mcols, mng = final._merge_fn(
-                tuple(rcols), jnp.int32(rcols[0].capacity), row_valid=rok)
-            if not grouped:
-                mng = jnp.int32(1)
+            fn = final._agg_fn if last else final._merge_fn
+            mcols, mng = fn(tuple(rcols), jnp.int32(rcols[0].capacity),
+                            row_valid=rok)
             return tuple(mcols), mng.astype(jnp.int32).reshape(1)
 
-        out_spec = P(axis) if grouped else P()
-        in_specs = (P(axis), P()) + (() if first else (out_spec, out_spec))
+        spec = P(axis)
         return _mesh_program(
-            per_device, mesh=self.mesh,
-            in_specs=in_specs,
-            out_specs=(out_spec, out_spec),
+            per_device, "ici_agg_exchange", mesh=self.mesh,
+            in_specs=(spec, spec, spec) + ((spec, spec) if acc_cap_local
+                                           else ()),
+            out_specs=(spec, spec),
             check_vma=False)
 
-    def _build_finalize_program(self, acc_cap_local: int):
-        axis = self.axis
-        final = self.final
-        grouped = bool(final.grouping)
+    def _mesh_rows(self, batch: ColumnarBatch, kept):
+        """(row-sharded columns, rows a device holds at most) of an epoch.
+        ``kept`` (a resident input's collect, else None) receives the
+        shards under the batch's arrays, and shards the last collect kept
+        for the same arrays are used again; whatever is laid out anew is
+        counted in ``mesh_reshard_bytes``."""
+        leaves = jax.tree_util.tree_leaves(list(batch.columns))
+        key = (batch.num_rows,) + tuple(map(id, leaves))
+        # an entry holds its arrays, so no live array can take their ids
+        hit = self._shards.get(key) if kept is not None else None
+        if hit is None:
+            PC.bump("mesh_reshard_bytes", batch.nbytes())
+            hit = (leaves,) + tuple(
+                _balanced_shards(batch, self.mesh, self.axis))
+        if kept is not None:
+            kept[key] = hit
+        return hit[1], hit[2]
 
-        def per_device(acc_cols, acc_ng):
-            acc_ok = (jnp.arange(acc_cap_local, dtype=jnp.int32)
-                      < acc_ng[0])
-            fcols, fng = final._agg_fn(
-                acc_cols, jnp.int32(acc_cap_local), row_valid=acc_ok)
-            return tuple(fcols), fng.astype(jnp.int32).reshape(1)
+    def _run_grouped_epoch(self, batch: ColumnarBatch, acc, acc_ng,
+                           last: bool, kept):
+        from spark_rapids_tpu.columnar.column import (DEFAULT_ROW_BUCKETS,
+                                                      round_up_bucket)
 
-        out_spec = P(axis) if grouped else P()
-        return _mesh_program(
-            per_device, mesh=self.mesh,
-            in_specs=(out_spec, out_spec),
-            out_specs=(out_spec, out_spec),
-            check_vma=False)
-
-    # ------------------------------------------------------------------
-    def _epochs(self, it) -> Iterator[ColumnarBatch]:
-        return _epoch_batches(it, self.epoch_bytes)
-
-    def _resize_acc(self, mcols, mcl: int, tgt_cap: int, n_dev: int):
-        """Re-bucket the accumulator to tgt_cap rows per device.
-
-        Merged groups are compacted to each device's block prefix, so the
-        per-device resize is a reshape+slice/pad of the sharded arrays;
-        the result is re-laid-out row-sharded over the mesh axis."""
-        grouped = bool(self.final.grouping)
-
-        def rs(arr):
-            if arr is None:
-                return None
-            if not grouped:
-                out = (arr[:tgt_cap] if tgt_cap <= arr.shape[0]
-                       else jnp.pad(arr, [(0, tgt_cap - arr.shape[0])]
-                                    + [(0, 0)] * (arr.ndim - 1)))
-                return out
-            shp = arr.shape
-            a = arr.reshape((n_dev, mcl) + shp[1:])
-            if tgt_cap <= mcl:
-                a = a[:, :tgt_cap]
-            else:
-                a = jnp.pad(a, [(0, 0), (0, tgt_cap - mcl)]
-                            + [(0, 0)] * (arr.ndim - 1))
-            out = a.reshape((n_dev * tgt_cap,) + shp[1:])
-            return jax.device_put(
-                out, NamedSharding(self.mesh, P(self.axis)))
-
-        return [DeviceColumn(c.dtype, rs(c.validity), data=rs(c.data),
-                             chars=rs(c.chars), lengths=rs(c.lengths))
-                for c in mcols]
-
-    def _run_epoch(self, batch: ColumnarBatch, acc, acc_ng_arr, n_dev):
-        """Run one epoch; re-bucket the merged accumulator to the smallest
-        pow2 per-device capacity holding every device's groups (the merge
-        runs at full concat capacity, so nothing is ever truncated)."""
-        cap = batch.capacity
-        if cap % n_dev or cap < n_dev:
-            batch = ColumnarBatch(
-                [c.slice_to(-(-cap // n_dev) * n_dev)
-                 for c in batch.columns], batch.num_rows, batch.schema)
-        sharded = self._shard_batch(batch)
-        first = acc is None
-        grouped = bool(self.final.grouping)
-        acc_cap_local = (0 if first
-                         else acc[0].capacity // (n_dev if grouped else 1))
-        key = (batch.capacity, first, acc_cap_local)
-        if key not in self._programs:
-            self._programs[key] = self._build_epoch_program(
-                first, acc_cap_local)
-        args = (tuple(sharded), jnp.int32(batch.num_rows))
-        if not first:
-            args = args + (tuple(acc), acc_ng_arr)
-        t0 = time.perf_counter_ns()
-        mcols, mng = self._programs[key](*args)
-        mng_np = np.asarray(mng)            # one host sync per epoch
-        _ici_account(self.node_name, n_dev, int(mng_np.sum()),
-                     batch.nbytes(), time.perf_counter_ns() - t0)
-        mcl = mcols[0].capacity // (n_dev if grouped else 1)
+        n_dev = int(self.mesh.devices.size)
+        with PC.span("srt.ici.partial"):
+            cols, per = self._mesh_rows(batch, kept)
+            if "partial" not in self._programs:
+                self._programs["partial"] = self._build_partial_program()
+            pcols, tgt, sent = self._programs["partial"](
+                tuple(cols), jnp.int32(batch.num_rows), jnp.int32(per))
+            sent_np = np.asarray(sent)      # the one sync of (a)
+        local_cap = pcols[0].capacity // n_dev
+        groups_cap = min(round_up_bucket(max(int(sent_np.sum(1).max()), 1),
+                                         DEFAULT_ROW_BUCKETS), local_cap)
+        quota = min(round_up_bucket(max(int(sent_np.max()), 1),
+                                    DEFAULT_ROW_BUCKETS), groups_cap)
+        PC.bump("ici_quota_rows", quota)
+        acc_cap_local = 0 if acc is None else acc[0].capacity // n_dev
+        key = (local_cap, groups_cap, quota, acc_cap_local, last)
+        with PC.span("srt.ici.exchange") as sp:
+            if key not in self._programs:
+                self._programs[key] = self._build_exchange_program(
+                    groups_cap, quota, acc_cap_local, last)
+            args = (pcols, tgt, sent)
+            if acc is not None:
+                args = args + (tuple(acc), acc_ng)
+            mcols, mng = self._programs[key](*args)
+            mng_np = np.asarray(mng)        # one host sync per epoch
+        moved = int(sent_np.sum() - np.trace(sent_np))
+        _ici_account(self.node_name, n_dev, moved,
+                     moved * _row_bytes(pcols), sp.ns)
+        if last:
+            return list(mcols), mng_np
+        mcl = mcols[0].capacity // n_dev
         need = max(int(mng_np.max()), 1)
         tgt_cap = 1 << (need - 1).bit_length()
         if tgt_cap != mcl:
-            return self._resize_acc(mcols, mcl, tgt_cap, n_dev), mng
+            mcols = _rebucket_sharded(mcols, mcl, tgt_cap, n_dev,
+                                      self.mesh, self.axis)
         return list(mcols), mng
 
-    # ------------------------------------------------------------------
-    def execute_columnar(self) -> Iterator[ColumnarBatch]:
+    # -- global: one epoch program, one finalize -------------------------
+    def _build_epoch_program(self, first: bool):
+        """One global epoch: partial -> all-gather -> merge into the
+        accumulator (``first`` epochs have none)."""
+        axis = self.axis
+        partial = self.partial
+        final = self.final
+
+        def per_device(cols, num_rows, *acc):
+            local_cap = cols[0].capacity
+            idx = jax.lax.axis_index(axis)
+            nloc = jnp.clip(num_rows - idx.astype(jnp.int32) * local_cap,
+                            0, local_cap)
+            pcols, ng = partial._agg_fn(cols, nloc)
+            grows = jnp.arange(pcols[0].capacity) < ng
+            rcols = []
+            for c in pcols:
+                validity = jax.lax.all_gather(c.validity, axis, tiled=True)
+                if c.is_string:
+                    rcols.append(DeviceColumn(
+                        c.dtype, validity,
+                        chars=jax.lax.all_gather(c.chars, axis, tiled=True),
+                        lengths=jax.lax.all_gather(c.lengths, axis,
+                                                   tiled=True)))
+                else:
+                    rcols.append(DeviceColumn(
+                        c.dtype, validity,
+                        data=jax.lax.all_gather(c.data, axis, tiled=True)))
+            rok = jax.lax.all_gather(grows, axis, tiled=True)
+            if not first:
+                acc_cols, acc_ng = acc
+                acc_cap = acc_cols[0].capacity
+                acc_ok = jnp.arange(acc_cap, dtype=jnp.int32) < acc_ng[0]
+                rcols = [_concat_cols(a, r)
+                         for a, r in zip(acc_cols, rcols)]
+                rok = jnp.concatenate([acc_ok, rok])
+            mcols, _ = final._merge_fn(
+                tuple(rcols), jnp.int32(rcols[0].capacity), row_valid=rok)
+            return tuple(mcols), jnp.int32(1).reshape(1)
+
+        in_specs = (P(axis), P()) + (() if first else (P(), P()))
+        return _mesh_program(
+            per_device, mesh=self.mesh,
+            in_specs=in_specs,
+            out_specs=(P(), P()),
+            check_vma=False)
+
+    def _build_finalize_program(self, acc_cap: int):
+        final = self.final
+
+        def per_device(acc_cols, acc_ng):
+            acc_ok = jnp.arange(acc_cap, dtype=jnp.int32) < acc_ng[0]
+            fcols, fng = final._agg_fn(
+                acc_cols, jnp.int32(acc_cap), row_valid=acc_ok)
+            return tuple(fcols), fng.astype(jnp.int32).reshape(1)
+
+        return _mesh_program(
+            per_device, mesh=self.mesh,
+            in_specs=(P(), P()),
+            out_specs=(P(), P()),
+            check_vma=False)
+
+    def _run_global_epoch(self, batch: ColumnarBatch, acc, acc_ng):
         n_dev = int(self.mesh.devices.size)
+        batch = _ceil_to_mesh(batch, n_dev)
+        PC.bump("mesh_reshard_bytes", batch.nbytes())
+        sharded = self._shard_batch(batch)
+        first = acc is None
+        key = (batch.capacity, first, 0 if first else acc[0].capacity)
+        if key not in self._programs:
+            self._programs[key] = self._build_epoch_program(first)
+        args = (tuple(sharded), jnp.int32(batch.num_rows))
+        if not first:
+            args = args + (tuple(acc), acc_ng)
+        with PC.span("srt.ici.exchange") as sp:
+            mcols, mng = self._programs[key](*args)
+            np.asarray(mng)                 # one host sync per epoch
+        # each device's one partial row goes to every other device
+        _ici_account(self.node_name, n_dev, n_dev * (n_dev - 1),
+                     n_dev * (n_dev - 1) * _row_bytes(mcols), sp.ns)
+        return [c.slice_to(1) for c in mcols], mng
+
+    # ------------------------------------------------------------------
+    def _epochs(self, it, resident: bool) -> Iterator[ColumnarBatch]:
+        """Epochs, each with whether it is the last.  A resident input's
+        batches are epochs as they come: their shards are kept, and a
+        concat would be new arrays every collect."""
+        batches = it if resident else _epoch_batches(it, self.epoch_bytes)
+        prev = None
+        for b in batches:
+            if b.num_rows == 0:
+                continue
+            if prev is not None:
+                yield prev, False
+            prev = b
+        if prev is not None:
+            yield prev, True
+
+    def execute_columnar(self) -> Iterator[ColumnarBatch]:
+        from spark_rapids_tpu.exec.basic import TpuLocalTableScanExec
+
+        grouped = bool(self.final.grouping)
+        child = self.children[0]
+        resident = (grouped and isinstance(child, TpuLocalTableScanExec)
+                    and child.cache_device)
+        kept = {} if resident else None
         acc = None
         acc_ng = None
-        saw_rows = False
         with self.metrics["opTime"].timed():
-            for epoch in self._epochs(self.children[0].execute_columnar()):
-                if epoch.num_rows == 0:
-                    continue
-                saw_rows = True
-                acc, acc_ng = self._run_epoch(epoch, acc, acc_ng, n_dev)
-            if not saw_rows:
+            for epoch, last in self._epochs(child.execute_columnar(),
+                                            resident):
+                if grouped:
+                    acc, acc_ng = self._run_grouped_epoch(epoch, acc,
+                                                          acc_ng, last, kept)
+                else:
+                    acc, acc_ng = self._run_global_epoch(epoch, acc, acc_ng)
+            if resident:
+                self._shards = kept
+            if acc is None:
                 yield from self._empty_input()
                 return
-            acc_cap_local = acc[0].capacity // (
-                n_dev if self.final.grouping else 1)
-            fkey = acc_cap_local
-            if self._finalize_p is None or self._finalize_p[0] != fkey:
-                self._finalize_p = (fkey,
-                                    self._build_finalize_program(fkey))
-            fcols, fng = self._finalize_p[1](tuple(acc), acc_ng)
-            fng_np = np.asarray(fng)          # one host sync
+            if not grouped:
+                with PC.span("srt.ici.finalize"):
+                    acc_cap = acc[0].capacity
+                    if (self._finalize_p is None
+                            or self._finalize_p[0] != acc_cap):
+                        self._finalize_p = (
+                            acc_cap, self._build_finalize_program(acc_cap))
+                    fcols, fng = self._finalize_p[1](tuple(acc), acc_ng)
+                    np.asarray(fng)         # one host sync
+            else:
+                # the last epoch's program (b) ran the final aggregate and
+                # synced its per-device row counts
+                fcols, fng_np = acc, acc_ng
         out_schema = self.final.output
-        if not self.final.grouping:
+        if not grouped:
             yield self._count_output(
                 ColumnarBatch([c.gather(jnp.arange(1)) for c in fcols],
                               1, out_schema))
             return
-        per_dev_cap = fcols[0].capacity // n_dev
-        for d in range(n_dev):
-            ng = int(fng_np[d])
-            if ng == 0:
-                continue
-            lo = d * per_dev_cap
-            cols = [
-                DeviceColumn(c.dtype,
-                             c.validity[lo: lo + per_dev_cap],
-                             data=None if c.data is None
-                             else c.data[lo: lo + per_dev_cap],
-                             chars=None if c.chars is None
-                             else c.chars[lo: lo + per_dev_cap],
-                             lengths=None if c.lengths is None
-                             else c.lengths[lo: lo + per_dev_cap])
-                for c in fcols]
-            yield self._count_output(
-                ColumnarBatch(cols, ng, out_schema))
+        with PC.span("srt.ici.emit"):
+            out = [ColumnarBatch(cols, int(fng_np[d]), out_schema)
+                   for d, cols in enumerate(self._per_device(fcols))
+                   if fng_np[d]]
+        for b in out:
+            yield self._count_output(b)
+
+    def _per_device(self, cols):
+        """Each device's own block of row-sharded columns, as columns on
+        that device (its shards: no copy, nothing crosses a chip)."""
+        n_dev = int(self.mesh.devices.size)
+        per_dev_cap = cols[0].capacity // n_dev
+
+        def block(arr, d):
+            return next(s.data for s in arr.addressable_shards
+                        if (s.index[0].start or 0) == d * per_dev_cap)
+
+        return [[_map_col_arrays(c, lambda a, d=d: block(a, d))
+                 for c in cols] for d in range(n_dev)]
 
     def _shard_batch(self, batch: ColumnarBatch) -> List[DeviceColumn]:
         """Row-shard every column array over the mesh axis."""
@@ -581,7 +709,8 @@ class TpuIciShuffleJoinExec(TpuExec):
         def per_device(rcols, r_rows):
             from spark_rapids_tpu.exec.join import _key_words_of
             from spark_rapids_tpu.ops.hashing import spark_partition_ids
-            from spark_rapids_tpu.parallel.mesh import ici_all_to_all_columns
+            from spark_rapids_tpu.parallel.mesh import (ici_all_to_all_columns,
+                                                        off_chip_rows)
 
             idx = jax.lax.axis_index(axis)
             rcap = rcols[0].capacity
@@ -607,12 +736,13 @@ class TpuIciShuffleJoinExec(TpuExec):
             row_index = srt[-1]
             n_valid = jnp.sum(bkvalid.astype(jnp.int32))
             return (tuple(rr), tuple(swords), row_index,
-                    n_valid.reshape(1), rr_ok)
+                    n_valid.reshape(1), rr_ok,
+                    off_chip_rows(rrows, tgt_r, axis))
 
         return _mesh_program(
             per_device, mesh=self.mesh,
             in_specs=(P(axis), P()),
-            out_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
+            out_specs=(P(axis),) * 6,
             check_vma=False)
 
     def _build_pprobe(self, l_schema):
@@ -633,7 +763,8 @@ class TpuIciShuffleJoinExec(TpuExec):
                 _multiword_searchsorted,
             )
             from spark_rapids_tpu.ops.hashing import spark_partition_ids
-            from spark_rapids_tpu.parallel.mesh import ici_all_to_all_columns
+            from spark_rapids_tpu.parallel.mesh import (ici_all_to_all_columns,
+                                                        off_chip_rows)
 
             idx = jax.lax.axis_index(axis)
             lcap = lcols[0].capacity
@@ -671,14 +802,13 @@ class TpuIciShuffleJoinExec(TpuExec):
                 diff = diff.at[end].add(-1, mode="drop")
                 covered_sorted = jnp.cumsum(diff[:-1]) > 0
                 out = out + (acc[0] | covered_sorted,)
-            return out
+            return out + (off_chip_rows(lrows, tgt_l, axis),)
 
         return _mesh_program(
             per_device, mesh=self.mesh,
             in_specs=(P(axis), P(), P(axis), P(axis))
             + ((P(axis),) if full else ()),
-            out_specs=(P(axis), P(axis), P(axis), P(axis), P(axis),
-                       P(axis)) + ((P(axis),) if full else ()),
+            out_specs=(P(axis),) * (8 if full else 7),
             check_vma=False)
 
     def _build_p2(self, out_cap, l_schema, r_schema, n_l):
@@ -847,10 +977,18 @@ class TpuIciShuffleJoinExec(TpuExec):
             if self._pbuild is None:
                 self._pbuild = self._build_pbuild(r_schema)
             t0 = time.perf_counter_ns()
-            rr, swords, row_index, n_valid, rr_ok = self._pbuild(
+            rr, swords, row_index, n_valid, rr_ok, b_moved = self._pbuild(
                 tuple(rs), jnp.int32(right.num_rows))
-            _ici_account(self.node_name, n_dev, right.num_rows,
-                         right.nbytes(), time.perf_counter_ns() - t0)
+            # the build's rows that left their chip are read with the
+            # first probe epoch's sync (no sync of their own)
+            build_account = [b_moved, _row_bytes(rs),
+                             time.perf_counter_ns() - t0]
+
+        def account(moved_np, row_bytes, dur_ns):
+            moved = int(np.asarray(moved_np).sum())
+            _ici_account(self.node_name, n_dev, moved, moved * row_bytes,
+                         dur_ns)
+
         matched = None
         if full:
             matched = jax.device_put(
@@ -893,15 +1031,21 @@ class TpuIciShuffleJoinExec(TpuExec):
                     res = self._pprobe[pkey](tuple(ls),
                                              jnp.int32(epoch.num_rows),
                                              swords, n_valid, *acc)
-                    _ici_account(self.node_name, n_dev, epoch.num_rows,
-                                 epoch.nbytes(),
-                                 time.perf_counter_ns() - t0)
                     (rl, lo, counts, unmatched, rl_ok, totals) = res[:6]
                     if full:
                         # OR-ing covered build rows is idempotent, so a
                         # skew re-run of the halves is safe
                         matched = res[6]
-                    totals_np = np.asarray(totals)  # one host sync/epoch
+                    b_moved = (build_account[0] if build_account
+                               else None)
+                    # one host sync/epoch
+                    totals_np, moved_np, b_moved = PC.sync_get(
+                        (totals, res[-1], b_moved))
+                    if build_account:
+                        account(b_moved, *build_account[1:])
+                        build_account = None
+                    account(moved_np, _row_bytes(ls),
+                            time.perf_counter_ns() - t0)
                     per_dev_rows = totals_np[:, 0] + (
                         totals_np[:, 1]
                         if jt in (JoinType.LEFT_OUTER, JoinType.FULL_OUTER)
@@ -974,6 +1118,8 @@ class TpuIciShuffleJoinExec(TpuExec):
                     cols = [c.gather(jnp.arange(lo_i, lo_i + per_dev_cap))
                             for c in out_cols[:keep_cols]]
                     yield self._emit(cols, ng)
+        if build_account:           # no probe epoch read it
+            account(*build_account)
         if full:
             with self.metrics["opTime"].timed():
                 bcap_local = swords[0].shape[0] // n_dev
@@ -1100,7 +1246,7 @@ class TpuIciSortExec(TpuExec):
             from spark_rapids_tpu.ops.sortkeys import (pack_sort_keys,
                                                        sort_permutation)
             from spark_rapids_tpu.parallel.mesh import (
-                ici_all_to_all_columns)
+                ici_all_to_all_columns, off_chip_rows)
 
             local_cap = cols[0].capacity
             idx = jax.lax.axis_index(axis)
@@ -1133,12 +1279,13 @@ class TpuIciSortExec(TpuExec):
             for c in rcols:
                 out.append(c.gather(perm))
             cnt = jnp.sum(rok.astype(jnp.int32))
-            return tuple(out), cnt.reshape(1)
+            return (tuple(out), cnt.reshape(1),
+                    off_chip_rows(rows, tgt, axis))
 
         return _mesh_program(
             per_device, mesh=self.mesh,
             in_specs=(P(axis), P(), P()),
-            out_specs=(P(axis), P(axis)),
+            out_specs=(P(axis), P(axis), P(axis)),
             check_vma=False)
 
     # -- execution ------------------------------------------------------
@@ -1199,11 +1346,14 @@ class TpuIciSortExec(TpuExec):
                     self._part_programs[pkey] = self._build_part_program(
                         schema, splitters.shape[1])
                 t0 = time.perf_counter_ns()
-                out_cols, cnts = self._part_programs[pkey](
+                out_cols, cnts, moved = self._part_programs[pkey](
                     tuple(sharded), jnp.int32(batch.num_rows), splitters)
-                cnts_np = np.asarray(cnts)      # one host sync per epoch
-                _ici_account(self.node_name, n_dev, int(cnts_np.sum()),
-                             batch.nbytes(), time.perf_counter_ns() - t0)
+                # one host sync per epoch
+                cnts_np, moved_np = PC.sync_get((cnts, moved))
+                moved = int(moved_np.sum())
+                _ici_account(self.node_name, n_dev, moved,
+                             moved * _row_bytes(sharded),
+                             time.perf_counter_ns() - t0)
                 per_dev_cap = out_cols[0].capacity // n_dev
                 for d in range(n_dev):
                     nrows = int(cnts_np[d])
@@ -1252,7 +1402,8 @@ def _build_exchange_epoch_program(mesh, axis: str, tgt_of):
 
     def per_device(cols, num_rows):
         from spark_rapids_tpu.ops.filterops import compact_columns
-        from spark_rapids_tpu.parallel.mesh import ici_all_to_all_columns
+        from spark_rapids_tpu.parallel.mesh import (ici_all_to_all_columns,
+                                                    off_chip_rows)
 
         local_cap = cols[0].capacity
         idx = jax.lax.axis_index(axis)
@@ -1263,12 +1414,13 @@ def _build_exchange_epoch_program(mesh, axis: str, tgt_of):
         rcols, rok = ici_all_to_all_columns(list(cols), rows, tgt,
                                             n_dev, axis)
         out, cnt = compact_columns(rok, rcols)
-        return tuple(out), cnt.astype(jnp.int32).reshape(1)
+        return (tuple(out), cnt.astype(jnp.int32).reshape(1),
+                off_chip_rows(rows, tgt, axis))
 
     return _mesh_program(
         per_device, mesh=mesh,
         in_specs=(P(axis), P()),
-        out_specs=(P(axis), P(axis)),
+        out_specs=(P(axis), P(axis), P(axis)),
         check_vma=False)
 
 
@@ -1297,12 +1449,14 @@ def _build_cross_slice_program(mesh, tgt_of):
         rcols, rok = cross_slice_all_to_all_columns(
             list(cols), rows, pid, n_host, n_ici)
         out, cnt = compact_columns(rok, list(rcols))
-        return tuple(out), cnt.astype(jnp.int32).reshape(1)
+        moved = jnp.sum((rows & (pid != idx)).astype(jnp.int32))
+        return (tuple(out), cnt.astype(jnp.int32).reshape(1),
+                moved.reshape(1))
 
     return _mesh_program(
         per_device, mesh=mesh,
         in_specs=(P(("host", "ici")), P()),
-        out_specs=(P(("host", "ici")), P(("host", "ici"))),
+        out_specs=(P(("host", "ici")),) * 3,
         check_vma=False)
 
 
@@ -1344,11 +1498,14 @@ class _IciExchangeStageBase(TpuExec):
         if pkey not in self._pex:
             self._pex[pkey] = self._build_program()
         t0 = time.perf_counter_ns()
-        rcols, cnts = self._pex[pkey](tuple(sharded),
-                                      jnp.int32(epoch.num_rows))
-        cnts_np = np.asarray(cnts).reshape(-1)  # one host sync per epoch
-        _ici_account(self.node_name, n_dev, int(cnts_np.sum()),
-                     epoch.nbytes(), time.perf_counter_ns() - t0)
+        rcols, cnts, moved = self._pex[pkey](tuple(sharded),
+                                             jnp.int32(epoch.num_rows))
+        # one host sync per epoch
+        cnts_np, moved_np = PC.sync_get((cnts, moved))
+        cnts_np = cnts_np.reshape(-1)
+        moved = int(moved_np.sum())
+        _ici_account(self.node_name, n_dev, moved,
+                     moved * _row_bytes(sharded), time.perf_counter_ns() - t0)
         per_dev_cap = rcols[0].capacity // n_dev
         need = max(int(cnts_np.max()), 1)
         blk_cap = min(1 << (need - 1).bit_length(), per_dev_cap)

@@ -102,72 +102,87 @@ def ici_all_to_all(values: jax.Array, validity: jax.Array,
     return recv_vals.reshape(-1), recv_ok.reshape(-1)
 
 
-def _slot_plan(validity: jax.Array, target_dev: jax.Array, n_dev: int):
-    """Shared slotting for a multi-column all-to-all: returns (perm, slot,
-    ok_send) placing row i of the sorted order at dense quota slot
-    [peer * cap + rank]."""
+def peer_counts(row_valid: jax.Array, target_dev: jax.Array, n_dev: int):
+    """(n_dev,) int32: how many valid rows go to each peer — one row of
+    the send matrix a counted quota is read from."""
+    return jnp.stack([jnp.sum(row_valid & (target_dev == p), dtype=jnp.int32)
+                      for p in range(n_dev)])
+
+
+def off_chip_rows(row_valid: jax.Array, target_dev: jax.Array, axis):
+    """(1,) int32: this device's valid rows whose target is another
+    device — the rows an exchange moves across chips."""
+    me = jax.lax.axis_index(axis).astype(jnp.int32)
+    return jnp.sum(row_valid & (target_dev != me),
+                   dtype=jnp.int32).reshape(1)
+
+
+def _slot_plan(validity: jax.Array, target_dev: jax.Array, n_dev: int,
+               quota: int):
+    """Shared slotting for a multi-column all-to-all: row i goes to slot
+    [peer * quota + its rank among the rows for that peer], input order
+    kept within a peer.  The rank is one prefix count per peer (no sort).
+    A row past its peer's quota, and a row not sent, gets a slot of its
+    own past the send buffer, which the scatter drops.  Returns
+    (slot, ok_send)."""
     cap = validity.shape[0]
-    perm = jax.lax.sort(
-        (jnp.where(validity, target_dev, n_dev).astype(jnp.int32),
-         jnp.arange(cap, dtype=jnp.int32)), num_keys=1, is_stable=True)[-1]
-    ok_s = validity[perm]
-    tgt_s = jnp.where(ok_s, target_dev[perm], n_dev)
-    is_start = jnp.concatenate([jnp.ones(1, jnp.bool_),
-                                tgt_s[1:] != tgt_s[:-1]])
-    pos = jnp.arange(cap, dtype=jnp.int32)
-    seg_start = jnp.where(is_start, pos, 0)
-    seg_start = jax.lax.associative_scan(jnp.maximum, seg_start)
-    slot = tgt_s * cap + (pos - seg_start)
-    return perm, slot, ok_s & (tgt_s < n_dev)
+    tgt = jnp.where(validity, target_dev, n_dev).astype(jnp.int32)
+    rank = jnp.zeros(cap, jnp.int32)
+    for p in range(n_dev):
+        hit = tgt == p
+        rank = jnp.where(hit, jnp.cumsum(hit.astype(jnp.int32)) - 1, rank)
+    ok = (tgt < n_dev) & (rank < quota)
+    slot = jnp.where(ok, tgt * quota + rank,
+                     n_dev * quota + jnp.arange(cap, dtype=jnp.int32))
+    return slot, ok
 
 
-def _a2a_array(arr: jax.Array, perm, slot, n_dev: int, axis: str):
-    """Route one array (any trailing shape) through the dense-quota
-    all-to-all using a precomputed slot plan."""
-    cap = perm.shape[0]
-    sorted_ = arr[perm]
-    send = jnp.zeros((n_dev * cap,) + arr.shape[1:], arr.dtype
-                     ).at[slot].set(sorted_, mode="drop")
-    send = send.reshape((n_dev, cap) + arr.shape[1:])
+def _a2a_array(arr: jax.Array, slot, n_dev: int, quota: int, axis: str):
+    """Route one array (any trailing shape) through the all-to-all at
+    ``quota`` slots per peer, using a precomputed slot plan."""
+    send = jnp.zeros((n_dev * quota,) + arr.shape[1:], arr.dtype
+                     ).at[slot].set(arr, mode="drop", unique_indices=True)
+    send = send.reshape((n_dev, quota) + arr.shape[1:])
     recv = jax.lax.all_to_all(send, axis, 0, 0, tiled=False)
-    return recv.reshape((n_dev * cap,) + arr.shape[1:])
+    return recv.reshape((n_dev * quota,) + arr.shape[1:])
 
 
 def ici_all_to_all_columns(cols, row_valid: jax.Array,
-                           target_dev: jax.Array, n_dev: int, axis: str):
+                           target_dev: jax.Array, n_dev: int, axis: str,
+                           quota: Optional[int] = None):
     """Device-resident shuffle of a whole batch (list of DeviceColumn)
     inside shard_map: every array (validity/data/chars/lengths) of every
     column rides the same all-to-all routing plan.
 
-    Returns (received columns, received-row mask).  Dense quota layout:
-    each device reserves cap slots per peer, so the received capacity is
-    n_dev * cap (ragged all-to-all is the planned upgrade —
-    jax.lax.ragged_all_to_all where available)."""
+    Returns (received columns, received-row mask).  Each device reserves
+    ``quota`` slots per peer, so the received capacity is n_dev * quota.
+    Left at None the quota is the input's capacity (a dense layout that
+    never drops a row); a caller that has counted what each device sends
+    each peer (``peer_counts``) passes at least the largest count."""
     from spark_rapids_tpu.columnar.column import DeviceColumn
 
-    perm, slot, ok_send = _slot_plan(row_valid, target_dev, n_dev)
-    cap = row_valid.shape[0]
-    # ok_send is already in sorted order; scatter it through the slot plan
-    sent_ok = jnp.zeros((n_dev * cap,), jnp.bool_).at[slot].set(
-        ok_send, mode="drop").reshape(n_dev, cap)
-    rok = jax.lax.all_to_all(sent_ok, axis, 0, 0, tiled=False).reshape(-1)
+    quota = row_valid.shape[0] if quota is None else quota
+    slot, ok_send = _slot_plan(row_valid, target_dev, n_dev, quota)
+    rok = _a2a_array(ok_send, slot, n_dev, quota, axis)
+
+    def route(arr):
+        return _a2a_array(arr, slot, n_dev, quota, axis)
+
     out = []
     for c in cols:
-        validity = _a2a_array(c.validity, perm, slot, n_dev, axis)
+        validity = route(c.validity)
         if c.is_string:
-            chars = _a2a_array(c.chars, perm, slot, n_dev, axis)
-            lengths = _a2a_array(c.lengths, perm, slot, n_dev, axis)
-            out.append(DeviceColumn(c.dtype, validity & rok, chars=chars,
-                                    lengths=lengths))
+            out.append(DeviceColumn(c.dtype, validity & rok,
+                                    chars=route(c.chars),
+                                    lengths=route(c.lengths)))
         elif c.is_array:
-            data = _a2a_array(c.data, perm, slot, n_dev, axis)
-            lengths = _a2a_array(c.lengths, perm, slot, n_dev, axis)
-            ev = _a2a_array(c.elem_valid, perm, slot, n_dev, axis)
-            out.append(DeviceColumn(c.dtype, validity & rok, data=data,
-                                    lengths=lengths, elem_valid=ev))
+            out.append(DeviceColumn(c.dtype, validity & rok,
+                                    data=route(c.data),
+                                    lengths=route(c.lengths),
+                                    elem_valid=route(c.elem_valid)))
         else:
-            data = _a2a_array(c.data, perm, slot, n_dev, axis)
-            out.append(DeviceColumn(c.dtype, validity & rok, data=data))
+            out.append(DeviceColumn(c.dtype, validity & rok,
+                                    data=route(c.data)))
     return out, rok
 
 

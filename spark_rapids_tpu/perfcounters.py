@@ -151,12 +151,16 @@ COUNTERS: Dict[str, int] = {
     "oom_retry_splits": 0,
     # ICI multi-chip shuffle (ISSUE 10): per-query collective-exchange
     # accounting — epochs through the mesh all-to-all stages, rows/bytes
-    # exchanged device-to-device (never through the host), and the wall
-    # inside the collective programs
+    # that left their chip (device-to-device, never through the host), and
+    # the wall of the collective steps; the grouped mesh aggregate's quota
+    # (rows a device reserves a peer) and the input bytes a mesh aggregate
+    # lays out as row shards (a resident table's once: the stage keeps them)
     "ici_epochs": 0,
     "ici_rows_exchanged": 0,
     "ici_bytes_moved": 0,
     "ici_shuffle_ns": 0,
+    "ici_quota_rows": 0,
+    "mesh_reshard_bytes": 0,
     # distributed cross-host tier (ISSUE 14, distributed/): elastic
     # membership (every worker join, incl. quarantined rejoins), LOST
     # declarations (missed heartbeats past workerLostMs or a dead

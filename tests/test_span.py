@@ -304,7 +304,8 @@ SPAN_TABLE = {
     "srt.exchange.partition", "srt.exchange.queue", "srt.join.build",
     "srt.join.probe", "srt.join.materialize", "srt.join.unique",
     "srt.join.lookup", "srt.joinagg.unique", "srt.joinagg.probe_sizes",
-    "srt.joinagg.mat_agg"}
+    "srt.joinagg.mat_agg", "srt.ici.partial", "srt.ici.exchange",
+    "srt.ici.finalize", "srt.ici.emit"}
 
 
 def test_span_names_are_the_documented_table_and_none_is_collect():
@@ -328,6 +329,7 @@ def test_span_names_are_the_documented_table_and_none_is_collect():
     ("srt.scan.h2d", ["io/scan.py"]),
     ("srt.exchange.partition", ["exec/exchange.py"]),
     ("srt.joinagg.unique", ["exec/fused.py"]),
+    ("srt.ici.partial", ["exec/ici.py"]),
 ])
 def test_a_span_has_one_site(name, files):
     assert _span_names()[name] == [

@@ -65,16 +65,17 @@ def _compile(fn, *args):
 # MINUTES past the 8,192-row bucket (PR 23, 8-core sandbox, bounded
 # group-by: 10.81 s at 2^13 rows, 187 s at 2^16, 352 s at 2^22, more than
 # 400 s at 2^25; join probe 1.3 s / 72.6 s / 121 s at 2^13 / 2^16 / 2^25;
-# ICI epoch program 11.6 s / 102.9 s at 2^13 / 2^16 and longer than 12 min
-# at 2^25).  Since PR 25 the chip compiles the join's and the aggregate's
-# programs at the 2^22-row bucket for every PR (the benchmark's
-# `first_setup_s`), so what tier-1 keeps is the LOWERING at 2^25 rows
-# (tracing + StableHLO for the placed operands: x64, sharding and shape
-# errors) and the proof that XLA:TPU accepts each of the three programs
-# at all, compiled at 2^13 rows, below the cliff (the ICI epoch program
-# runs in no cell, so this is the only compiler that sees it).  The
-# compile past the cliff, at 2^16 and (does it fit HBM?) at 2^25 rows, is
-# the `slow` cases' and the chip's.
+# the one-program ICI epoch of PR 23 11.6 s / 102.9 s at 2^13 / 2^16 and
+# longer than 12 min at 2^25).  Since PR 25 the chip compiles the join's
+# and the aggregate's programs at the 2^22-row bucket for every PR (the
+# benchmark's `first_setup_s`), and the mesh aggregate's two programs at
+# 2^24 rows a chip in the four-chip cell (`q18_mesh_groupby_4chip`), so
+# what tier-1 keeps is the LOWERING at real sizes (tracing + StableHLO
+# for the placed operands: x64, sharding and shape errors, and the
+# exchange's counted quota at the Q18 cell's own size) and the proof that
+# XLA:TPU accepts each sort-bearing program at all, compiled at 2^13 rows,
+# below the cliff.  The compile past the cliff, at 2^16 and (does it fit
+# HBM?) at 2^25 rows, is the `slow` cases' and the chip's.
 TIER1_ROWS = 1 << 13
 PAST_CLIFF_ROWS = 1 << 16
 REAL_ROWS = 1 << 25
@@ -168,27 +169,50 @@ def _lower_left_outer_join_lookup(cap, one_chip):
     return jax.jit(join._lookup_fn(pschema)).lower(*_placed(args, one_chip))
 
 
-def _lower_ici_epoch(cap, topo):
-    """The epoch program of TpuIciShuffleAggExec — local partial
-    aggregate, murmur3 all-to-all over ICI, merge — on a 4-device mesh of
-    the described chips, ``cap`` rows over the mesh."""
-    from spark_rapids_tpu.compilecache.aot import dummy_batch_args
+def _ici_agg(topo, df_of):
+    """The TpuIciShuffleAggExec of ``df_of(session)`` on a 4-device mesh
+    of the described chips."""
     from spark_rapids_tpu.exec.ici import TpuIciShuffleAggExec
-    from spark_rapids_tpu.session import TpuSession, count_, sum_
+    from spark_rapids_tpu.session import TpuSession
 
     s = TpuSession({"spark.rapids.sql.enabled": True,
                     "spark.rapids.shuffle.mode": "ICI",
-                    "spark.rapids.tpu.mesh.enabled": True})
-    df = _long_df(s, k=37, v=1000).group_by("k").agg(
-        sum_("v", "s"), count_(None, "c"))
+                    "spark.rapids.tpu.mesh.enabled": True,
+                    "spark.rapids.tpu.mesh.devices": 4})
+    df = df_of(s)
     ici = _find_exec(df._planned()[0], TpuIciShuffleAggExec)
     assert ici is not None, df.explain()
     ici.mesh = Mesh(np.array(topo.devices[:4]), (ici.axis,))
+    return ici
+
+
+def _lower_ici_agg(ici, cap, groups_cap, quota):
+    """TpuIciShuffleAggExec's two programs, ``cap`` rows over the mesh:
+    (a) the local partial aggregate, murmur3 ids and the send matrix,
+    (b) the all-to-all at ``quota`` rows a peer of the partial's first
+    ``groups_cap`` rows, then the final aggregate.  Returns both
+    lowerings, (b) placed on (a)'s output shapes."""
+    from spark_rapids_tpu.compilecache.aot import (abstract_scalar,
+                                                   dummy_columns)
+
     rows = NamedSharding(ici.mesh, P(ici.axis))
-    cols, num_rows = dummy_batch_args(ici.children[0].output, cap)
-    return ici._build_epoch_program(first=True).lower(
-        _placed(cols, rows),
-        _placed(num_rows, NamedSharding(ici.mesh, P())))
+    one = _placed(abstract_scalar(jnp.int32), NamedSharding(ici.mesh, P()))
+    partial = ici._build_partial_program().lower(
+        _placed(dummy_columns(ici.children[0].output, cap), rows), one, one)
+    exchange = ici._build_exchange_program(groups_cap, quota, 0, True).lower(
+        *_placed(partial.out_info, rows))
+    return partial, exchange
+
+
+def _lower_ici_epoch(cap, topo, program):
+    """One of the mesh aggregate's programs for a grouped sum and count,
+    ``cap`` rows over the mesh, the exchange at the densest quota."""
+    from spark_rapids_tpu.session import count_, sum_
+
+    ici = _ici_agg(topo, lambda s: _long_df(s, k=37, v=1000).group_by(
+        "k").agg(sum_("v", "s"), count_(None, "c")))
+    local = cap // 4
+    return _lower_ici_agg(ici, cap, local, local)[program]
 
 
 # expect trouble from the (_TILE, 128) uint32 blocks and from x64 grid
@@ -231,8 +255,11 @@ _SORT_PROGRAMS = {
         lambda rows, topo, chip: _lower_left_outer_join_lookup(rows, chip),
         ()),
     "ici_epoch_4_chips": (
-        lambda rows, topo, chip: _lower_ici_epoch(rows, topo),
+        lambda rows, topo, chip: _lower_ici_epoch(rows, topo, 1),
         ("all-to-all", "all_to_all")),
+    "ici_partial_4_chips": (
+        lambda rows, topo, chip: _lower_ici_epoch(rows, topo, 0),
+        ("sort",)),
 }
 
 
@@ -271,5 +298,54 @@ def test_left_outer_join_lookup_for_v5e_at_2_25_rows(one_chip, full_compile):
 
 @SORT_PROGRAM
 def test_ici_hash_repartition_for_four_v5e_chips(topo, full_compile):
-    text = _lower_or_compile(full_compile, _lower_ici_epoch(REAL_ROWS, topo))
+    text = _lower_or_compile(full_compile,
+                             _lower_ici_epoch(REAL_ROWS, topo, 1))
     assert "all-to-all" in text or "all_to_all" in text
+
+
+def test_q18_exchange_takes_the_counted_quota_on_four_v5e_chips(topo):
+    """The Q18 cell at its full size: the send matrix of the generator's
+    59,986,052 rows in the balanced layout (each shard's distinct
+    l_orderkey by its murmur3 peer) puts Q at 2^20 and G at 2^22, and the
+    lowered exchange program receives n_dev x Q = 2^22 rows a chip and
+    merges there, where the one-program epoch merged 4 x 2^24."""
+    from benchmark.harness.manifest import Manifest
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.columnar.column import (DEFAULT_ROW_BUCKETS,
+                                                  DeviceColumn,
+                                                  round_up_bucket)
+    from spark_rapids_tpu.ops.hashing import spark_partition_ids
+
+    cell = Manifest().cell("q18_mesh_groupby_4chip")
+    rows = cell.fact_rows
+    keys = cell.generators["lineitem"].make(
+        rows, np.random.default_rng([2**31 + 18, 0]))["l_orderkey"]
+    # the stage's balanced layout of the resident 2^26-row batch
+    per = -(-rows // 4)
+    cap = round_up_bucket(rows, DEFAULT_ROW_BUCKETS) // 4
+    assert (per, cap) == (14_996_513, 1 << 24)
+    sent = np.zeros((4, 4), np.int64)
+    for d in range(4):
+        shard = np.unique(keys[d * per:(d + 1) * per])
+        col = DeviceColumn(T.LONG, jnp.ones(len(shard), jnp.bool_),
+                           data=jnp.asarray(shard))
+        sent[d] = np.bincount(np.asarray(spark_partition_ids([col], 4)),
+                              minlength=4)
+    # an order split by a shard boundary is a group on both chips
+    assert 15_000_000 <= sent.sum() <= 15_000_003
+    quota = round_up_bucket(int(sent.max()), DEFAULT_ROW_BUCKETS)
+    groups_cap = round_up_bucket(int(sent.sum(1).max()), DEFAULT_ROW_BUCKETS)
+    assert (quota, groups_cap) == (1 << 20, 1 << 22)
+
+    from benchmark.harness.cell import _resident_frame
+
+    gen = cell.generators["lineitem"]
+    ici = _ici_agg(topo, lambda s: cell.query.build({"lineitem": (
+        _resident_frame(s, gen.make(64, np.random.default_rng(18)),
+                        gen.TYPES))}))
+    partial, exchange = _lower_ici_agg(ici, 4 * cap, groups_cap, quota)
+    (pkey, _), tgt, matrix = partial.out_info
+    assert pkey.data.shape == (4 * cap,) and matrix.shape == (4, 4)
+    (fkey, _), counts = exchange.out_info
+    # the global shape of a row-sharded output: 4 chips x n_dev x Q
+    assert fkey.data.shape == (4 * 4 * quota,) and counts.shape == (4,)
