@@ -69,6 +69,12 @@ class TpuHashAggregateExec(TpuExec):
         # node's jitted program, applied in selection-mask mode
         self.pre_ops = []
         self.input_schema = child_plan_output
+        from spark_rapids_tpu.config import AGG_SMALL_GROUPS_CAP, get_conf
+
+        # the groups-cap ladder's first rung, read when the plan is built
+        # (never inside a trace): full-width programs past it take the
+        # end-row form (_ends_form)
+        self._ends_above = get_conf().get(AGG_SMALL_GROUPS_CAP)
 
     @property
     def output(self):
@@ -82,8 +88,11 @@ class TpuHashAggregateExec(TpuExec):
             names = "+".join(type(o).__name__.replace("Op", "")
                              for o in self.pre_ops)
             fused = f" fused=[{names}]"
+        # the form the last full-width grouped program took
+        seg = getattr(self, "_seg_form", None)
+        seg = f" seg={seg}" if seg else ""
         return (f"TpuHashAggregate({self.mode.value}) keys=[{g}] "
-                f"aggs=[{a}]{fused}")
+                f"aggs=[{a}]{fused}{seg}")
 
     @property
     def _has_collect(self) -> bool:
@@ -631,6 +640,7 @@ class TpuHashAggregateExec(TpuExec):
                 self._groups_cap_hint = B2
                 bump("agg_groups_cap_regrows")
                 if B2 >= batch.capacity:
+                    self._launch_full_width(batch.capacity)
                     cols, nrows = self._agg_jit(None)(*args)
                     n = int(nrows)
                     break
@@ -638,6 +648,7 @@ class TpuHashAggregateExec(TpuExec):
                 n = int(nrows)
                 B = B2
             return ColumnarBatch(list(cols), n, self._output)
+        self._launch_full_width(batch.capacity)
         cols, nrows = self._agg_jit(None)(*args)
         n = 1 if not self.grouping else int(nrows)
         return ColumnarBatch(list(cols), n, self._output)
@@ -751,6 +762,51 @@ class TpuHashAggregateExec(TpuExec):
         B = max(B, getattr(self, "_groups_cap_hint", 0))
         return B if B < cap else None
 
+    def _ends_form(self, cap: int) -> bool:
+        """Whether the full-width grouped program at capacity ``cap``
+        takes the end-row form (``SegEnds``, ``_compact_ends``): above the
+        ladder's first rung, below which full width is already cheap (at
+        every capacity with the ladder off), and every aggregate an
+        integer or decimal sum, average or count.  Float sums, min/max,
+        first/last, folds and the rest keep the scatter program whole."""
+        if not self.grouping or self._has_collect:
+            return False
+        if self._ends_above and cap <= self._ends_above:
+            return False
+        exact = (T.IntegralType, T.DecimalType)
+        for a, fields in zip(self.aggregates, self._agg_fields()):
+            if a.func in ("count", "count_star", "count_if"):
+                continue
+            if a.func not in ("sum", "avg"):
+                return False
+            if self.mode == AggregateMode.FINAL:
+                sch = self.child_schema
+                name = a.result_name + ("_sum" if a.func == "avg" else "")
+                in_dt = sch.fields[sch.field_names().index(name)].dataType
+            elif a.child is None:
+                return False
+            else:
+                in_dt = a.child.dataType
+            # an average divides its exact sum row by row: its result may
+            # be a double, its partial sum buffer may not
+            out_ok = (a.func == "avg" and self.mode != AggregateMode.PARTIAL
+                      or isinstance(fields[0].dataType, exact))
+            if not (isinstance(in_dt, exact) and out_ok):
+                return False
+        return True
+
+    def _launch_full_width(self, cap: int) -> None:
+        """Books a launch of this node's full-width grouped program at
+        capacity ``cap``: ``seg=ends|scatter`` in describe(), and the
+        counter ``agg_segment_compactions`` where it took the end-row
+        form."""
+        if not self.grouping:
+            return
+        ends = self._ends_form(cap)
+        self._seg_form = "ends" if ends else "scatter"
+        if ends:
+            bump("agg_segment_compactions")
+
     def _max_group_rows_fn(self, cols, num_rows):
         """Largest per-group row count (the collect array width bound)."""
         batch = ColumnarBatch(list(cols), num_rows, self.input_schema)
@@ -795,8 +851,10 @@ class TpuHashAggregateExec(TpuExec):
         cap = batch.capacity
         # ---- sort rows by group keys (stable, padding last) ----
         keys: List[jax.Array] = []
+        key_at = []         # each key column's null word in ``keys``
         hi = jnp.int64(9223372036854775807)
         for kc in key_cols:
+            key_at.append(len(keys))
             nullk = jnp.where(kc.validity, 0, -1).astype(jnp.int64)
             keys.append(jnp.where(mask, nullk, hi))
             for w in _column_key_words(kc):
@@ -841,6 +899,7 @@ class TpuHashAggregateExec(TpuExec):
             seg = jnp.where(mask_sorted, seg, cap - 1)  # padding -> last
             nseg = cap
             bscope = None
+            ends = None
             if groups_cap:
                 # bounded-cardinality mode: outputs are
                 # groups_cap wide; every SEG primitive in this trace takes
@@ -850,17 +909,31 @@ class TpuHashAggregateExec(TpuExec):
                 nseg = groups_cap
                 bscope = SEG.bounds_scope(SEG.SegBounds(seg, nseg))
                 bscope.__enter__()
+            elif self._ends_form(cap):
+                # end-row form: every output is formed at its group's last
+                # row, then one sort moves the end rows to their slots
+                ends = SEG.SegEnds(seg, mask_sorted)
+                bscope = SEG.bounds_scope(ends)
+                bscope.__enter__()
             try:
                 # ---- group-key output columns ----
-                first_idx = SEG.seg_first_index(seg, mask_sorted, nseg)
-                safe_first = jnp.clip(first_idx, 0, cap - 1)
                 out_cols: List[DeviceColumn] = []
-                group_valid = jnp.arange(nseg) < ngroups
-                for kc in key_cols:
-                    g = _gather_col(kc, perm[safe_first])
-                    out_cols.append(DeviceColumn(
-                        g.dtype, g.validity & group_valid, data=g.data,
-                        chars=g.chars, lengths=g.lengths))
+                if ends is not None:
+                    # a group's rows are all valid: its end row's key is
+                    # the group's
+                    group_valid = ends.is_end
+                    out_cols = [_key_at_rows(kc, sorted_keys, at,
+                                             group_valid)
+                                for kc, at in zip(key_cols, key_at)]
+                else:
+                    first_idx = SEG.seg_first_index(seg, mask_sorted, nseg)
+                    safe_first = jnp.clip(first_idx, 0, cap - 1)
+                    group_valid = jnp.arange(nseg) < ngroups
+                    for kc in key_cols:
+                        g = _gather_col(kc, perm[safe_first])
+                        out_cols.append(DeviceColumn(
+                            g.dtype, g.validity & group_valid, data=g.data,
+                            chars=g.chars, lengths=g.lengths))
                 # ---- aggregates ----
                 for a, f in zip(self.aggregates, self._agg_fields()):
                     out_cols.extend(self._eval_agg(
@@ -871,6 +944,9 @@ class TpuHashAggregateExec(TpuExec):
                     bscope.__exit__()
         finally:
             self._presorted = None
+        if ends is not None:
+            out_cols = _compact_ends(out_cols, key_cols, seg, ends.is_end,
+                                     perm, ngroups)
         return tuple(out_cols), ngroups.astype(jnp.int32)
 
     _PRESORTABLE_FUNCS = frozenset({
@@ -1728,6 +1804,60 @@ def _seg_last_index(seg, row_mask, num_segments):
     iota = jnp.arange(n, dtype=jnp.int32)
     v = jnp.where(row_mask, iota, -1)
     return jax.ops.segment_max(v, seg, num_segments=num_segments)
+
+
+def _key_at_rows(kc: DeviceColumn, sorted_keys, at: int, row_valid):
+    """Key column ``kc`` in the sorted rows, read back from its sorted key
+    words where they hold the value itself (integer, date, timestamp and
+    64-bit decimal keys: no gather); None for the others, which
+    ``_compact_ends`` gathers at the slots."""
+    d = kc.data
+    if (kc.chars is not None or kc.children is not None or d is None
+            or d.ndim != 1 or not jnp.issubdtype(d.dtype, jnp.integer)):
+        return None
+    return DeviceColumn(kc.dtype, (sorted_keys[at] == 0) & row_valid,
+                        data=sorted_keys[at + 1].astype(d.dtype))
+
+
+def _compact_ends(cols, key_cols, seg, is_end, perm, ngroups):
+    """The end-row form's output columns at their slots: ONE sort keyed by
+    each end row's segment id, every other row after them, carries every
+    output word, the validities packed 32 to a word.  Slot g < ngroups
+    then holds group g; the slots past it hold rows that end no segment,
+    whose validities hold ``is_end`` and so read False.  A key column that
+    ``_key_at_rows`` could not read (None in ``cols``) is gathered at the
+    slots through the sorted row index, carried beside them."""
+    cap = seg.shape[0]
+    flat = [c for c in cols if c is not None]
+    words = [w for c in flat for w in _flat_sort_operands(c)[:-1]]
+    packed = []
+    for i in range(0, len(flat), 32):
+        w = jnp.zeros(cap, jnp.uint32)
+        for b, c in enumerate(flat[i:i + 32]):
+            w = w | (c.validity.astype(jnp.uint32) << b)
+        packed.append(w)
+    extra = (perm,) if len(flat) < len(cols) else ()
+    out = jax.lax.sort(
+        (jnp.where(is_end, seg, cap),) + tuple(words) + tuple(packed)
+        + extra, num_keys=1, is_stable=False)
+    nw = len(words)
+    words = list(out[1:1 + nw])
+    packed = out[1 + nw:1 + nw + len(packed)]
+    slot_valid = jnp.arange(cap) < ngroups
+    out_cols, j = [], 0
+    for i, c in enumerate(cols):
+        if c is None:
+            g = _gather_col(key_cols[i], out[-1])
+            out_cols.append(DeviceColumn(
+                g.dtype, g.validity & slot_valid, data=g.data,
+                chars=g.chars, lengths=g.lengths))
+            continue
+        k = c.data.ndim        # one word, or a decimal128's two limbs
+        v = ((packed[j // 32] >> (j % 32)) & 1).astype(jnp.bool_)
+        out_cols.append(_rebuild_flat_col(c, words[:k] + [v]))
+        del words[:k]
+        j += 1
+    return out_cols
 
 
 def _gather_col(c: DeviceColumn, idx) -> DeviceColumn:

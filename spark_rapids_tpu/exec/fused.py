@@ -679,6 +679,7 @@ class TpuJoinAggFusedExec(TpuExec):
 
         jitted = self._cached(("mat_agg", out_cap, with_um,
                                self._agg_tag(agg)), fn)
+        agg._launch_full_width(out_cap)
         cols, nrows = jitted(
             build.row_index,
             tuple(build.batch.columns[i] for i in join._b_sel),
@@ -807,6 +808,8 @@ class TpuJoinAggFusedExec(TpuExec):
             for lookup, match in zip(lookups, matches):
                 bump("join_lookups_" + lookup)
                 bump("join_matches_" + match)
+            if groups_cap is None:
+                inner._launch_full_width(cap)
             return self._cached(("uniq_agg", tag, groups_cap),
                                 mk(groups_cap))(*args)
 
@@ -992,6 +995,7 @@ class TpuWindowChainFusedExec(TpuExec):
                     bump("agg_groups_cap_regrows")
                     if B2 >= b.capacity:
                         B2 = None
+                        self.pre_agg._launch_full_width(b.capacity)
                     cols, count, ng = self._cached(
                         ("chain", with_agg, b.capacity, B2),
                         self._chain_fn(with_agg, B2))(*args)
@@ -1000,6 +1004,8 @@ class TpuWindowChainFusedExec(TpuExec):
                         break
                     B = B2
                 return ColumnarBatch(list(cols), n, self.output)
+            if with_agg:
+                self.pre_agg._launch_full_width(b.capacity)
             cols, count, _ = self._cached(
                 ("chain", with_agg, b.capacity, None),
                 self._chain_fn(with_agg))(*args)

@@ -389,6 +389,7 @@ class TpuIciShuffleAggExec(TpuExec):
             cols, per = self._mesh_rows(batch, kept)
             if "partial" not in self._programs:
                 self._programs["partial"] = self._build_partial_program()
+            self.partial._launch_full_width(cols[0].capacity // n_dev)
             pcols, tgt, sent = self._programs["partial"](
                 tuple(cols), jnp.int32(batch.num_rows), jnp.int32(per))
             sent_np = np.asarray(sent)      # the one sync of (a)
@@ -407,6 +408,8 @@ class TpuIciShuffleAggExec(TpuExec):
             args = (pcols, tgt, sent)
             if acc is not None:
                 args = args + (tuple(acc), acc_ng)
+            if last:
+                self.final._launch_full_width(n_dev * quota + acc_cap_local)
             mcols, mng = self._programs[key](*args)
             mng_np = np.asarray(mng)        # one host sync per epoch
         moved = int(sent_np.sum() - np.trace(sent_np))

@@ -306,10 +306,21 @@ def sum128_global(h, l, validity) -> Tuple[jax.Array, jax.Array, jax.Array, jax.
 
 
 def sum128_segments(h, l, validity, seg_ids, num_segments: int):
-    """Masked segmented sum -> (ok, any_valid, hi, lo) per segment."""
+    """Masked segmented sum -> (ok, any_valid, hi, lo) per segment, or at
+    each segment's end row under the end-row form (``ops/segment.py``
+    ``SegEnds``: the limbs are non-negative, so their cumsums never
+    decrease).  The bounded form is not taken here: a bounded 128-bit
+    sum scatters at full width."""
+    from spark_rapids_tpu.ops.segment import active_ends
+
     if num_segments == 1:
         return sum128_global(h, l, validity)
     limbs = _limbs32(h, l)
+    ends = active_ends(num_segments)
+    if ends is not None:
+        ok, hi, lo = _recombine([ends.nonneg_sum(jnp.where(validity, x, 0))
+                                 for x in limbs])
+        return ok, ends.counts(validity) > 0, hi, lo
     sums = [jax.ops.segment_sum(jnp.where(validity, x, 0), seg_ids,
                                 num_segments=num_segments) for x in limbs]
     ok, hi, lo = _recombine(sums)

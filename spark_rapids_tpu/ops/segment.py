@@ -69,6 +69,60 @@ class SegBounds:
         return self.csum_diff(validity.astype(jnp.int64))
 
 
+_M32 = 0xFFFFFFFF
+
+
+class SegEnds:
+    """End-row form of a SORTED segment-id array whose valid rows lead
+    (``row_mask``): each segment's value is formed at its LAST row by
+    scans alone, a cumsum less the cumsum at the previous segment's end,
+    with no scatter (an int64 scatter-add over 2^24 rows costs ~2.2 s on
+    the v5e).  A row that ends no segment holds a partial value; the
+    caller moves every end row to its segment's slot with ONE sort over
+    all of its outputs (exec/aggregate.py ``_compact_ends``).  Installed
+    through ``bounds_scope`` with ``num`` = the row capacity: integer
+    sums and counts take this form; min/max, first/last and folds have
+    none (it lacks ``SegBounds``' gathers), nor have float sums."""
+
+    __slots__ = ("is_end", "num")
+
+    def __init__(self, seg_ids, row_mask):
+        same_next = (seg_ids[1:] == seg_ids[:-1]) & row_mask[1:]
+        self.is_end = row_mask & jnp.concatenate(
+            [~same_next, jnp.ones(1, jnp.bool_)])
+        self.num = seg_ids.shape[0]
+
+    def nonneg_sum(self, x):
+        """Per-segment sum of NON-NEGATIVE x, at each end row: its cumsum
+        never decreases, so the exclusive cummax of the end rows' cumsums
+        is the cumsum at the previous segment's end."""
+        cs = jnp.cumsum(x)
+        at_ends = jax.lax.cummax(jnp.where(self.is_end, cs,
+                                           jnp.zeros((), cs.dtype)))
+        return cs - jnp.concatenate([jnp.zeros(1, cs.dtype), at_ends[:-1]])
+
+    def csum_diff(self, contrib):
+        """Per-segment sum of any integer contrib, equal modulo 2^64 to a
+        wrapping segment_sum: the sums of its two unsigned 32-bit halves,
+        each below 2^63 for fewer than 2^31 rows."""
+        if jnp.issubdtype(contrib.dtype, jnp.floating):
+            raise NotImplementedError("a float sum has no end-row form")
+        x = contrib.astype(jnp.int64)
+        lo = self.nonneg_sum(x & _M32)
+        hi = self.nonneg_sum((x >> 32) & _M32)
+        return (lo + (hi << 32)).astype(contrib.dtype)
+
+    def counts(self, validity):
+        # int32 scans: exact below 2^31 rows, and native on the v5e
+        return self.nonneg_sum(validity.astype(jnp.int32)).astype(jnp.int64)
+
+
+def active_ends(num_segments: int):
+    """The end-row form installed for ``num_segments``, else None."""
+    b = _active_bounds(num_segments, None)
+    return b if isinstance(b, SegEnds) else None
+
+
 _BOUNDS_TLS = threading.local()
 
 
@@ -80,10 +134,11 @@ def _bounds_stack() -> list:
 
 
 class bounds_scope:
-    """Trace-scoped bounded-segments mode: inside the scope, every
-    segment primitive called with ``num_segments == bounds.num`` takes the
-    boundary form instead of a full-width scatter.  Installed by the
-    aggregate's bounded program builder around its evaluation so the ~40
+    """Trace-scoped segments mode: inside the scope, every segment
+    primitive called with ``num_segments == bounds.num`` takes the
+    boundary form (``SegBounds``) or the end-row form (``SegEnds``)
+    instead of a full-width scatter.  Installed by the aggregate's
+    ``_agg_fn`` around its evaluation so the ~40
     SEG call sites need no signature change.  The ambient stack is
     PER-THREAD: tracing is synchronous on its own thread, but concurrent
     collects and the AOT compile pool trace on different threads at the
@@ -125,10 +180,12 @@ def seg_sum(values, validity, seg_ids, num_segments: int, bounds=None):
         # global reduction: plain tree-reduce, no scatter
         return (jnp.sum(contrib, keepdims=True),
                 jnp.sum(validity.astype(jnp.int64), keepdims=True) > 0)
-    if bounds is not None and not jnp.issubdtype(values.dtype,
-                                                 jnp.floating):
+    if bounds is not None and (isinstance(bounds, SegEnds)
+                               or not jnp.issubdtype(values.dtype,
+                                                     jnp.floating)):
         # integer/decimal: cumsum-diff is exact (wrap cancels); floats
-        # keep the scatter (cumsum-diff cancels across segments)
+        # keep the scatter (cumsum-diff cancels across segments), which
+        # the end-row form refuses
         return bounds.csum_diff(contrib), bounds.counts(validity) > 0
     s = jax.ops.segment_sum(contrib, seg_ids, num_segments=num_segments)
     cnt = jax.ops.segment_sum(validity.astype(jnp.int64), seg_ids,

@@ -255,6 +255,11 @@ COUNTERS: Dict[str, int] = {
     "joinagg_fused_lookups": 0,
     "join_rows_materialized": 0,
     "join_lookups_unique": 0,
+    # launches of a full-width grouped aggregate program that formed its
+    # groups at their segments' end rows and compacted them with one sort
+    # instead of scattering (exec/aggregate.py _ends_form; describe()
+    # ends in seg=ends)
+    "agg_segment_compactions": 0,
 }
 
 

@@ -64,7 +64,8 @@ def _ici(df):
 
 
 def _run(conf, tables, collects=2):
-    """(answers of ``collects`` collects, the counters of the last one)."""
+    """(answers of ``collects`` collects, the counters of the last one, the
+    frame)."""
     from spark_rapids_tpu import perfcounters as PC
 
     df = Q18.build({"lineitem": _frame(_session(conf), tables)})
@@ -75,7 +76,7 @@ def _run(conf, tables, collects=2):
         snap = PC.snapshot()
         answers.append(Q18.answer(df.collect()))
         counters = PC.since(snap)
-    return answers, counters
+    return answers, counters, df
 
 
 def _shards(keys):
@@ -120,7 +121,7 @@ def _check_all_paths(tables):
     resident mesh path's second collect."""
     want = Q18.reference(tables)
     for conf in (MESH, BASE, {**MESH, **RESIDENT}):
-        answers, counters = _run(conf, tables)
+        answers, counters, _ = _run(conf, tables)
         assert answers == [want] * 2, conf
     return want, counters
 
@@ -201,16 +202,26 @@ def test_the_chips_outputs_together_are_the_reference(every_order):
     assert got == Q18.reference(tables)
 
 
-def test_the_counters_count_what_crosses_a_chip(every_order):
+@pytest.mark.parametrize("form", ["scatter", "ends"])
+def test_the_counters_count_what_crosses_a_chip(every_order, form):
     """Rows and bytes that leave their chip, from the partition ids of
     each shard's groups; the quota from the same matrix; a resident table
     resharded in its first collect alone, one that is not resident every
-    collect."""
+    collect.  With the groups-cap ladder's first rung below the shard's
+    2,048 rows, programs (a) and (b) take the end-row form: one
+    ``agg_segment_compactions`` each a collect."""
     tables = _generated(8_000, 2**31 + 91)
     m = send_matrix(tables["lineitem"]["l_orderkey"])
     moved = int(m.sum() - np.trace(m))
+    ladder = {"spark.rapids.tpu.agg.smallGroupsCap":
+              64 if form == "ends" else 65536}
     for conf, resident in (({**MESH, **RESIDENT}, True), (MESH, False)):
-        _, d = _run(conf, tables, collects=2)
+        answers, d, df = _run({**conf, **ladder}, tables, collects=2)
+        assert answers == [Q18.reference(tables)] * 2
+        assert d["agg_segment_compactions"] == (2 if form == "ends" else 0)
+        ici = _ici(df)
+        assert [x._seg_form for x in (ici.partial, ici.final)] == [form] * 2
+        assert ici.describe().count(f" seg={form})") == 2, ici.describe()
         assert d["ici_epochs"] == 1
         assert d["ici_rows_exchanged"] == moved
         assert d["ici_bytes_moved"] == moved * ROW_BYTES
